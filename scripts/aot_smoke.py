@@ -5,8 +5,12 @@ Bounded CI gate (scripts/check.sh) for the executable cache
 Each boot is a FRESH subprocess (in-process trace caches would fake the
 warm number) sharing one AOT cache dir and one XLA persistent-cache dir —
 the product recipe: the AOT tier covers the warmup programs, the XLA tier
-covers the init-time jits, and ``persistent_cache_min_compile_secs`` auto-
-drops to 0 when the AOT cache is on.
+covers the init-time jits. Both dirs are fresh temp dirs ON PURPOSE: the
+first boot must be cold. The XLA dir reaches the children the way a
+deployment places it, through ``JAX_COMPILATION_CACHE_DIR``.
+
+CPU-only: both children pin the CPU platform, and the parent never touches
+JAX (a parent that had would hold the chip its children need).
 
 Gates:
 - the second boot compiles ZERO warmup programs (every one deserializes,
@@ -15,7 +19,7 @@ Gates:
 - warm boot wall < 50% of the cold boot (hardware target is <10% of the
   ~150 s cold boot; CPU-tiny measures the same mechanism at smaller scale).
 
-Appends an ``aot.smoke`` line to PERF_LEDGER.jsonl so the warm/cold split
+Appends an ``aot.smoke`` line to the $VMT_PERF_LEDGER ledger so the warm/cold split
 trends round over round.
 
 Usage: python scripts/aot_smoke.py [--out AOT_SMOKE.json]
@@ -49,10 +53,12 @@ def boot_once() -> int:
         FrameworkConfig,
         ViLBertConfig,
     )
+    from vilbert_multitask_tpu.engine import cachedir
     from vilbert_multitask_tpu.engine.runtime import InferenceEngine
     from vilbert_multitask_tpu.features.pipeline import RegionFeatures
 
     t0 = time.perf_counter()
+    cachedir.enable_compilation_cache()  # → $JAX_COMPILATION_CACHE_DIR
     cfg = FrameworkConfig(
         model=ViLBertConfig().tiny(),
         engine=EngineConfig(
@@ -60,7 +66,6 @@ def boot_once() -> int:
             image_buckets=(1, 2), throughput_buckets=None,
             compute_dtype="float32",
             use_pallas_coattention=False, use_pallas_self_attention=False,
-            compilation_cache_dir=os.environ["AOT_SMOKE_XLA_DIR"],
             aot_cache_dir=os.environ["AOT_SMOKE_AOT_DIR"]))
     eng = InferenceEngine(cfg, seed=0)
     # The replica-boot sequence (serve/pool.py): cache first, warmup only
@@ -111,7 +116,7 @@ def main() -> int:
 
     root = tempfile.mkdtemp(prefix="vmt_aot_smoke_")
     env = {"AOT_SMOKE_AOT_DIR": os.path.join(root, "aot"),
-           "AOT_SMOKE_XLA_DIR": os.path.join(root, "xla")}
+           "JAX_COMPILATION_CACHE_DIR": os.path.join(root, "xla")}
     cold = _run_boot(env)
     warm = _run_boot(env)
     ratio = warm["wall_s"] / max(cold["wall_s"], 1e-9)

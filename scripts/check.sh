@@ -98,7 +98,8 @@ PY
 
 # Analyzer wall time for the whole static block above (strict scan +
 # baseline hygiene + three surface gates): the tier count keeps growing,
-# so full-scan latency regressions gate like bench regressions.
+# so full-scan latency regressions gate like bench regressions
+# (appended only where $VMT_PERF_LEDGER names a ledger file).
 python scripts/perf_ledger.py append lint \
   "wall_s=$(python -c "import time; print(f'{time.perf_counter() - $lint_t0:.3f}')")" \
   || true

@@ -64,7 +64,11 @@ def main() -> None:
         model=ViLBertConfig().tiny(),
         engine=EngineConfig(max_text_len=16, max_regions=9, num_features=8,
                             image_buckets=(1, 2, 4),
-                            compute_dtype="float32"),
+                            compute_dtype="float32",
+                            # CPU demo: XLA attention (the Pallas kernels
+                            # compile only for a TPU).
+                            use_pallas_coattention=False,
+                            use_pallas_self_attention=False),
         serving=ServingConfig(
             queue_db_path=f"{ROOT}/queue.sqlite3",
             results_db_path=f"{ROOT}/results.sqlite3",

@@ -14,6 +14,10 @@ into the same ``fleet.sqlite3``, then interrogate the app's HTTP face:
 Appends a perf-ledger entry (boot + fleet-query latency) so fleet-plane
 cost drift surfaces in ``perf_ledger.py check``, not a pager.
 
+CPU-only dryrun: this process pins the CPU platform before ServeApp touches
+JAX, and the peer process imports no JAX at all — neither ever holds (or
+waits for) a chip.
+
 Usage: python scripts/fleet_smoke.py [--out FLEET_SMOKE.json]
 """
 
@@ -69,6 +73,10 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--out", default="FLEET_SMOKE.json")
     args = p.parse_args(argv)
+
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
     from vilbert_multitask_tpu import obs
     from vilbert_multitask_tpu.serve.app import ServeApp

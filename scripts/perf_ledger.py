@@ -1,4 +1,8 @@
-"""Perf-ledger CLI: read, append to, and gate on PERF_LEDGER.jsonl.
+"""Perf-ledger CLI: read, append to, and gate on a perf ledger file.
+
+The file is ``--path`` or ``$VMT_PERF_LEDGER``; with neither, reads are
+empty and appends write nothing (the repo's PERF_LEDGER.jsonl belongs to
+the benchmark driver, not to this program).
 
 The ledger (obs/ledger.py) is the append-only sequence of headline
 numbers every bench/soak/smoke run leaves behind — one JSON line per run,
@@ -100,8 +104,8 @@ def cmd_append(args) -> int:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--path", default=None,
-                   help="ledger file (default: repo-root PERF_LEDGER.jsonl "
-                        "or $VMT_PERF_LEDGER)")
+                   help="ledger file (default: $VMT_PERF_LEDGER; with "
+                        "neither, nothing is read or written)")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     s = sub.add_parser("show", help="print entries, oldest first")

@@ -96,9 +96,10 @@ def main() -> int:
             f"(span {span:.3f})")
         maxdiffs[spec.head] = round(diff, 5)
 
-    # The knee the bench sweep brackets: int8's fewer weight bytes must
-    # flip the roofline verdict at a strictly smaller batch.
-    kind = jax.devices()[0].device_kind
+    # The analytic knee on a NAMED chip (this smoke runs on CPU, which has
+    # no entry in the peak tables): int8's fewer weight bytes must flip
+    # the v5e roofline verdict at a strictly smaller batch.
+    kind = "TPU v5e"
     knee32 = knee_rows(model, ecfg, kind, b32)
     kneeq = knee_rows(model, ecfg, kind, bq)
     assert 1 <= kneeq < knee32, (kneeq, knee32)
@@ -112,6 +113,7 @@ def main() -> int:
         "param_bytes_int8": bq,
         "bytes_ratio": round(ratio, 4),
         "head_maxdiff": maxdiffs,
+        "knee_chip": kind,
         "knee_rows_f32": knee32,
         "knee_rows_int8": kneeq,
         "weight_bytes_per_row_int8": wpr,

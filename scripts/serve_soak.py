@@ -66,14 +66,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 # config_fingerprint() of the run's FrameworkConfig, stamped by _build_cfg:
-# every PERF_LEDGER.jsonl entry this script appends carries the real
+# every perf-ledger entry this script appends carries the real
 # fingerprint, so cross-round baselines only compare like configs.
 _FP: "str | None" = None
 
 
 def _ledger_verdict(report: dict, verdict: bool,
                     prefix: str = "soak.") -> None:
-    """Append this run's verdict line to PERF_LEDGER.jsonl (best-effort:
+    """Append this run's verdict line to the $VMT_PERF_LEDGER ledger (best-effort:
     the artifact file is the soak's contract; a read-only checkout must
     not fail the run). Variants ledger under distinct metric names —
     full-model and chaos runs have different latency shapes than the CI
@@ -313,7 +313,6 @@ class DryrunEngine:
         self.killed = False
         self.mesh = None
         self.pallas_enabled = False
-        self.kernel_fallback = False
         self.stage_times = {}
         self.input_cache_stats = {}
         self.service_s = service_ms_per_row / 1e3
@@ -877,7 +876,7 @@ def _ledger_autoscale(report: dict, verdict: bool) -> None:
 
 
 # One warm AOT-cache replica boot costs ~2.6 s on the serving config
-# (PERF_LEDGER ``aot.boot``): the ISSUE's promptness bar — capacity must
+# (perf-ledger ``aot.boot``): the ISSUE's promptness bar — capacity must
 # exist within one boot latency of the sustained-breach decision.
 _AOT_BOOT_BAR_S = 2.6
 

@@ -55,7 +55,12 @@ def main(argv=None) -> int:
 
     cfg = FrameworkConfig()
     if args.tiny:
-        cfg = dataclasses.replace(cfg, model=cfg.model.tiny())
+        # CPU smoke: tiny model on XLA attention (the Pallas kernels
+        # compile only for a TPU).
+        cfg = dataclasses.replace(
+            cfg, model=cfg.model.tiny(), engine=dataclasses.replace(
+                cfg.engine, use_pallas_coattention=False,
+                use_pallas_self_attention=False))
     # Size the device input cache to the gallery: the protocol's whole
     # economy is gallery features staying resident (~0.4 MB bf16/image —
     # a 1k gallery is ~0.4 GB of a 16 GB HBM). The 64-entry serving

@@ -341,8 +341,9 @@ def test_vmt119_composed_through_call_chain():
 
 
 def test_vmt119_regression_real_engine_runtime_not_flagged():
-    # Ground truth: engine/runtime.py's _fallback_lock → _compile_lock
-    # ordering is one-way by design.  The detector must stay silent on it.
+    # Ground truth: engine/runtime.py's lock nesting (_input_cache_lock,
+    # _compile_lock, _boot_lock, the per-program locks) is one-way by
+    # design.  The detector must stay silent on it.
     path = os.path.join(REPO, "vilbert_multitask_tpu", "engine",
                         "runtime.py")
     with open(path, "r", encoding="utf-8") as fh:

@@ -45,6 +45,12 @@ def _total_compiles() -> float:
     return sum(runtime._COMPILES.collect().values())
 
 
+def _failures(event: str) -> float:
+    """Swallowed-failure count for one event — what chip_smoke.py requires
+    to stay flat, since no flight recorder is installed during boot."""
+    return aotcache._FAILURES.collect().get((event,), 0.0)
+
+
 def test_record_key_matches_manifest_grammar():
     key = aotcache.record_key("rows", 8, "bfloat16", True, "dp-1.tp1.sp1",
                               False)
@@ -56,11 +62,14 @@ def test_record_key_matches_manifest_grammar():
 def test_fingerprint_discriminates(tiny_config):
     cfg = FrameworkConfig(model=tiny_config)
     fp = aotcache.compile_fingerprint(cfg)
-    # model_gen folds into the hash (a degraded engine must not share
-    # entries with the pristine one), and any compile-relevant knob flip
-    # lands in a different cache generation.
-    assert aotcache.fingerprint_hash(fp) != aotcache.fingerprint_hash(
-        fp, model_gen=1)
+    # Any compile-relevant knob flip lands in a different cache
+    # generation (the Pallas flags included: an XLA-attention program must
+    # never be served to an engine configured for the kernels).
+    xla = dataclasses.replace(
+        cfg, engine=dataclasses.replace(cfg.engine,
+                                        use_pallas_coattention=False))
+    assert (aotcache.fingerprint_hash(aotcache.compile_fingerprint(xla))
+            != aotcache.fingerprint_hash(fp))
     other = dataclasses.replace(
         cfg, engine=dataclasses.replace(cfg.engine, param_dtype="bfloat16"))
     assert (aotcache.fingerprint_hash(aotcache.compile_fingerprint(other))
@@ -82,7 +91,7 @@ def test_round_trip_zero_compiles(tiny_config, tmp_path):
     stats = cold.live_stats()
     assert stats["engine_aot_compiled"] == 1.0
     assert stats["engine_aot_hits"] == 0.0
-    assert cold._aot.entry_count(cold._model_gen) == 1
+    assert cold._aot.entry_count() == 1
     assert stats.get("engine_boot_compile_s", 0.0) > 0.0
     _, ref = cold.run(cold.prepare(1, "what is this", _regions()))
 
@@ -90,6 +99,7 @@ def test_round_trip_zero_compiles(tiny_config, tmp_path):
     # fast-boot contract is ZERO traces/compiles for manifest-covered
     # programs (ISSUE acceptance).
     before = _total_compiles()
+    fell_back = _failures("exec_fallback")
     warm = InferenceEngine(cfg, params=cold.params, seed=0)
     assert warm.boot_from_cache() is True
     stats = warm.live_stats()
@@ -108,6 +118,7 @@ def test_round_trip_zero_compiles(tiny_config, tmp_path):
                                [a["confidence"] for a in ref.answers],
                                rtol=1e-5)
     assert warm.live_stats()["engine_aot_fallbacks"] == 0.0
+    assert _failures("exec_fallback") == fell_back
     assert _total_compiles() == before
 
 
@@ -124,8 +135,11 @@ def test_corrupt_entry_misses_and_recompiles(tiny_config, tmp_path):
 
     # A poisoned entry must cost a recompile, never a broken engine:
     # load fails -> miss -> compile -> the entry is rewritten healthy.
+    # Swallowed, but counted.
+    before = _failures("load_failed")
     warm = InferenceEngine(cfg, params=cold.params, seed=0)
     assert warm.boot_from_cache() is False
+    assert _failures("load_failed") == before + 1
     warm.warmup()
     stats = warm.live_stats()
     assert stats["engine_aot_compiled"] == 1.0

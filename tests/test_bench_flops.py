@@ -9,8 +9,10 @@ import pytest
 
 from vilbert_multitask_tpu.config import EngineConfig, FrameworkConfig
 from vilbert_multitask_tpu.engine.flops import (
+    knee_rows,
     peak_flops_for,
     serving_forward_flops,
+    serving_roofline,
 )
 from vilbert_multitask_tpu.engine.runtime import InferenceEngine
 
@@ -58,10 +60,24 @@ def test_peak_lookup():
     assert np.isfinite(peak_flops_for("TPU v6 lite"))
 
 
+def test_roofline_refuses_a_device_it_does_not_know(tiny_config):
+    """An unknown device kind is an error, never a silently substituted
+    reference chip; a caller that wants a named chip's analytic roofline
+    off that chip passes the chip's name."""
+    e = EngineConfig()
+    for kind in ("cpu", "TPU v99"):
+        with pytest.raises(ValueError, match="no peak"):
+            knee_rows(tiny_config, e, kind, 10**6)
+        with pytest.raises(ValueError, match="no peak"):
+            serving_roofline(tiny_config, e, 8, kind, 10**6)
+    assert knee_rows(tiny_config, e, "TPU v5e", 10**6) >= 1
+    assert serving_roofline(tiny_config, e, 8, "TPU v5 lite",
+                            10**6)["achievable_mfu"] > 0
+
+
 def test_bench_sweep_parse_is_forgiving():
     """A malformed BENCH_SWEEP_ROWS env var must degrade to 'no sweep',
-    never raise: the parse runs at bench.py import time, before the
-    orchestrator's always-emit-JSON kill trap exists."""
+    never raise: the parse runs at bench.py import time."""
     import importlib.util
     import pathlib
 

@@ -85,33 +85,38 @@ def test_engine_device_pins_host_params(tiny_config):
         assert isinstance(leaf, jax.Array) and not isinstance(leaf, np.ndarray)
 
 
-def test_warmup_falls_back_to_xla_when_kernel_rejected(tiny_config,
+def test_kernel_compile_error_propagates_out_of_warmup(tiny_config,
                                                        monkeypatch):
-    """Pallas is default-on; if Mosaic rejects the kernel on some backend,
-    warmup() must degrade the engine to XLA attention and keep serving —
-    for EVERY consumer (ServeApp, evals, bench), not just the benchmark."""
+    """There is no automatic XLA fallback: a kernel the compiler refuses
+    fails warmup() — and so the boot — with the compiler's own message,
+    and the engine stays on the path its config names. The remedy is the
+    explicit ``use_pallas_*`` flag, never a silent re-route."""
     from vilbert_multitask_tpu.ops import coattention
-
-    def boom(*a, **k):
-        raise RuntimeError("Mosaic rejected the kernel (simulated)")
 
     cfg = FrameworkConfig(
         model=tiny_config,
         engine=EngineConfig(compute_dtype="float32", max_regions=11),
     )
-    # Construction must never compile the kernel (init runs through an XLA
-    # twin), so the engine builds fine even where Mosaic would reject it...
-    monkeypatch.setattr(coattention, "flash_cross_attention", boom)
+    # Construction never compiles the kernel (init runs through an XLA
+    # twin), so the engine builds; the refusal surfaces at warmup.
     eng = InferenceEngine(cfg, seed=0)
-    assert eng.pallas_enabled and not eng.kernel_fallback
-    # ...and ANY first forward degrades — here a live request on an un-warmed
-    # engine (the evals-harness / --no-warmup path), not just warmup().
-    regions = make_regions(1, feat_dim=cfg.model.v_feature_size)
-    _, result = eng.run(eng.prepare(1, "what is the man holding", regions))
-    assert result.answers
-    assert eng.kernel_fallback
-    assert not eng.pallas_enabled  # rebuilt model runs XLA attention
-    eng.warmup(buckets=(1, 2))  # further compiles stay on the XLA path
+    assert eng.pallas_enabled
+    # Pallas on, interpret not requested, backend not a TPU: an error, not
+    # a slow interpreted success.
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        eng.warmup(buckets=(1,), parallel=False)
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic rejected the kernel (simulated)")
+
+    monkeypatch.setattr(coattention, "flash_cross_attention", boom)
+    with pytest.raises(RuntimeError, match="Mosaic rejected the kernel"):
+        eng.warmup(buckets=(2, 4))  # parallel pool: first error wins
+    # ...and on a live request against an un-warmed bucket, the same way.
+    regions = make_regions(2, feat_dim=cfg.model.v_feature_size)
+    with pytest.raises(RuntimeError, match="Mosaic rejected the kernel"):
+        eng.run(eng.prepare(7, "a man riding a horse", regions))
+    assert eng.pallas_enabled  # nothing was rebuilt behind the caller
 
 
 def test_vocab_overflow_fails_at_boot(tiny_config, caplog):

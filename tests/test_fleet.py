@@ -322,6 +322,26 @@ def test_ledger_append_read_and_direction(tmp_path):
     assert key_direction("git_rev") is None  # meta, never gated
 
 
+def test_ledger_is_opt_in(tmp_path, monkeypatch):
+    """With VMT_PERF_LEDGER unset nothing is appended anywhere — the
+    repo-root PERF_LEDGER.jsonl is the benchmark driver's file, and no
+    test, smoke or bench run may touch it. Set, the variable names the
+    file."""
+    from vilbert_multitask_tpu.obs.ledger import default_ledger_path
+
+    monkeypatch.delenv("VMT_PERF_LEDGER", raising=False)
+    monkeypatch.chdir(tmp_path)
+    assert default_ledger_path() is None
+    entry = append_entry("m", {"value": 1.0})
+    assert entry["value"] == 1.0  # still returned for the caller's report
+    assert list(tmp_path.iterdir()) == []
+    assert read_entries() == [] and check()["verdict"] == "empty"
+
+    monkeypatch.setenv("VMT_PERF_LEDGER", str(tmp_path / "mine.jsonl"))
+    append_entry("m", {"value": 2.0})
+    assert [e["value"] for e in read_entries()] == [2.0]
+
+
 def test_ledger_check_verdicts(tmp_path):
     path = str(tmp_path / "PERF_LEDGER.jsonl")
     assert check(path)["verdict"] == "empty"

@@ -1,11 +1,14 @@
 """Pallas flash co-attention vs the XLA reference path.
 
-On CPU the kernel runs in interpreter mode (auto-selected), so these tests
-validate the exact blockwise online-softmax math everywhere; on TPU the same
-code compiles via Mosaic.
+These tests run on CPU, so every call says ``interpret=True`` (or sets
+``pallas_interpret`` on the module/config) — the explicit choice; nothing
+infers it from the backend. They validate the exact blockwise online-softmax
+math. The same kernel compiled by Mosaic (``interpret=False``, bf16, the
+three serving geometries) is checked on the chip by ``chip_smoke.py``.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +37,7 @@ def test_matches_xla_reference_serving_shapes():
     mask = mask.at[:, 0].set(1)
     bias = mask_to_bias(mask)
     ref, _ = multi_head_attention(q, k, v, bias)
-    out = flash_cross_attention(q, k, v, bias)
+    out = flash_cross_attention(q, k, v, bias, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -47,7 +50,8 @@ def test_blockwise_path_multiple_kv_blocks():
     mask = jnp.ones((B, Nk), jnp.int32)
     bias = mask_to_bias(mask)
     ref, _ = multi_head_attention(q, k, v, bias)
-    out = flash_cross_attention(q, k, v, bias, block_q=8, block_k=64)
+    out = flash_cross_attention(q, k, v, bias, block_q=8, block_k=64,
+                                interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -59,11 +63,13 @@ def test_masked_keys_do_not_leak():
     q, k, v = _rand_qkv(rng, B, Nq, Nk, H, D)
     mask = jnp.concatenate(
         [jnp.ones((B, 25), jnp.int32), jnp.zeros((B, 15), jnp.int32)], axis=1)
-    out_full = flash_cross_attention(q, k, v, mask_to_bias(mask))
+    out_full = flash_cross_attention(q, k, v, mask_to_bias(mask),
+                                     interpret=True)
     # Same computation with garbage in the masked tail.
     k2 = k.at[:, 25:].set(1e3)
     v2 = v.at[:, 25:].set(-1e3)
-    out_garbage = flash_cross_attention(q, k2, v2, mask_to_bias(mask))
+    out_garbage = flash_cross_attention(q, k2, v2, mask_to_bias(mask),
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out_full), np.asarray(out_garbage),
                                atol=1e-5)
 
@@ -71,7 +77,8 @@ def test_masked_keys_do_not_leak():
 def test_model_parity_pallas_vs_xla(tiny_config, rng):
     """Full trunk forward: Pallas co-attention ≡ XLA co-attention."""
     cfg_x = tiny_config
-    cfg_p = dataclasses.replace(cfg_x, use_pallas_coattention=True)
+    cfg_p = dataclasses.replace(cfg_x, use_pallas_coattention=True,
+                                pallas_interpret=True)
     B, Nt, Nv = 2, 10, 7
     nrng = np.random.default_rng(3)
     args = (
@@ -110,7 +117,8 @@ def test_self_attention_pallas_matches_xla(rng):
     mask = jnp.ones((B, N), jnp.int32).at[:, 17:].set(0)
     bias = mask_to_bias(mask)
     mod_x = FusedSelfAttention(hidden_size=H, num_heads=2, use_pallas=False)
-    mod_p = FusedSelfAttention(hidden_size=H, num_heads=2, use_pallas=True)
+    mod_p = FusedSelfAttention(hidden_size=H, num_heads=2, use_pallas=True,
+                               pallas_interpret=True)
     params = mod_x.init(rng, x, bias)["params"]
     ref, probs = mod_x.apply({"params": params}, x, bias)
     out, none_probs = mod_p.apply({"params": params}, x, bias)
@@ -134,7 +142,7 @@ def test_self_attention_kernel_at_visual_stream_geometry(rng):
     mod_x = FusedSelfAttention(hidden_size=H, num_heads=heads,
                                use_pallas=False)
     mod_p = FusedSelfAttention(hidden_size=H, num_heads=heads,
-                               use_pallas=True)
+                               use_pallas=True, pallas_interpret=True)
     params = mod_x.init(rng, x, bias)["params"]
     ref, _ = mod_x.apply({"params": params}, x, bias)
     out, probs = mod_p.apply({"params": params}, x, bias)
@@ -153,24 +161,41 @@ def test_self_attention_kernel_at_visual_stream_geometry(rng):
     assert probs_t is not None  # stayed on XLA as designed
 
 
-def test_mosaic_compiles_kernel_on_tpu():
-    """TPU-only (skips on the CPU-pinned test backend): the kernel must
-    COMPILE under Mosaic — interpret=False — and match XLA at the serving
-    geometry. bench.py exercises this on hardware every round
-    (BENCH_r03: pallas_coattention=true); this pins it as a test artifact
-    wherever a chip is visible."""
+def test_kernel_under_a_mesh_runs_through_shard_map():
+    """A partitioned program cannot contain a bare Mosaic call (XLA refuses
+    to partition it), so under a mesh the kernel runs through shard_map:
+    rows over dp and heads over tp where the axes divide them — the rule
+    parallel/sharding.py places batches by — replicated otherwise."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dp", "tp"))
+    rng = np.random.default_rng(5)
+    sharded_kernel = jax.jit(functools.partial(
+        flash_cross_attention, interpret=True, mesh=mesh))
+    for B in (8, 2, 10):  # dp=4 divides 8; 2 and 10 stay replicated
+        q, k, v = _rand_qkv(rng, B, 12, 9, 4, 16)
+        bias = mask_to_bias(jnp.ones((B, 9), jnp.int32).at[:, 7:].set(0))
+        ref, _ = multi_head_attention(q, k, v, bias)
+        rows = NamedSharding(mesh, P("dp") if B % 4 == 0 else P())
+        out = sharded_kernel(
+            *(jax.device_put(x, rows) for x in (q, k, v, bias)))
+        assert out.sharding.spec == P("dp" if B % 4 == 0 else None,
+                                      None, "tp")
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_off_tpu_without_interpret_is_an_error():
+    """The default compiles under Mosaic: off-TPU that raises instead of
+    quietly running the Pallas interpreter (the serving path must never
+    turn a missing chip into a slow success)."""
     import pytest
 
-    if jax.default_backend() != "tpu":
-        pytest.skip("needs a real TPU backend (Mosaic)")
     rng = np.random.default_rng(0)
-    B, Nq, Nk, H, D = 2, 38, 101, 8, 128
-    q, k, v = _rand_qkv(rng, B, Nq, Nk, H, D)
-    bias = mask_to_bias(jnp.ones((B, Nk), jnp.int32))
-    ref, _ = multi_head_attention(q, k, v, bias)
-    out = flash_cross_attention(q, k, v, bias, interpret=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-2, rtol=2e-2)  # bf16-class tolerance
+    q, k, v = _rand_qkv(rng, 1, 8, 8, 1, 128)
+    bias = mask_to_bias(jnp.ones((1, 8), jnp.int32))
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        flash_cross_attention(q, k, v, bias)
 
 
 def test_pretraining_heads_skippable(tiny_config, rng):
@@ -203,7 +228,8 @@ def test_pretraining_heads_skippable(tiny_config, rng):
 def test_attention_maps_still_available_with_pallas_config(tiny_config, rng):
     """The visualization contract (reference worker.py:288) falls back to the
     probs-returning XLA path even when the Pallas flag is on."""
-    cfg_p = dataclasses.replace(tiny_config, use_pallas_coattention=True)
+    cfg_p = dataclasses.replace(tiny_config, use_pallas_coattention=True,
+                                pallas_interpret=True)
     B, Nt, Nv = 1, 6, 5
     args = (
         jnp.zeros((B, Nt), jnp.int32),

@@ -76,6 +76,25 @@ def make_pool(n=2, serving=None, **serving_overrides):
     return pool
 
 
+# ------------------------------------------------------------------- boot
+def test_warmup_raises_when_no_replica_comes_up():
+    """One bad replica does not sink the boot; a pool in which NONE booted
+    re-raises the warmup error (a compiler refusing a kernel, say) instead
+    of starting a server that can answer nothing."""
+    class Refused(FakeEngine):
+        def warmup(self, buckets=None, parallel=None):
+            raise RuntimeError("Mosaic rejected the kernel (simulated)")
+
+    mixed = ReplicaPool([Refused(), FakeEngine()], serving=ServingConfig())
+    mixed.warmup()
+    assert [r.state for r in mixed.replicas] == [STATE_DEAD, STATE_READY]
+
+    dead = ReplicaPool([Refused(), Refused()], serving=ServingConfig())
+    with pytest.raises(RuntimeError, match="Mosaic rejected the kernel"):
+        dead.warmup()
+    assert all(r.state == STATE_DEAD for r in dead.replicas)
+
+
 # ---------------------------------------------------------------- routing
 def test_routing_skips_non_ready_replicas():
     pool = make_pool(3, pool_checkout_timeout_s=0.2)

@@ -13,8 +13,8 @@ witness ROADMAP item 3(a) needs before the multi-process soak lands:
   which a lost nack/release write would skew;
 - exactly one terminal per job, and the queue drains to empty.
 
-Throughput lands in PERF_LEDGER.jsonl as ``txn.stress`` so cross-process
-claim rate has a tracked baseline.
+Throughput lands as a ``txn.stress`` ledger line under ``tmp_path`` (a test
+never writes the repo's PERF_LEDGER.jsonl — that file is the driver's).
 """
 
 import os
@@ -127,4 +127,4 @@ def test_two_process_claim_nack_release_ack_exactly_once(tmp_path):
         "claims_per_s": round(total_claims / elapsed, 2),
         "jobs": JOBS,
         "processes": len(workers),
-    }, extra={"verdict": "pass"})
+    }, path=str(tmp_path / "ledger.jsonl"), extra={"verdict": "pass"})

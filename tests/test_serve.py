@@ -620,7 +620,7 @@ def test_healthz_reports_boot_info(stack):
         assert before["ok"] is True and before["boot"] == {}
         # ServeApp mutates the shared dict as boot stages finish.
         boot.update(engine_init_s=1.2, warmup_s=3.4, buckets=[1, 2],
-                    pallas=True, kernel_fallback=False)
+                    pallas=True)
         conn.request("GET", "/healthz")
         after = json.loads(conn.getresponse().read())
         assert after["boot"]["warmup_s"] == 3.4
@@ -637,8 +637,7 @@ def test_parallel_warmup_compiles_all_buckets(tiny_framework_cfg, engine):
     engine.warmup(parallel=True)
     for b in tiny_framework_cfg.engine.image_buckets:
         # single-device serving runs the per-row program (engine._forward_rows)
-        assert ("rows", b, False, engine._model_gen) in engine._compiled
-    assert not engine.kernel_fallback
+        assert ("rows", b, False) in engine._compiled
 
 
 # ------------------------------------------------------- mesh-aware binary
@@ -656,6 +655,10 @@ def test_serveapp_serves_through_mesh(tiny_framework_cfg, features_dir,
     assert jax.device_count() >= 8  # conftest virtual mesh
     cfg = dataclasses.replace(
         tiny_framework_cfg,
+        # Hermetic AOT cache: ServeApp's default is a fixed directory in
+        # the checkout, which would carry executables across test runs.
+        engine=dataclasses.replace(tiny_framework_cfg.engine,
+                                   aot_cache_dir=str(tmp_path / "aot")),
         serving=dataclasses.replace(
             tiny_framework_cfg.serving,
             queue_db_path=str(tmp_path / "q.sqlite3"),
@@ -1070,6 +1073,10 @@ def test_serveapp_start_exposes_build_info_uptime_and_recorder(
 
     cfg = dataclasses.replace(
         tiny_framework_cfg,
+        # Hermetic AOT cache: ServeApp's default is a fixed directory in
+        # the checkout, which would carry executables across test runs.
+        engine=dataclasses.replace(tiny_framework_cfg.engine,
+                                   aot_cache_dir=str(tmp_path / "aot")),
         serving=dataclasses.replace(
             tiny_framework_cfg.serving,
             queue_db_path=str(tmp_path / "q.sqlite3"),

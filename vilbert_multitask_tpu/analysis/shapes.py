@@ -1,7 +1,7 @@
 """Abstract shape/dtype interpretation — the fourth analyzer tier.
 
-The engine's compile cache is keyed by ``(program, bucket, attn,
-model_gen)`` and the AOT roadmap wants executables persisted per
+The engine's compile cache is keyed by ``(program, bucket, attn)``
+and the AOT roadmap wants executables persisted per
 (bucket, dtype, fused/quant mode, topology) — but nothing before this
 module could *enumerate* that key universe or prove it bounded. This is
 the domain that can: symbolic dimensions bound to config knobs

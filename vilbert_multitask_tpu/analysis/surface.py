@@ -85,8 +85,7 @@ class ProgramFamily:
             "key_witness": _witness(
                 self.path, self.line,
                 f"compile-cache key built here: "
-                f"(\"{self.family}\", {', '.join(self.key_params)}, "
-                f"model_gen)"),
+                f"(\"{self.family}\", {', '.join(self.key_params)})"),
             "jit_static_args": list(self.static_args),
             "key_params": list(self.key_params),
             "static_origins": self.static_origins,
@@ -170,7 +169,7 @@ def discover_programs(project) -> List[ProgramFamily]:
 # dispatch funnels that forward a (bucket, collect_attention) prefix
 # verbatim — the provenance that matters is at the mouth of the funnel,
 # not the passthrough hops.
-_FUNNELS = ("_call_forward", "_run_rows", "_dispatch_forward")
+_FUNNELS = ("_call_forward", "_run_rows")
 
 
 def collect_static_origins(project, programs: List[ProgramFamily],
@@ -268,16 +267,6 @@ def _find_def(project, name: str) -> Optional[Tuple[str, int]]:
         for node in ast.walk(mod.ctx.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) \
                     and node.name == name:
-                return mod.ctx.rel_path, node.lineno
-    return None
-
-
-def _find_attr_augassign(project, attr: str) -> Optional[Tuple[str, int]]:
-    for mod in sorted(project.modules.values(), key=lambda m: m.name):
-        for node in ast.walk(mod.ctx.tree):
-            if isinstance(node, ast.AugAssign) \
-                    and isinstance(node.target, ast.Attribute) \
-                    and node.target.attr == attr:
                 return mod.ctx.rel_path, node.lineno
     return None
 
@@ -380,16 +369,6 @@ def build_surface(project) -> dict:
                             })
     records.sort(key=lambda r: r["key"])
 
-    gen = _find_attr_augassign(project, "_model_gen")
-    model_gen = {
-        "note": ("the key's generation counter: bumped on kernel-fallback "
-                 "rebuild, which clears the cache — it versions programs "
-                 "within a process, it does not widen the universe"),
-    }
-    if gen is not None:
-        model_gen["witness"] = _witness(
-            gen[0], gen[1], "generation bump on degrade-to-XLA")
-
     return {
         "version": SURFACE_VERSION,
         "generator": "vmtlint surface",
@@ -401,7 +380,6 @@ def build_surface(project) -> dict:
             "collect_attention": attn,
             "topologies": topologies,
         },
-        "model_gen": model_gen,
         "record_count": len(records),
         "records": records,
     }

@@ -21,22 +21,27 @@ Entry names are the manifest record keys (``analysis/surface.py``
 ``__``. The fingerprint directory is what makes stale entries MISS instead
 of poisoning: it hashes everything that changes the compiled program but
 is not in the record key — jax/jaxlib versions, backend, device kind, the
-actual mesh shape, ``model_gen`` (the kernel-fallback generation), and the
-compile-relevant config sections. A new jaxlib, a degraded engine, or a
-resized model lands in a different directory and recompiles cleanly;
-nothing ever deserializes an executable built for a different world.
+actual mesh shape, the compile-relevant config sections, and a digest of
+this package's source (an executable is a build product of the model code:
+an edited layer under an unchanged config must miss too). A new jaxlib, a
+resized model or a code change lands in a different directory and
+recompiles cleanly; nothing ever deserializes an executable built for a
+different world.
 
 Each ``.aotx`` file is one pickle of ``{payload, in_tree, out_tree,
 fingerprint, key}`` — the exact triple ``deserialize_and_load`` needs
 (PyTreeDefs of dict/tuple/None trees pickle fine). Loads verify the
 embedded fingerprint as belt-and-braces over the directory hash; any
 read/unpickle/deserialize failure is a clean miss (recompile-and-overwrite
-heals it), never a crash.
+heals it), never a crash — but never a silent one either: every swallowed
+failure books ``vmt_aot_cache_failures_total{event}`` (:func:`record_failure`),
+which ``chip_smoke.py`` requires to stay at zero.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -46,6 +51,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
+import jaxlib
 
 from vilbert_multitask_tpu import obs
 
@@ -58,8 +64,7 @@ FINGERPRINT_BASENAME = "fingerprint.json"
 # buckets, dtypes, fused mode, kernel flags, slab sizing) stays in the
 # fingerprint — a drifted value must miss.
 _NON_COMPILE_ENGINE_KNOBS = frozenset({
-    "vocab_path", "labels_root", "compilation_cache_dir", "aot_cache_dir",
-    "persistent_cache_min_compile_secs", "parallel_warmup",
+    "vocab_path", "labels_root", "aot_cache_dir", "parallel_warmup",
 })
 
 _HITS = obs.REGISTRY.counter(
@@ -78,19 +83,42 @@ _COMPILE_MS = obs.REGISTRY.histogram(
     "lower+compile time per cache miss (ms).")
 
 
+_FAILURES = obs.REGISTRY.counter(
+    "vmt_aot_cache_failures_total",
+    "Swallowed AOT-cache failures: store_failed / load_failed (a stale or "
+    "corrupt entry) / exec_fallback (a deserialized executable refused "
+    "its first call and the jitted forward recompiled).",
+    labelnames=("event",))
+
+
 def record_compile_ms(ms: float) -> None:
     """Book one miss-path lower+compile duration (the compile itself runs
     engine-side, next to the jit machinery, so the runtime calls this)."""
     _COMPILE_MS.observe(ms)
 
 
-def _jaxlib_version() -> str:
-    try:
-        import jaxlib
+def record_failure(event: str, **detail) -> None:
+    """Count one swallowed cache failure and offer it to the flight
+    recorder (``aot_cache_<event>``). The counter is what makes the failure
+    visible when no recorder is installed — boot runs before
+    ``ServeApp.start()`` installs one."""
+    _FAILURES.inc(event=event)
+    obs.record_event(f"aot_cache_{event}", **detail)
 
-        return getattr(jaxlib, "__version__", jax.__version__)
-    except Exception:  # noqa: BLE001 — version probing must never fail boot
-        return jax.__version__
+
+@functools.lru_cache(maxsize=1)
+def _source_digest() -> str:
+    """Short hash over every ``.py`` file of this package (path + bytes)."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for root, dirs, files in os.walk(pkg):
+        dirs.sort()
+        for name in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(root, name)
+            h.update(os.path.relpath(path, pkg).encode())
+            with open(path, "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()[:16]
 
 
 def topology_id(mesh_cfg) -> str:
@@ -123,7 +151,8 @@ def compile_fingerprint(cfg, *, mesh=None, heads: bool = True
     dev = jax.devices()[0]
     return {
         "jax": jax.__version__,
-        "jaxlib": _jaxlib_version(),
+        "jaxlib": jaxlib.__version__,
+        "source": _source_digest(),
         "backend": jax.default_backend(),
         "device_kind": getattr(dev, "device_kind", "unknown"),
         "mesh": ("none" if mesh is None else
@@ -135,13 +164,10 @@ def compile_fingerprint(cfg, *, mesh=None, heads: bool = True
     }
 
 
-def fingerprint_hash(fingerprint: Dict[str, Any], model_gen: int = 0) -> str:
-    """Stable short hash of (fingerprint, model_gen) — the cache
-    subdirectory name. ``model_gen`` folds in here so post-degrade
-    programs (XLA attention after a Mosaic rejection) can never be served
-    to a gen-0 boot that should probe the Pallas path."""
-    blob = json.dumps({**fingerprint, "model_gen": model_gen},
-                      sort_keys=True, default=repr)
+def fingerprint_hash(fingerprint: Dict[str, Any]) -> str:
+    """Stable short hash of the fingerprint — the cache subdirectory
+    name."""
+    blob = json.dumps(fingerprint, sort_keys=True, default=repr)
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
@@ -159,35 +185,41 @@ class AotCache:
     engine down.
     """
 
-    def __init__(self, root: str, fingerprint: Dict[str, Any]):
+    def __init__(self, root: str, fingerprint: Dict[str, Any], *,
+                 mesh=None):
         self.root = os.path.abspath(root)
         self.fingerprint = fingerprint
+        # The devices cached programs execute on: the mesh's, or the one
+        # default device a mesh-less engine jits for. deserialize_and_load
+        # otherwise binds the executable to EVERY local device, and a
+        # single-device program then refuses its first call on a multi-
+        # device host ("expected N shards").
+        self._devices = (list(mesh.devices.flat) if mesh is not None
+                         else jax.devices()[:1])
         self._lock = threading.Lock()
-        # (model_gen, key) → loaded executable: the pool fast path.
-        self._loaded: Dict[Any, Any] = {}
+        # key → loaded executable: the pool fast path.
+        self._loaded: Dict[str, Any] = {}
         # path → raw file bytes, filled by prefetch() while the checkpoint
         # restore runs on another thread (disjoint resources: disk here,
         # network/device there).
         self._prefetched: Dict[str, bytes] = {}
 
     # ------------------------------------------------------------- layout
-    def dir_for(self, model_gen: int = 0) -> str:
-        return os.path.join(self.root,
-                            fingerprint_hash(self.fingerprint, model_gen))
+    def dir(self) -> str:
+        return os.path.join(self.root, fingerprint_hash(self.fingerprint))
 
-    def entry_path(self, key: str, model_gen: int = 0) -> str:
-        return os.path.join(self.dir_for(model_gen), entry_filename(key))
+    def entry_path(self, key: str) -> str:
+        return os.path.join(self.dir(), entry_filename(key))
 
     # ----------------------------------------------------------- prefetch
-    def prefetch(self, keys: Optional[List[str]] = None,
-                 model_gen: int = 0) -> int:
+    def prefetch(self, keys: Optional[List[str]] = None) -> int:
         """Read entry bytes into memory (pure disk I/O — no jax work), so
         boot can overlap this with the checkpoint restore. ``keys=None``
         prefetches every entry in the current fingerprint directory.
         Returns the number of entries buffered."""
-        d = self.dir_for(model_gen)
+        d = self.dir()
         if keys is not None:
-            paths = [self.entry_path(k, model_gen) for k in keys]
+            paths = [self.entry_path(k) for k in keys]
         else:
             try:
                 paths = [os.path.join(d, n) for n in sorted(os.listdir(d))
@@ -207,17 +239,16 @@ class AotCache:
         return n
 
     # ---------------------------------------------------------- load/store
-    def load(self, key: str, *, model_gen: int = 0, program: str = ""):
+    def load(self, key: str, *, program: str = ""):
         """Deserialize-and-load one entry; None on any miss (absent, wrong
         fingerprint, unreadable, undeserializable — all clean)."""
-        memo_key = (model_gen, key)
         with self._lock:
-            if memo_key in self._loaded:
+            if key in self._loaded:
                 _HITS.inc(program=program or key.split("/", 1)[0])
-                return self._loaded[memo_key]
-        path = self.entry_path(key, model_gen)
+                return self._loaded[key]
+        path = self.entry_path(key)
         t0 = time.perf_counter()
-        loaded = self._load_from_disk(path, model_gen)
+        loaded = self._load_from_disk(path)
         program = program or key.split("/", 1)[0]
         if loaded is None:
             _MISSES.inc(program=program)
@@ -225,10 +256,10 @@ class AotCache:
         _HITS.inc(program=program)
         _DESERIALIZE_MS.observe((time.perf_counter() - t0) * 1e3)
         with self._lock:
-            self._loaded[memo_key] = loaded
+            self._loaded[key] = loaded
         return loaded
 
-    def _load_from_disk(self, path: str, model_gen: int):
+    def _load_from_disk(self, path: str):
         with self._lock:
             blob = self._prefetched.pop(path, None)
         if blob is None:
@@ -241,20 +272,19 @@ class AotCache:
             entry = pickle.loads(blob)
             if entry.get("format") != ENTRY_FORMAT:
                 raise ValueError(f"entry format {entry.get('format')!r}")
-            want = {**self.fingerprint, "model_gen": model_gen}
-            if entry.get("fingerprint") != want:
+            if entry.get("fingerprint") != self.fingerprint:
                 raise ValueError("fingerprint mismatch")
             from jax.experimental import serialize_executable as se
 
             return se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=self._devices)
         except Exception as e:  # noqa: BLE001 — stale/corrupt entries are
             # misses by design; the recompile overwrites them.
-            obs.record_event("aot_cache_load_failed", path=path,
-                             error=repr(e))
+            record_failure("load_failed", path=path, error=repr(e))
             return None
 
-    def store(self, key: str, compiled, *, model_gen: int = 0) -> bool:
+    def store(self, key: str, compiled) -> bool:
         """Serialize one compiled executable; atomic write (tmp+rename) so
         a crashed boot never leaves a torn entry. Best-effort: serialization
         or IO failures are recorded and swallowed — the engine already holds
@@ -266,16 +296,16 @@ class AotCache:
             entry = {
                 "format": ENTRY_FORMAT,
                 "key": key,
-                "fingerprint": {**self.fingerprint, "model_gen": model_gen},
+                "fingerprint": self.fingerprint,
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
             }
             blob = pickle.dumps(entry)
-            d = self.dir_for(model_gen)
+            d = self.dir()
             os.makedirs(d, exist_ok=True)
-            self._write_fingerprint(d, model_gen)
-            path = self.entry_path(key, model_gen)
+            self._write_fingerprint(d)
+            path = self.entry_path(key)
             tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
             with open(tmp, "wb") as f:
                 f.write(blob)
@@ -283,11 +313,10 @@ class AotCache:
             return True
         except Exception as e:  # noqa: BLE001 — cache writes must never
             # fail a boot that already compiled its program.
-            obs.record_event("aot_cache_store_failed", key=key,
-                             error=repr(e))
+            record_failure("store_failed", key=key, error=repr(e))
             return False
 
-    def _write_fingerprint(self, d: str, model_gen: int) -> None:
+    def _write_fingerprint(self, d: str) -> None:
         """Human-readable fingerprint next to the entries (debugging aid —
         `why did my cache miss` is answered by diffing two of these)."""
         path = os.path.join(d, FINGERPRINT_BASENAME)
@@ -296,16 +325,16 @@ class AotCache:
         try:
             tmp = f"{path}.tmp.{os.getpid()}"
             with open(tmp, "w", encoding="utf-8") as f:
-                json.dump({**self.fingerprint, "model_gen": model_gen},
-                          f, indent=2, sort_keys=True, default=repr)
+                json.dump(self.fingerprint, f, indent=2, sort_keys=True,
+                          default=repr)
             os.replace(tmp, path)
         except OSError:
             pass
 
     # ------------------------------------------------------- introspection
-    def entry_count(self, model_gen: int = 0) -> int:
+    def entry_count(self) -> int:
         try:
-            return sum(1 for n in os.listdir(self.dir_for(model_gen))
+            return sum(1 for n in os.listdir(self.dir())
                        if n.endswith(ENTRY_SUFFIX))
         except OSError:
             return 0

@@ -122,18 +122,27 @@ PEAK_HBM_BYTES_PER_S = (
     ("v2", 700e9),
 )
 
-# Off-TPU (CPU smoke runs, unknown device kinds) the roofline is still
-# worth stating against a reference chip so TINY bench artifacts carry the
-# same fields as hardware ones — the reason string names the substitution.
-_REFERENCE_CHIP = ("v5e", 197e12, 819e9)
-
-
 def peak_hbm_bw_for(device_kind: str) -> float | None:
     dk = device_kind.lower()
     for key, bw in PEAK_HBM_BYTES_PER_S:
         if key in dk:
             return bw
     return None
+
+
+def _peaks_for(device_kind: str) -> tuple:
+    """(peak FLOP/s, peak HBM bytes/s) for a device in the tables above.
+    A device that is not in them is an error, not a default: a caller that
+    wants the analytic roofline of a NAMED chip off that chip (a CPU smoke
+    reasoning about v5e) passes that chip's name."""
+    peak = peak_flops_for(device_kind)
+    bw = peak_hbm_bw_for(device_kind)
+    if peak is None or bw is None:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth entry for device kind "
+            f"{device_kind!r}; pass the name of a chip in "
+            f"engine/flops.py's tables (e.g. 'TPU v5e')")
+    return peak, bw
 
 
 def param_tree_bytes(params) -> int:
@@ -165,14 +174,10 @@ def knee_rows(mcfg: ViLBertConfig, ecfg: EngineConfig, device_kind: str,
     ``t_compute >= t_mem``. FLOPs are linear in batch
     (:func:`serving_forward_flops`) while the weight-read term is flat, so
     the knee is analytic: ``ceil(param_bytes · peak / (bw · flops_per_row))``.
-    Unknown device kinds (CPU smoke runs) compute against the v5e
-    reference, same substitution as :func:`serving_roofline`."""
+    Raises ``ValueError`` for a device kind the peak tables do not know."""
     import math
 
-    peak = peak_flops_for(device_kind)
-    bw = peak_hbm_bw_for(device_kind)
-    if peak is None or bw is None:
-        _, peak, bw = _REFERENCE_CHIP
+    peak, bw = _peaks_for(device_kind)
     flops_per_row = serving_forward_flops(mcfg, ecfg, 1)
     return max(1, math.ceil(param_bytes * peak / (bw * flops_per_row)))
 
@@ -186,16 +191,10 @@ def serving_roofline(mcfg: ViLBertConfig, ecfg: EngineConfig, batch: int,
     When that ratio is well below 1 the forward is weight-read-bound and
     more MXU (or a measured MFU "gap") is not the story — fewer weight
     bytes (``EngineConfig.param_dtype="bfloat16"``) or bigger batches are.
-    Returns ``{"achievable_mfu", "reason"}``; unknown device kinds compute
-    against the v5e reference so the fields are always present.
+    Returns ``{"achievable_mfu", "reason"}``; raises ``ValueError`` for a
+    device kind the peak tables do not know.
     """
-    peak = peak_flops_for(device_kind)
-    bw = peak_hbm_bw_for(device_kind)
-    note = ""
-    if peak is None or bw is None:
-        ref, peak, bw = _REFERENCE_CHIP
-        note = (f" [no spec table entry for {device_kind!r}; "
-                f"roofline stated against {ref}]")
+    peak, bw = _peaks_for(device_kind)
     flops = serving_forward_flops(mcfg, ecfg, batch)
     t_compute = flops / peak
     t_mem = param_bytes / bw
@@ -210,4 +209,4 @@ def serving_roofline(mcfg: ViLBertConfig, ecfg: EngineConfig, batch: int,
             f"compute-bound at batch {batch}: {t_compute * 1e3:.2f} ms "
             f"compute vs {t_mem * 1e3:.2f} ms weight reads — MFU can "
             f"approach 1.0")
-    return {"achievable_mfu": round(mfu, 4), "reason": reason + note}
+    return {"achievable_mfu": round(mfu, 4), "reason": reason}

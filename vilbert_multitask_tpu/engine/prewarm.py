@@ -134,7 +134,7 @@ def main(argv=None) -> int:
     skip_text = " ".join(f"{k}={v}" for k, v in sorted(skipped.items()))
     print(f"prewarm: hits={counts['hit']} compiled={counts['compiled']} "
           f"skipped=[{skip_text or 'none'}] "
-          f"entries={engine._aot.entry_count(engine._model_gen)} "
+          f"entries={engine._aot.entry_count()} "
           f"init={init_s:.1f}s total={time.perf_counter() - t0:.1f}s")
     return 0
 

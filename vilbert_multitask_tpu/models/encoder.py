@@ -17,7 +17,7 @@ Reference capability: BertEncoder in the external ``vilbert`` package
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, List, Optional, Tuple
+from typing import TYPE_CHECKING, Any, List, Optional, Tuple
 
 import jax.numpy as jnp
 from flax import linen as nn
@@ -38,6 +38,10 @@ class TwoStreamEncoder(nn.Module):
 
     config: ViLBertConfig
     ring_v: Optional["RingContext"] = None
+    # Mesh of a partitioned (multi-chip) program: the Pallas kernels then
+    # run under shard_map (ops/coattention.py). Like ring_v, it cannot live
+    # in ViLBertConfig — that tree is JSON-serializable checkpoint metadata.
+    kernel_mesh: Optional[Any] = None
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
@@ -59,6 +63,8 @@ class TwoStreamEncoder(nn.Module):
                 attention_dropout=cfg.attention_probs_dropout_prob,
                 layer_norm_eps=cfg.layer_norm_eps,
                 use_pallas=cfg.use_pallas_self_attention,
+                pallas_interpret=cfg.pallas_interpret,
+                kernel_mesh=self.kernel_mesh,
                 dtype=self.dtype,
                 name=f"t_layer_{i}",
             )
@@ -74,6 +80,8 @@ class TwoStreamEncoder(nn.Module):
                 attention_dropout=cfg.v_attention_probs_dropout_prob,
                 layer_norm_eps=cfg.layer_norm_eps,
                 use_pallas=cfg.use_pallas_self_attention,
+                pallas_interpret=cfg.pallas_interpret,
+                kernel_mesh=self.kernel_mesh,
                 ring=self.ring_v,
                 dtype=self.dtype,
                 name=f"v_layer_{i}",
@@ -94,6 +102,8 @@ class TwoStreamEncoder(nn.Module):
                 attention_dropout=cfg.attention_probs_dropout_prob,
                 layer_norm_eps=cfg.layer_norm_eps,
                 use_pallas=cfg.use_pallas_coattention,
+                pallas_interpret=cfg.pallas_interpret,
+                kernel_mesh=self.kernel_mesh,
                 dtype=self.dtype,
                 name=f"c_layer_{i}",
             )

@@ -9,7 +9,7 @@ package driven from worker.py:286-289 — re-designed as Flax modules.
 from __future__ import annotations
 
 import functools
-from typing import TYPE_CHECKING, Optional, Tuple
+from typing import TYPE_CHECKING, Any, Optional, Tuple
 
 import jax.numpy as jnp
 from flax import linen as nn
@@ -91,6 +91,8 @@ class TransformerLayer(nn.Module):
     attention_dropout: float = 0.1
     layer_norm_eps: float = 1e-12
     use_pallas: bool = False
+    pallas_interpret: bool = False
+    kernel_mesh: Optional[Any] = None
     ring: Optional["RingContext"] = None
     dtype: jnp.dtype = jnp.float32
 
@@ -101,6 +103,8 @@ class TransformerLayer(nn.Module):
             num_heads=self.num_heads,
             dropout_rate=self.attention_dropout,
             use_pallas=self.use_pallas,
+            pallas_interpret=self.pallas_interpret,
+            kernel_mesh=self.kernel_mesh,
             ring=self.ring,
             dtype=self.dtype,
             name="attention",
@@ -149,6 +153,8 @@ class ConnectionLayer(nn.Module):
     attention_dropout: float = 0.1
     layer_norm_eps: float = 1e-12
     use_pallas: bool = False
+    pallas_interpret: bool = False
+    kernel_mesh: Optional[Any] = None
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -167,6 +173,8 @@ class ConnectionLayer(nn.Module):
             num_heads=self.bi_num_heads,
             dropout_rate=self.attention_dropout,
             use_pallas=self.use_pallas,
+            pallas_interpret=self.pallas_interpret,
+            kernel_mesh=self.kernel_mesh,
             dtype=self.dtype,
             name="text_attends_image",
         )(t_hidden, v_hidden, v_mask_bias, deterministic=deterministic,
@@ -177,6 +185,8 @@ class ConnectionLayer(nn.Module):
             num_heads=self.bi_num_heads,
             dropout_rate=self.attention_dropout,
             use_pallas=self.use_pallas,
+            pallas_interpret=self.pallas_interpret,
+            kernel_mesh=self.kernel_mesh,
             dtype=self.dtype,
             name="image_attends_text",
         )(v_hidden, t_hidden, t_mask_bias, deterministic=deterministic,

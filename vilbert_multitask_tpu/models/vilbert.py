@@ -72,6 +72,7 @@ class ViLBertModel(nn.Module):
 
     config: ViLBertConfig
     ring_v: Optional[Any] = None  # parallel.ring.RingContext — see encoder
+    kernel_mesh: Optional[Any] = None  # jax Mesh — see encoder
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
@@ -79,6 +80,7 @@ class ViLBertModel(nn.Module):
         self.embeddings = TextEmbeddings(cfg, dtype=self.dtype)
         self.v_embeddings = ImageEmbeddings(cfg, dtype=self.dtype)
         self.encoder = TwoStreamEncoder(cfg, ring_v=self.ring_v,
+                                        kernel_mesh=self.kernel_mesh,
                                         dtype=self.dtype)
         self.t_pooler = Pooler(cfg.bi_hidden_size, dtype=self.dtype)
         self.v_pooler = Pooler(cfg.bi_hidden_size, dtype=self.dtype)
@@ -123,15 +125,20 @@ class ViLBertForVLTasks(nn.Module):
     sequence-parallel ring attention on the context's mesh — the
     long-context serving/training path. Dense and ring instances have
     identical param trees (checkpoints are interchangeable).
+    ``kernel_mesh`` is the device mesh of a partitioned program, under
+    which the Pallas kernels run through shard_map.
     """
 
     config: ViLBertConfig
     ring_v: Optional[Any] = None
+    kernel_mesh: Optional[Any] = None
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
         cfg = self.config
-        self.bert = ViLBertModel(cfg, ring_v=self.ring_v, dtype=self.dtype)
+        self.bert = ViLBertModel(cfg, ring_v=self.ring_v,
+                                 kernel_mesh=self.kernel_mesh,
+                                 dtype=self.dtype)
         bi = cfg.bi_hidden_size
         self.vil_prediction = SimpleClassifier(
             bi * 2, cfg.num_labels, cfg.layer_norm_eps, dtype=self.dtype

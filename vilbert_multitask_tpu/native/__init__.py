@@ -2,11 +2,13 @@
 
 Reference capability: the C++/CUDA layer the reference drives through
 ``maskrcnn_benchmark`` (NMS kernel + box selection, reference
-worker.py:51,123-176) and fast feature IO. The library builds on demand with
-the in-image toolchain (``make`` + g++); every entry point has a pure
-JAX/numpy twin (ops/nms.py, features/store.py), so the framework degrades
-gracefully when no compiler is present — ``available()`` gates the fast
-path.
+worker.py:51,123-176) and fast feature IO. The library is built from
+``native/vmt_native.cpp`` by ``make`` (mtime-aware: a no-op when the binary
+is current) on first use in every process — the ``.so`` is untracked, and a
+binary of unknown provenance is never loaded without make's say-so. Every
+entry point has a pure JAX/numpy twin (ops/nms.py, features/store.py), so
+the framework degrades gracefully when no toolchain is present —
+``available()`` gates the fast path.
 """
 
 from __future__ import annotations
@@ -43,7 +45,7 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if not _build():
             _load_failed = True
             return None
         try:

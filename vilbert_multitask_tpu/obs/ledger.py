@@ -1,13 +1,15 @@
-"""Perf ledger: the append-only trajectory behind ``PERF_LEDGER.jsonl``.
+"""Perf ledger: an append-only trajectory of run headlines, in the file
+``$VMT_PERF_LEDGER`` names.
 
-Bench and soak results used to land in ad-hoc ``BENCH_*.json`` /
-``SERVE_SOAK*.json`` artifacts — rich individually, invisible as a
-sequence (the ROADMAP's BENCH trajectory was literally ``[]``). The
-ledger is the machine-readable sequence: every bench/soak/smoke run
-appends ONE json line of headline numbers (p50/p95, qps, knee_rows,
-boot_s ...) stamped with wall time, git rev, and the serving
-``config_fingerprint()``, and :func:`check` turns the trailing window
-into a regression verdict with noise bounds.
+Every bench/soak/smoke run appends ONE json line of headline numbers
+(p50/p95, qps, knee_rows, boot_s ...) stamped with wall time, git rev, and
+the serving ``config_fingerprint()``, and :func:`check` turns the trailing
+window into a regression verdict with noise bounds.
+
+The ledger is opt-in: with ``VMT_PERF_LEDGER`` unset (and no explicit
+``path``) nothing is appended and reads come back empty. In particular the
+program never writes ``PERF_LEDGER.jsonl`` at the repo root — that file is
+the benchmark driver's record, not this program's.
 
 Direction is inferred from key names (the repo's metric-naming
 convention is already consistent): ``*_ms``/``*_s`` are latencies
@@ -25,23 +27,16 @@ import subprocess
 import time
 from typing import Any, Dict, List, Optional
 
-LEDGER_BASENAME = "PERF_LEDGER.jsonl"
+LEDGER_ENV = "VMT_PERF_LEDGER"
 
 # Bookkeeping keys never compared as metrics.
 _META_KEYS = {"ts_unix", "metric", "git_rev", "config_fingerprint",
               "run_id", "artifact", "verdict", "partial"}
 
 
-def default_ledger_path(root: Optional[str] = None) -> str:
-    """``PERF_LEDGER.jsonl`` at the repo root (or ``$VMT_PERF_LEDGER``)."""
-    env = os.environ.get("VMT_PERF_LEDGER")
-    if env:
-        return env
-    if root is None:
-        # obs/ledger.py -> obs -> package -> repo root
-        root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-    return os.path.join(root, LEDGER_BASENAME)
+def default_ledger_path() -> Optional[str]:
+    """The file ``$VMT_PERF_LEDGER`` names, or None when it is unset."""
+    return os.environ.get(LEDGER_ENV) or None
 
 
 def git_rev(cwd: Optional[str] = None) -> Optional[str]:
@@ -49,7 +44,9 @@ def git_rev(cwd: Optional[str] = None) -> Optional[str]:
     try:
         out = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"],
-            cwd=cwd or os.path.dirname(default_ledger_path()),
+            # obs/ledger.py -> obs -> package -> repo root
+            cwd=cwd or os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))),
             capture_output=True, text=True, timeout=10)
         return out.stdout.strip() or None if out.returncode == 0 else None
     except Exception:  # noqa: BLE001 — ledger stamping must never raise
@@ -60,7 +57,9 @@ def append_entry(metric: str, values: Dict[str, Any], *,
                  path: Optional[str] = None,
                  config_fingerprint: Optional[str] = None,
                  extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Append one run's headline numbers; returns the written entry.
+    """Append one run's headline numbers to ``path`` (default: the file
+    ``$VMT_PERF_LEDGER`` names; neither → nothing is written); returns the
+    entry either way.
 
     Best-effort by design: a bench must publish its artifact even when
     the ledger file is unwritable, so IO errors are swallowed (the entry
@@ -75,8 +74,10 @@ def append_entry(metric: str, values: Dict[str, Any], *,
     entry.update(values)
     if extra:
         entry.update(extra)
+    p = path or default_ledger_path()
+    if p is None:
+        return entry
     try:
-        p = path or default_ledger_path()
         if os.path.dirname(p):
             os.makedirs(os.path.dirname(p), exist_ok=True)
         with open(p, "a", encoding="utf-8") as f:
@@ -91,6 +92,8 @@ def read_entries(path: Optional[str] = None,
     """All parseable entries, oldest first (filtered by ``metric``)."""
     p = path or default_ledger_path()
     out: List[Dict[str, Any]] = []
+    if p is None:
+        return out
     try:
         with open(p, encoding="utf-8") as f:
             for line in f:

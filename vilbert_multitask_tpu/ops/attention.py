@@ -13,7 +13,7 @@ package (driven from worker.py:286-289); redesigned, not translated.
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Optional, Tuple
+from typing import TYPE_CHECKING, Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +85,8 @@ class FusedSelfAttention(nn.Module):
     num_heads: int
     dropout_rate: float = 0.1
     use_pallas: bool = False
+    pallas_interpret: bool = False  # CPU tests only (ops/coattention.py)
+    kernel_mesh: Optional[Any] = None  # Mesh of a partitioned program
     ring: Optional["RingContext"] = None  # parallel/ring.py
     dtype: jnp.dtype = jnp.float32
 
@@ -116,7 +118,9 @@ class FusedSelfAttention(nn.Module):
                 flash_cross_attention,
             )
 
-            ctx = flash_cross_attention(q, k, v, mask_bias)
+            ctx = flash_cross_attention(q, k, v, mask_bias,
+                                        interpret=self.pallas_interpret,
+                                        mesh=self.kernel_mesh)
             return ctx.reshape(*x.shape[:-1], self.hidden_size), None
         dropout_rng = self.make_rng("dropout") if use_dropout else None
         ctx, probs = multi_head_attention(
@@ -143,6 +147,8 @@ class CrossAttention(nn.Module):
     num_heads: int
     dropout_rate: float = 0.1
     use_pallas: bool = False
+    pallas_interpret: bool = False  # CPU tests only (ops/coattention.py)
+    kernel_mesh: Optional[Any] = None  # Mesh of a partitioned program
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -163,7 +169,9 @@ class CrossAttention(nn.Module):
                 flash_cross_attention,
             )
 
-            ctx = flash_cross_attention(q, k, v, y_mask_bias)
+            ctx = flash_cross_attention(q, k, v, y_mask_bias,
+                                        interpret=self.pallas_interpret,
+                                        mesh=self.kernel_mesh)
             return ctx.reshape(B, Nq, self.bi_hidden_size), None
         dropout_rng = self.make_rng("dropout") if use_dropout else None
         ctx, probs = multi_head_attention(

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG_BIG = -2.0e9  # mask bias for padded KV rows; far below the -10000 mask
 
@@ -75,7 +76,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("block_q", "block_k", "interpret", "mesh")
 )
 def flash_cross_attention(
     q: jnp.ndarray,  # (B, Nq, H, D)
@@ -85,16 +86,47 @@ def flash_cross_attention(
     *,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
+    mesh: Mesh | None = None,
 ) -> jnp.ndarray:
     """Blockwise cross-attention; returns context (B, Nq, H, D).
 
     Pads Nq/Nk/D to tile boundaries (masking padded keys via the bias) and
     slices the padding back off — callers keep reference shapes (37+1 text
     tokens, 101 regions).
+
+    ``interpret`` is the caller's explicit choice (CPU tests pass True, or
+    set ``ViLBertConfig.pallas_interpret``); it is never inferred from the
+    backend. The default compiles under Mosaic, so off-TPU the call raises
+    instead of quietly running the Pallas interpreter.
+
+    ``mesh`` is the device mesh of a partitioned program (the engine's
+    multi-chip ``batched`` family). XLA cannot partition a Mosaic call by
+    itself ("Mosaic kernels cannot be automatically partitioned"), so the
+    kernel then runs under ``shard_map``: batch rows split over ``dp`` and
+    heads over ``tp`` wherever those axes divide them (the same
+    divisibility rule parallel/sharding.py places batches by), replicated
+    otherwise. Attention is independent per (row, head), so each shard's
+    kernel is the whole computation for its rows.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if mesh is None:
+        return _flash(q, k, v, bias, block_q=block_q, block_k=block_k,
+                      interpret=interpret)
+
+    def axis(name: str, dim: int):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and dim % size == 0 else None
+
+    qkv = P(axis("dp", q.shape[0]), None, axis("tp", q.shape[2]), None)
+    return jax.shard_map(
+        functools.partial(_flash, block_q=block_q, block_k=block_k,
+                          interpret=interpret),
+        mesh=mesh, in_specs=(qkv, qkv, qkv, P(qkv[0], None, None, None)),
+        out_specs=qkv, check_vma=False)(q, k, v, bias)
+
+
+def _flash(q, k, v, bias, *, block_q: int, block_k: int, interpret: bool):
+    """The kernel launch on one device's rows (see flash_cross_attention)."""
     B, Nq, H, D = q.shape
     Nk = k.shape[1]
     out_dtype = q.dtype

@@ -17,7 +17,14 @@ import time
 from typing import Any, Callable, Dict, Optional
 
 from vilbert_multitask_tpu import obs
-from vilbert_multitask_tpu.config import FrameworkConfig, config_fingerprint
+from vilbert_multitask_tpu.config import (
+    FrameworkConfig,
+    add_backend_args,
+    apply_backend_args,
+    config_fingerprint,
+    require_tpu,
+)
+from vilbert_multitask_tpu.engine import cachedir
 from vilbert_multitask_tpu.engine.runtime import InferenceEngine
 from vilbert_multitask_tpu.features.store import FeatureStore
 from vilbert_multitask_tpu.serve.autoscale import Autoscaler
@@ -50,33 +57,30 @@ class ServeApp:
                  engine_factory: Optional[Callable[[], Any]] = None):
         self.cfg = cfg or FrameworkConfig()
         s = self.cfg.serving
-        # Persistent XLA compile cache on by default for the serving binary:
-        # restarts skip the per-bucket compiles (the boot-latency item from
-        # round 2's verdict). An explicit EngineConfig value wins.
-        if self.cfg.engine.compilation_cache_dir is None:
-            cache_dir = os.path.join(
-                os.path.dirname(s.queue_db_path) or "serve_state", "xla_cache")
-            self.cfg = dataclasses.replace(
-                self.cfg, engine=dataclasses.replace(
-                    self.cfg.engine, compilation_cache_dir=cache_dir))
+        # Persistent XLA compile cache on for the serving binary: restarts
+        # skip the per-bucket compiles. Placed by the one rule in
+        # engine/cachedir.py ($JAX_COMPILATION_CACHE_DIR, else a fixed path
+        # in the checkout) — never beside the state directory, which soaks
+        # and smokes root in a fresh temp dir every run.
+        self.boot_info: dict = {
+            "phase": "booting",
+            "compile_cache_dir": cachedir.enable_compilation_cache()}
         # AOT executable cache (engine/aotcache.py) on by default too:
         # NEXT TO THE CHECKPOINT when one is given — the executables are
         # as much a build artifact of the deployed weights as the weights
         # themselves, and a prewarm CI step populates them in the same
         # place every replica host mounts. No checkpoint (random-weights
-        # dev boots) → under serve_state with the other durable files.
-        # An explicit EngineConfig value wins.
+        # dev boots) → the fixed in-checkout default. An explicit
+        # EngineConfig value wins.
         if self.cfg.engine.aot_cache_dir is None:
             aot_dir = (
                 os.path.join(os.path.dirname(os.path.abspath(
                     checkpoint_path)), "aot_cache")
                 if checkpoint_path is not None else
-                os.path.join(os.path.dirname(s.queue_db_path)
-                             or "serve_state", "aot_cache"))
+                cachedir.default_aot_cache_dir())
             self.cfg = dataclasses.replace(
                 self.cfg, engine=dataclasses.replace(
                     self.cfg.engine, aot_cache_dir=aot_dir))
-        self.boot_info: dict = {"phase": "booting"}
         self.extractor = None  # set when live_extract builds a detector
         self.hub = PushHub()
         self.queue = DurableQueue(
@@ -126,7 +130,8 @@ class ServeApp:
                     self.cfg.engine.aot_cache_dir,
                     aotcache.compile_fingerprint(
                         self.cfg, mesh=mesh,
-                        heads=self.cfg.engine.fused_task_heads))
+                        heads=self.cfg.engine.fused_task_heads),
+                    mesh=mesh)
                 self.boot_info["aot_prefetched"] = aot.prefetch()
             if restore is not None:
                 params = restore.join()
@@ -203,6 +208,11 @@ class ServeApp:
                 else [engine]
             self.engine = ReplicaPool(engines, serving=s)
         self.boot_info["replicas"] = [r.name for r in self.engine.replicas]
+        # Which program family serves is decided above from the device
+        # count alone (no option): say so where operators look.
+        self.boot_info["program_family"] = (
+            "batched" if getattr(self.engine, "mesh", None) is not None
+            else "rows")
         self._refresh_boot_phases()
         self.fingerprint = config_fingerprint(self.cfg)
         # Result cache + singleflight registry: a second table pair in the
@@ -467,7 +477,6 @@ class ServeApp:
             warmup_s=round(time.perf_counter() - t0, 1),
             buckets=list(self.cfg.engine.all_row_buckets()),
             pallas=self.engine.pallas_enabled,
-            kernel_fallback=self.engine.kernel_fallback,
         )
         self._refresh_boot_phases()
         # Warming before start() returns to "booting" (still not serving);
@@ -656,9 +665,20 @@ def main(argv=None) -> None:
                         "unless --detector-checkpoint is given")
     p.add_argument("--detector-checkpoint", default=None,
                    help="Orbax checkpoint dir for the live detector")
+    add_backend_args(p)
     args = p.parse_args(argv)
 
-    app = ServeApp(feature_root=args.features,
+    # The serving binary is a TPU program: without a chip it stops here
+    # instead of carrying on on the CPU. --cpu is the explicit way off.
+    cfg = apply_backend_args(FrameworkConfig(), args)
+    if not args.cpu:
+        require_tpu("serve.app")
+    import jax
+
+    dev = jax.devices()[0]
+    print(f"platform: {dev.platform}  device_kind: {dev.device_kind}  "
+          f"devices: {jax.device_count()}")
+    app = ServeApp(cfg, feature_root=args.features,
                    checkpoint_path=args.checkpoint,
                    live_extract=args.live_extract,
                    detector_checkpoint=args.detector_checkpoint)

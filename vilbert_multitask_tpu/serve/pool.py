@@ -187,10 +187,6 @@ class ReplicaPool:
         return bool(getattr(self._host, "pallas_enabled", False))
 
     @property
-    def kernel_fallback(self) -> bool:
-        return bool(getattr(self._host, "kernel_fallback", False))
-
-    @property
     def stage_times(self):
         return self._host.stage_times
 
@@ -224,7 +220,13 @@ class ReplicaPool:
         populated, so serial warmup costs ~one compile total, and the pool
         becomes partially available as soon as the first replica flips
         ready.
+
+        One bad replica does not sink the boot, but a pool in which NO
+        replica came up does: the last warmup error (a compiler refusing a
+        kernel, say) is re-raised instead of starting a server that can
+        answer nothing.
         """
+        failure: Optional[BaseException] = None
         for rep in self.replicas:
             if rep.state == STATE_DEAD:
                 continue
@@ -234,11 +236,15 @@ class ReplicaPool:
                     rep.engine.warmup(buckets=buckets, parallel=parallel)
             except Exception as e:  # noqa: BLE001 — a bad replica must not
                 rep.last_error = repr(e)  # sink the whole boot.
+                failure = e
                 self._set_state(rep, STATE_DEAD)
                 obs.record_event("replica_boot_failed", replica=rep.name,
                                  error=repr(e))
                 continue
             self._set_state(rep, STATE_READY)
+        if failure is not None and not any(
+                rep.state == STATE_READY for rep in self.replicas):
+            raise failure
 
     def _boot_from_cache(self, rep: Replica, buckets=None) -> bool:
         """Try the engine's AOT warm-boot path; True means every warmup

@@ -1,0 +1,14 @@
+"""Tests of the benchmark itself. Run by hand, on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
+
+They are not part of the repo's tier-1 suite (which collects ``tests/``).
+"""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

@@ -643,8 +643,9 @@ class InferenceEngine:
                      if self.param_quantized else heads)
             out, label_logits = fused_head_output(
                 model.config, slabs, trunk_out, batch["image_mask"], cdt)
-            bundle = self._fused_bundle(out, label_logits,
-                                        batch["task_ids"], self._gqa_gather)
+            with jax.named_scope("decode_bundle"):
+                bundle = self._fused_bundle(
+                    out, label_logits, batch["task_ids"], self._gqa_gather)
             return out, bundle
         out = model.apply(
             {"params": params},
@@ -655,7 +656,8 @@ class InferenceEngine:
             # serving decodes never read the masked-LM/region heads
             compute_pretraining_heads=False,
         )
-        return out, InferenceEngine._decode_bundle(out)
+        with jax.named_scope("decode_bundle"):
+            return out, InferenceEngine._decode_bundle(out)
 
     def _forward(self, bucket: int, collect_attention: bool):
         """Batched-input program (the mesh path: inputs are device_put with
@@ -703,14 +705,17 @@ class InferenceEngine:
                      donate_argnames=donate)
             def fwd(params, heads, slab, pack, attn=collect_attention):
                 rows = pack["rows"]
+                # Scopes are profile metadata (op_name prefixes): the
+                # executable stays ``jit_fwd``.
+                with jax.named_scope("slab_gather"):
+                    images = {k: slab[k][rows] for k in
+                              ("features", "spatials", "image_mask")}
                 batch = dict(
                     input_ids=pack["input_ids"],
-                    features=slab["features"][rows],
-                    spatials=slab["spatials"][rows],
                     segment_ids=pack["segment_ids"],
                     input_mask=pack["input_mask"],
-                    image_mask=slab["image_mask"][rows],
                     task_ids=pack["task_ids"],
+                    **images,
                 )
                 return engine._apply_heads(model, params, heads, batch, attn)
 
@@ -928,8 +933,8 @@ class InferenceEngine:
                                "use prepare() with in-memory regions instead")
         fetch = getattr(self.feature_store, "fetch", None)
         t_fetch = time.perf_counter()
-        with obs.span("engine.features", source="store",
-                      n_images=len(image_paths), task_id=task_id):
+        with obs.span("engine.features", n_images=len(image_paths),
+                      task_id=task_id):
             if fetch is not None:
                 pairs = [fetch(p) for p in image_paths]
                 regions = [r for r, _ in pairs]
@@ -992,8 +997,7 @@ class InferenceEngine:
             ).stack(bucket)
         self.stage_times["tokenize_s"] = time.perf_counter() - t_tok
         t_feat = time.perf_counter()
-        with obs.span("engine.features", source="encode", n_images=n,
-                      task_id=task_id):
+        with obs.span("engine.encode", n_images=n, task_id=task_id):
             # Feature files are confidence-ordered (extractor top-K order,
             # same as the reference's .npy dumps), so an over-provisioned
             # store clips to this engine's region budget instead of erroring.
@@ -1114,11 +1118,13 @@ class InferenceEngine:
                         for k in slab}
 
             self._slab_insert_fn = jax.jit(_ins)
-        placed = jax.device_put(dict(
-            features=host_row["features"], spatials=host_row["spatials"],
-            image_mask=host_row["image_mask"],
-            slot=np.asarray(slot, np.int32)))
-        self._slab = self._slab_insert_fn(self._slab, placed)
+        with obs.span("engine.slab_insert", slot=slot):
+            placed = jax.device_put(dict(
+                features=host_row["features"], spatials=host_row["spatials"],
+                image_mask=host_row["image_mask"],
+                slot=np.asarray(slot, np.int32)))
+            self._slab = self._slab_insert_fn(self._slab, placed)
+        obs.INPUT_CACHE_INSERTS.inc()
 
     def _row_slot_locked(self, host_row: dict, key: Optional[str]) -> int:
         """Slab slot for one image row (caller holds _input_cache_lock):
@@ -1129,8 +1135,10 @@ class InferenceEngine:
             if slot is not None:
                 self._input_cache.move_to_end(key)
                 self._input_cache_hits += 1
+                obs.INPUT_CACHE_HITS.inc()
                 return slot
             self._input_cache_misses += 1
+            obs.INPUT_CACHE_MISSES.inc()
             if self._slab_free:
                 slot = self._slab_free.pop()
             else:
@@ -1332,7 +1340,11 @@ class InferenceEngine:
         def _drain_one() -> None:
             nonlocal dec_s
             c, bundle = pending.popleft()
-            bundle = jax.device_get(bundle)
+            # The fetch is where the host waits for the device: the
+            # dispatch returned as soon as the program was enqueued.
+            with obs.span("engine.result_wait",
+                          rows=sum(r.n_images for _, r in c)):
+                bundle = jax.device_get(bundle)
             td = time.perf_counter()
             with obs.span("engine.decode", n_requests=len(c)):
                 row = 0
@@ -1347,7 +1359,18 @@ class InferenceEngine:
                       n_requests=len(reqs),
                       n_chunks=len(chunks)):
             for c in chunks:
-                pending.append((c, self._dispatch_many([r for _, r in c])))
+                rows = sum(r.n_images for _, r in c)
+                with obs.span("engine.dispatch", rows=rows,
+                              bucket=self.cfg.engine.row_bucket_for(rows)
+                              ) as sp:
+                    # Plain reads: another thread dispatching on this
+                    # engine (warm-up) can blur the two attributes, never
+                    # the counters.
+                    hits, misses = (self._input_cache_hits,
+                                    self._input_cache_misses)
+                    pending.append((c, self._dispatch_many([r for _, r in c])))
+                    sp.set(hits=self._input_cache_hits - hits,
+                           misses=self._input_cache_misses - misses)
                 if len(pending) >= self._MAX_INFLIGHT_CHUNKS:
                     _drain_one()
             while pending:
@@ -1495,9 +1518,7 @@ class InferenceEngine:
         if self.feature_store is None:
             raise RuntimeError("predict() needs a FeatureStore; use "
                                "prepare()+run() with in-memory regions instead")
-        t0 = time.perf_counter()
         # One store read yields regions + device-cache identities together.
         req = self.prepare_from_store(task_id, question, image_paths)
-        self.stage_times["prepare_s"] = time.perf_counter() - t0
         _, result = self.run(req, collect_attention=collect_attention)
         return result

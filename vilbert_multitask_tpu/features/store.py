@@ -19,11 +19,13 @@ from __future__ import annotations
 
 import os
 import struct
+import time
 from collections import OrderedDict
 from typing import Dict, Iterable
 
 import numpy as np
 
+from vilbert_multitask_tpu import obs
 from vilbert_multitask_tpu.features.pipeline import RegionFeatures
 
 _VLFR_MAGIC = b"VLFR\x01"
@@ -163,7 +165,9 @@ class FeatureStore:
         key = file_identity(path)
         if key in self._cache:
             self._cache.move_to_end(key)
+            obs.FEATURE_STORE_HITS.inc()
             return self._cache[key], key
+        t_load = time.perf_counter()
         if path.endswith(".npy"):
             region = load_reference_npy(path)
         elif self._native_ok:
@@ -172,6 +176,9 @@ class FeatureStore:
             region = native.read_vlfr(path)
         else:
             region = load_vlfr(path)
+        obs.FEATURE_STORE_MISSES.inc()
+        obs.FEATURE_STORE_LOAD_SECONDS.inc(time.perf_counter() - t_load)
+        obs.FEATURE_STORE_READ_BYTES.inc(os.path.getsize(path))
         self._cache[key] = region
         if len(self._cache) > self.max_cached:
             self._cache.popitem(last=False)

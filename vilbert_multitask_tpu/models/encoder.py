@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING, Any, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -123,37 +124,36 @@ class TwoStreamEncoder(nn.Module):
         cfg = self.config
         attn_maps: List[Tuple] = []
 
+        def stream(scope, layers, hidden, mask_bias):
+            # jax.named_scope: profile metadata only (op_name prefixes a
+            # device trace can be summed by); a run of layers between two
+            # bridges shares one scope, each bridge has its own.
+            with jax.named_scope(scope):
+                for layer in layers:
+                    hidden, _ = layer(hidden, mask_bias, deterministic)
+            return hidden
+
         t_ptr = 0
         v_ptr = 0
         for c_idx, (v_stop, t_stop) in enumerate(
             zip(cfg.v_biattention_id, cfg.t_biattention_id)
         ):
-            while t_ptr < t_stop:
-                t_hidden, _ = self.t_layers[t_ptr](
-                    t_hidden, t_mask_bias, deterministic
+            t_hidden = stream("text_stream", self.t_layers[t_ptr:t_stop],
+                              t_hidden, t_mask_bias)
+            v_hidden = stream("visual_stream", self.v_layers[v_ptr:v_stop],
+                              v_hidden, v_mask_bias)
+            t_ptr, v_ptr = max(t_ptr, t_stop), max(v_ptr, v_stop)
+            with jax.named_scope(f"coattention_bridge_{c_idx}"):
+                v_hidden, t_hidden, co_probs = self.c_layers[c_idx](
+                    v_hidden, v_mask_bias, t_hidden, t_mask_bias,
+                    deterministic, collect_attention,
                 )
-                t_ptr += 1
-            while v_ptr < v_stop:
-                v_hidden, _ = self.v_layers[v_ptr](
-                    v_hidden, v_mask_bias, deterministic
-                )
-                v_ptr += 1
-            v_hidden, t_hidden, co_probs = self.c_layers[c_idx](
-                v_hidden, v_mask_bias, t_hidden, t_mask_bias,
-                deterministic, collect_attention,
-            )
             if collect_attention:
                 attn_maps.append(co_probs)
 
-        while v_ptr < cfg.v_num_hidden_layers:
-            v_hidden, _ = self.v_layers[v_ptr](
-                v_hidden, v_mask_bias, deterministic
-            )
-            v_ptr += 1
-        while t_ptr < cfg.num_hidden_layers:
-            t_hidden, _ = self.t_layers[t_ptr](
-                t_hidden, t_mask_bias, deterministic
-            )
-            t_ptr += 1
+        v_hidden = stream("visual_stream", self.v_layers[v_ptr:],
+                          v_hidden, v_mask_bias)
+        t_hidden = stream("text_stream", self.t_layers[t_ptr:],
+                          t_hidden, t_mask_bias)
 
         return t_hidden, v_hidden, attn_maps

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from flax import struct
@@ -212,45 +213,46 @@ class ViLBertForVLTasks(nn.Module):
             collect_attention=output_all_attention_masks,
         )
 
-        if cfg.fusion_method == "mul":
-            pooled = pooled_t * pooled_v
-        elif cfg.fusion_method == "sum":
-            pooled = pooled_t + pooled_v
-        else:
-            raise ValueError(f"unknown fusion_method {cfg.fusion_method}")
-        pooled = self.head_dropout(pooled, deterministic=deterministic)
+        with jax.named_scope("task_heads"):
+            if cfg.fusion_method == "mul":
+                pooled = pooled_t * pooled_v
+            elif cfg.fusion_method == "sum":
+                pooled = pooled_t + pooled_v
+            else:
+                raise ValueError(f"unknown fusion_method {cfg.fusion_method}")
+            pooled = self.head_dropout(pooled, deterministic=deterministic)
 
-        vil_prediction = self.vil_prediction(pooled)
-        vil_prediction_gqa = self.vil_prediction_gqa(pooled)
-        vil_logit = self.vil_logit(pooled)
-        vil_tri_prediction = self.vil_tri_prediction(pooled)
+            vil_prediction = self.vil_prediction(pooled)
+            vil_prediction_gqa = self.vil_prediction_gqa(pooled)
+            vil_logit = self.vil_logit(pooled)
+            vil_tri_prediction = self.vil_tri_prediction(pooled)
 
-        # NLVR2: adjacent rows are the image pair for one example
-        # (repeat-batching at engine/dispatch.py, mirroring worker.py:266-276).
-        vil_binary_prediction = None
-        if pooled.shape[0] % 2 == 0:
-            paired = pooled.reshape(pooled.shape[0] // 2, -1)
-            vil_binary_prediction = self.vil_binary_prediction(paired)
-        elif self.is_initializing():
-            # Materialize the head's params even when init ran with an odd
-            # batch, so param existence never depends on the init shapes.
-            self.vil_binary_prediction(
-                jnp.zeros((1, 2 * pooled.shape[-1]), self.dtype)
-            )
+            # NLVR2: adjacent rows are the image pair for one example
+            # (repeat-batching at engine/dispatch.py, mirroring worker.py:266-276).
+            vil_binary_prediction = None
+            if pooled.shape[0] % 2 == 0:
+                paired = pooled.reshape(pooled.shape[0] // 2, -1)
+                vil_binary_prediction = self.vil_binary_prediction(paired)
+            elif self.is_initializing():
+                # Materialize the head's params even when init ran with an odd
+                # batch, so param existence never depends on the init shapes.
+                self.vil_binary_prediction(
+                    jnp.zeros((1, 2 * pooled.shape[-1]), self.dtype)
+                )
 
-        # Grounding heads: mask penalty keeps padded regions out of the softmax
-        # (same -10000 fold-in the reference model applies).
-        vision_logit = self.vision_logit(self.head_dropout(
-            v_seq, deterministic=deterministic))
-        vision_logit = vision_logit + mask_to_bias(image_mask, self.dtype)[:, 0, 0, :, None]
-        linguisic_logit = self.linguisic_logit(self.head_dropout(
-            t_seq, deterministic=deterministic))
+            # Grounding heads: mask penalty keeps padded regions out of the softmax
+            # (same -10000 fold-in the reference model applies).
+            vision_logit = self.vision_logit(self.head_dropout(
+                v_seq, deterministic=deterministic))
+            vision_logit = vision_logit + mask_to_bias(image_mask, self.dtype)[:, 0, 0, :, None]
+            linguisic_logit = self.linguisic_logit(self.head_dropout(
+                t_seq, deterministic=deterministic))
 
-        linguisic_prediction = vision_prediction = None
-        if compute_pretraining_heads or self.is_initializing():
-            linguisic_prediction = self.cls_text(
-                t_seq, self.bert.embeddings.word_table)
-            vision_prediction = self.cls_image(v_seq)
+            linguisic_prediction = vision_prediction = None
+            if compute_pretraining_heads or self.is_initializing():
+                linguisic_prediction = self.cls_text(
+                    t_seq, self.bert.embeddings.word_table)
+                vision_prediction = self.cls_image(v_seq)
 
         return ViLBertOutput(
             vil_prediction=vil_prediction,
@@ -266,6 +268,7 @@ class ViLBertForVLTasks(nn.Module):
         )
 
 
+@jax.named_scope("task_heads")
 def fused_head_output(
     cfg: ViLBertConfig, slabs: dict, trunk_out, image_mask, dtype
 ) -> Tuple[ViLBertOutput, jnp.ndarray]:

@@ -122,6 +122,10 @@ __all__ = [
     "REPLICA_STATE", "FAILOVER_COUNTER", "POISON_COUNTER",
     "RESULT_CACHE_HITS", "RESULT_CACHE_MISSES",
     "RESULT_CACHE_INVALIDATIONS", "COALESCED_SUBMITS", "TENANT_DEFICIT",
+    "SCHED_READY_JOBS", "INTAKE_EMPTY_POLLS", "INTAKE_BACKPRESSURE_POLLS",
+    "FEATURE_STORE_HITS", "FEATURE_STORE_MISSES", "FEATURE_STORE_READ_BYTES",
+    "FEATURE_STORE_LOAD_SECONDS", "INPUT_CACHE_HITS", "INPUT_CACHE_MISSES",
+    "INPUT_CACHE_INSERTS",
     "SAMPLER_THREAD_NAME", "Sampler", "TimeSeriesStore",
     "RECORDER_THREAD_NAME", "FlightRecorder", "active_recorder",
     "clear_recorder", "install_recorder", "record_event", "record_spike",
@@ -189,6 +193,54 @@ QUEUE_WAIT = REGISTRY.histogram(
 BATCHES_DISPATCHED = REGISTRY.counter(
     "vmt_batches_dispatched_total",
     "Device chunks dispatched by the continuous-batching scheduler.",
+)
+SCHED_READY_JOBS = REGISTRY.histogram(
+    "vmt_sched_ready_jobs",
+    "Jobs parked in the scheduler's ready-queue at the moment the window "
+    "policy fired (1 = the batcher had nothing to pack the job with).",
+    buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64),
+)
+INTAKE_EMPTY_POLLS = REGISTRY.counter(
+    "vmt_intake_empty_polls_total",
+    "Intake poll-interval sleeps taken because the claim came back empty.",
+)
+INTAKE_BACKPRESSURE_POLLS = REGISTRY.counter(
+    "vmt_intake_backpressure_polls_total",
+    "Intake poll-interval sleeps taken because the ready-queue was at "
+    "sched_ready_depth (claim run-ahead bounded, not an empty queue).",
+)
+
+# Feature path (features/store.py host LRU, engine/runtime.py device slab).
+# What has to be told apart has a name of its own, not a label: readers sum
+# a counter's label sets.
+FEATURE_STORE_HITS = REGISTRY.counter(
+    "vmt_feature_store_hits_total",
+    "FeatureStore reads answered from the host LRU.",
+)
+FEATURE_STORE_MISSES = REGISTRY.counter(
+    "vmt_feature_store_misses_total",
+    "FeatureStore reads that loaded the feature file.",
+)
+FEATURE_STORE_READ_BYTES = REGISTRY.counter(
+    "vmt_feature_store_read_bytes_total",
+    "Bytes of the feature files loaded on FeatureStore misses.",
+)
+FEATURE_STORE_LOAD_SECONDS = REGISTRY.counter(
+    "vmt_feature_store_load_seconds_total",
+    "Seconds from open to RegionFeatures on FeatureStore misses.",
+)
+INPUT_CACHE_HITS = REGISTRY.counter(
+    "vmt_input_cache_hits_total",
+    "Image rows resolved to a resident device-slab slot (no upload).",
+)
+INPUT_CACHE_MISSES = REGISTRY.counter(
+    "vmt_input_cache_misses_total",
+    "Keyed image rows that were not slab-resident (each one inserts).",
+)
+INPUT_CACHE_INSERTS = REGISTRY.counter(
+    "vmt_input_cache_inserts_total",
+    "Rows written into the device slab: keyed misses plus keyless "
+    "scratch rows (each a functional update of the whole slab).",
 )
 
 # Replica-pool instruments (serve/pool.py).

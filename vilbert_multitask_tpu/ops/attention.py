@@ -118,9 +118,12 @@ class FusedSelfAttention(nn.Module):
                 flash_cross_attention,
             )
 
-            ctx = flash_cross_attention(q, k, v, mask_bias,
-                                        interpret=self.pallas_interpret,
-                                        mesh=self.kernel_mesh)
+            # The scope says which use of the one kernel this is; the
+            # kernel's own events keep their %flash_cross_attention name.
+            with jax.named_scope("self_attention_kernel"):
+                ctx = flash_cross_attention(q, k, v, mask_bias,
+                                            interpret=self.pallas_interpret,
+                                            mesh=self.kernel_mesh)
             return ctx.reshape(*x.shape[:-1], self.hidden_size), None
         dropout_rng = self.make_rng("dropout") if use_dropout else None
         ctx, probs = multi_head_attention(
@@ -169,9 +172,10 @@ class CrossAttention(nn.Module):
                 flash_cross_attention,
             )
 
-            ctx = flash_cross_attention(q, k, v, y_mask_bias,
-                                        interpret=self.pallas_interpret,
-                                        mesh=self.kernel_mesh)
+            with jax.named_scope("coattention_kernel"):
+                ctx = flash_cross_attention(q, k, v, y_mask_bias,
+                                            interpret=self.pallas_interpret,
+                                            mesh=self.kernel_mesh)
             return ctx.reshape(B, Nq, self.bi_hidden_size), None
         dropout_rng = self.make_rng("dropout") if use_dropout else None
         ctx, probs = multi_head_attention(

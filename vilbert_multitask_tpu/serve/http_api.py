@@ -119,10 +119,12 @@ class ApiServer:
 
     # ------------------------------------------------------------- handlers
     def submit_job(self, payload: Dict[str, Any]) -> Tuple[int, Dict[str, Any]]:
-        # Trace root: the id minted here rides in the queue job body and is
-        # re-entered by the worker, correlating one request's spans across
-        # the HTTP handler / worker thread boundary.
-        trace_id = obs.new_trace_id()
+        # The trace id rides in the queue job body and is re-entered by the
+        # worker, correlating one request's spans across the HTTP handler /
+        # worker thread boundary. It is minted ONCE: under ``http.request``
+        # (the root ``do_POST`` opens) it is the root's, since a child span
+        # adopts its parent's id; a direct call mints it here.
+        trace_id = obs.current_trace_id() or obs.new_trace_id()
         with obs.trace_scope(trace_id), obs.span("http.submit") as sp:
             code, body = self._submit_job(payload, trace_id, sp)
         if code == 200:
@@ -138,7 +140,9 @@ class ApiServer:
             images = list(payload.get("image_list", []))
         except (KeyError, TypeError, ValueError):
             return 400, {"error": "need task_id, socket_id, question, image_list"}
-        decision = self._admission_decision()
+        with obs.span("http.admission") as asp:
+            decision = self._admission_decision()
+            asp.set(admitted=decision.admitted)
         if not decision.admitted:
             return 429, {
                 "error": "overloaded; retry later",
@@ -177,9 +181,11 @@ class ApiServer:
         if self.cache is not None and not collect:
             key = cache_key(task_id, images, question,
                             self.cache.fingerprint)
-            verdict_c, value = self.cache.admit(
-                key, socket_id=socket_id, trace_id=trace_id,
-                tenant=tenant, coalesce=self.serving.coalesce_enabled)
+            with obs.span("cache.admit") as csp:
+                verdict_c, value = self.cache.admit(
+                    key, socket_id=socket_id, trace_id=trace_id,
+                    tenant=tenant, coalesce=self.serving.coalesce_enabled)
+                csp.set(verdict=verdict_c)
             if verdict_c == "hit":
                 return self._serve_cache_hit(spec, socket_id, trace_id,
                                              tenant, value, sp)
@@ -195,23 +201,26 @@ class ApiServer:
                              "cache": "coalesced"}
             obs.RESULT_CACHE_MISSES.inc()
         try:
-            job_id = self.queue.publish(
-                make_job_message(
-                    images, question, task_id, socket_id,
-                    # "full" passes through (complete per-head maps
-                    # persisted); any other truthy value → compact summary.
-                    collect_attention=("full" if collect == "full"
-                                       else bool(collect)),
-                    trace_id=trace_id,
-                    tenant=tenant,
-                    # The deadline is minted HERE — queueing time counts
-                    # against the budget, so a job stuck behind a backlog
-                    # expires instead of burning a forward for a long-gone
-                    # client.
-                    deadline=(Deadline(budget).to_wire()
-                              if budget and budget > 0 else None),
-                    published_unix=time.time(),
-                    cache_key=key))
+            with obs.span("queue.publish") as psp:
+                job_id = self.queue.publish(
+                    make_job_message(
+                        images, question, task_id, socket_id,
+                        # "full" passes through (complete per-head maps
+                        # persisted); any other truthy value → compact
+                        # summary.
+                        collect_attention=("full" if collect == "full"
+                                           else bool(collect)),
+                        trace_id=trace_id,
+                        tenant=tenant,
+                        # The deadline is minted HERE — queueing time counts
+                        # against the budget, so a job stuck behind a
+                        # backlog expires instead of burning a forward for
+                        # a long-gone client.
+                        deadline=(Deadline(budget).to_wire()
+                                  if budget and budget > 0 else None),
+                        published_unix=time.time(),
+                        cache_key=key))
+                psp.set(job_id=job_id)
         except Exception:
             # Leadership was claimed above: a failed publish must drop
             # the claim, or every future identical submit would attach
@@ -220,7 +229,10 @@ class ApiServer:
                 self.cache.abandon(key)
             raise
         if self.cache is not None and key:
-            self.cache.set_leader(key, job_id)
+            # The submit's fourth sqlite round trip: the claimed row learns
+            # which job leads it.
+            with obs.span("cache.set_leader", job_id=job_id):
+                self.cache.set_leader(key, job_id)
         sp.set(task_id=task_id, job_id=job_id, n_images=len(images))
         body = {"job_id": job_id, "task": spec.name}
         if key:
@@ -876,15 +888,17 @@ class ApiServer:
                 self.end_headers()
                 self.wfile.write(data)
 
-            def do_POST(self):
-                length = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(length)
-                ctype = self.headers.get("Content-Type", "")
-                path = self.path.rstrip("/") or "/"
-                if path == "/":
+            def _handle_submit(self, length: int):
+                """POST /: one ``http.request`` root span from before the
+                body is read to after the response is written, so what a
+                client waits for beyond ``http.submit`` (socket read, JSON,
+                response write) is on a line of its own."""
+                with obs.span("http.request", bytes=length) as sp:
+                    raw = self.rfile.read(length)
                     try:
                         payload = json.loads(raw or b"{}")
                     except json.JSONDecodeError:
+                        sp.set(status=400)
                         self._json(400, {"error": "invalid JSON"})
                         return
                     code, body = api.submit_job(payload)
@@ -893,8 +907,18 @@ class ApiServer:
                         # RFC 9110 §10.2.3: Retry-After in whole seconds.
                         headers = {"Retry-After": str(max(1, int(round(
                             body.get("retry_after_s", 1)))))}
+                    sp.set(status=code)
                     self._json(code, body, headers=headers)
-                elif path == "/upload_image":
+
+            def do_POST(self):
+                length = int(self.headers.get("Content-Length", 0))
+                path = self.path.rstrip("/") or "/"
+                if path == "/":
+                    self._handle_submit(length)
+                    return
+                raw = self.rfile.read(length)
+                ctype = self.headers.get("Content-Type", "")
+                if path == "/upload_image":
                     self._handle_upload(raw, ctype)
                 elif path.startswith("/worker/"):
                     self._handle_worker(path, raw)

@@ -290,10 +290,12 @@ class ContinuousScheduler:
             with self._cond:
                 backlog = len(self._ready)
             if backlog >= self.serving.sched_ready_depth:
+                obs.INTAKE_BACKPRESSURE_POLLS.inc()
                 self.stop.wait(self.poll_interval_s)
                 continue
             job = self.worker._claim()
             if job is None:
+                obs.INTAKE_EMPTY_POLLS.inc()
                 self.stop.wait(self.poll_interval_s)
                 continue
             if self.worker._check_deadline(job):
@@ -348,6 +350,8 @@ class ContinuousScheduler:
                 if not fire:
                     self._cond.wait(min(wait_s, self.poll_interval_s))
                     continue
+                # In-memory observe, like the gauge set below (VMT116).
+                obs.SCHED_READY_JOBS.observe(len(self._ready))
                 batch, expired, rest = select_batch(
                     self._ready, now, max_rows,
                     deficits=self._deficits if self._fairness else None,
@@ -475,10 +479,16 @@ class ContinuousScheduler:
                  for i in members],
                 batch_rows=rows_total, bucket=top_bucket, replica=rep_name)
 
+        # A batch of one serves one trace, so its spans join it (the
+        # engine's child spans then sit in the request's own waterfall); a
+        # shared batch stands alone under a fresh id, members in job_ids.
+        own_trace = (packed[0].job.body.get("trace_id")
+                     if len(packed) == 1 else None)
         try:
-            with obs.span("worker.batch_forward", n_jobs=len(packed),
-                          job_ids=[i.job.id for i in packed],
-                          replica=rep_name):
+            with obs.trace_scope(own_trace), \
+                    obs.span("worker.batch_forward", n_jobs=len(packed),
+                             job_ids=[i.job.id for i in packed],
+                             replica=rep_name):
                 engine.run_many(reqs, on_result=_on_result)
             # Attribute the shared forward window into each member's own
             # trace (same contract as step_batch) so per-request

@@ -243,12 +243,30 @@ class _CountingStop(threading.Event):
         return self.is_set()
 
 
+class _SilentQueue:
+    """A queue nothing is ever published to: ``wait_for_work`` sleeps its
+    timeout out, notes it, and sets ``stop`` after ``polls`` waits."""
+
+    def __init__(self, stop, polls):
+        self.stop, self.polls, self.timeouts = stop, polls, []
+
+    def work_seq(self):
+        return 0
+
+    def wait_for_work(self, seen_seq, timeout_s):
+        self.timeouts.append(timeout_s)
+        time.sleep(timeout_s)
+        if len(self.timeouts) >= self.polls:
+            self.stop.set()
+        return False, seen_seq
+
+
 class _IdleWorker:
     """A worker whose queue is empty; only what ``_intake_pump`` touches."""
 
-    def __init__(self, worker):
+    def __init__(self, worker, queue=None):
         self.serving, self.engine = worker.serving, worker.engine
-        self.claims = 0
+        self.queue, self.claims = queue, 0
 
     def _claim(self):
         self.claims += 1
@@ -260,16 +278,28 @@ def _poll_counters():
             obs.INTAKE_BACKPRESSURE_POLLS.value())
 
 
-def test_an_empty_queue_counts_empty_polls_only(stack):
-    worker = _IdleWorker(stack[4])
+@pytest.mark.parametrize("signals", [True, False])
+def test_an_empty_queue_counts_empty_polls_only(stack, signals):
+    """Every empty claim is counted once and nothing else is. The wait
+    after it is the queue's own, no longer than ``poll_interval_s``; on a
+    queue that cannot signal (``serve/remote.py``) it is the sleep on the
+    stop event that it always was."""
     stop = _CountingStop(polls=5)
+    queue = _SilentQueue(stop, polls=5) if signals else object()
+    worker = _IdleWorker(stack[4], queue)
     sched = ContinuousScheduler(worker, stop_event=stop,
                                 poll_interval_s=0.05)
     empty0, back0 = _poll_counters()
     sched._intake_pump()
     empty1, back1 = _poll_counters()
-    assert (empty1 - empty0, back1 - back0) == (5, 0)
-    assert worker.claims == 5 and stop.waits == [0.05] * 5
+    assert (empty1 - empty0, back1 - back0) == (worker.claims, 0)
+    if signals:
+        # One claim a wait: a wait ends when the timed claim is due.
+        assert worker.claims == 5 and stop.waits == []
+        assert len(queue.timeouts) == 5
+        assert all(0.0 < t <= 0.05 for t in queue.timeouts)
+    else:
+        assert worker.claims == 5 and stop.waits == [0.05] * 5
 
 
 def test_a_full_ready_queue_counts_backpressure_polls_only(stack):

@@ -293,3 +293,267 @@ def test_scheduler_chaos_exactly_one_terminal(stack):
     assert not dups, f"duplicate terminal states: {dups}"
     assert q.counts().get("inflight", 0) == 0
     assert worker.inflight_count() == 0
+
+
+# ------------------------------------------------- intake wake-up (ISSUE 26)
+# A publish in this process wakes a waiting intake thread; the timer is the
+# fallback, on one thread at a time. Where a pass has to prove the signal,
+# the poll interval is 5 s and the assertion far inside it.
+def _job(i=0):
+    return make_job_message(["img_a.jpg"], f"wake q {i}", 1, "wake")
+
+
+class _Intakes:
+    """``n`` intake threads of a scheduler over the real queue and the real
+    ``ServeWorker._claim``, with the prep stubbed out (no engine work)."""
+
+    def __init__(self, worker, n, poll_interval_s):
+        worker._intake = lambda job: (job.id, _Req(), 0.0)
+        self.q, self.stop = worker.queue, threading.Event()
+        self.sched = ContinuousScheduler(worker, stop_event=self.stop,
+                                         poll_interval_s=poll_interval_s)
+        self.empty0 = obs.INTAKE_EMPTY_POLLS.value()
+        self.claims0 = self.claims()
+        self.threads = [threading.Thread(target=self.sched._intake_pump,
+                                         daemon=True) for _ in range(n)]
+        for t in self.threads:
+            t.start()
+
+    @staticmethod
+    def claims():
+        return (obs.INTAKE_CLAIMS_SIGNALLED.value(),
+                obs.INTAKE_CLAIMS_UNSIGNALLED.value())
+
+    def new_claims(self):
+        return tuple(b - a for a, b in zip(self.claims0, self.claims()))
+
+    def empty_polls(self):
+        return obs.INTAKE_EMPTY_POLLS.value() - self.empty0
+
+    def all_waiting(self, timeout=10.0):
+        """True once every thread has come back from its first, empty claim
+        and waits on the queue."""
+        end = time.monotonic() + timeout
+        while time.monotonic() < end:
+            with self.q._wake:
+                if self.q._waiting == len(self.threads):
+                    return True
+            time.sleep(0.005)
+        return False
+
+    def ready(self, n, timeout):
+        """The ready set's job ids once it holds ``n`` (or at the timeout)."""
+        with self.sched._cond:
+            self.sched._cond.wait_for(
+                lambda: len(self.sched._ready) >= n, timeout)
+            return sorted(i.job.id for i in self.sched._ready)
+
+    def close(self, timeout=10.0):
+        """Stop, and spare the test the rest of a long poll interval: a
+        signal a waiter takes it to the top of its loop."""
+        self.stop.set()
+        for _ in self.threads:
+            self.q.publish(_job())
+        for t in self.threads:
+            t.join(timeout)
+        return not any(t.is_alive() for t in self.threads)
+
+
+def test_publish_wakes_a_waiting_intake(stack):
+    s, hub, q, store, worker = stack
+    intakes = _Intakes(worker, 4, poll_interval_s=5.0)
+    try:
+        ids = []
+        for i in range(3):
+            # One at a time: a thread still busy with a job claims the next
+            # straight after it, with no need of a signal.
+            assert intakes.all_waiting()
+            ids.append(q.publish(_job(i)))
+            assert intakes.ready(i + 1, timeout=2.0) == ids
+        assert intakes.all_waiting()
+        # Each came in on a thread the signal woke, none by the timer. The
+        # empty claims are the threads' first: a claim that saw nothing
+        # behind its job is not followed by one that finds the same.
+        assert intakes.new_claims() == (3, 0)
+        assert intakes.empty_polls() == 4
+    finally:
+        assert intakes.close()
+
+
+def test_publish_between_empty_claim_and_wait_is_not_slept_through(stack):
+    s, hub, q, store, worker = stack
+    claim = worker._claim
+    published = []
+
+    def claim_then_publish():
+        job = claim()
+        if job is None and not published:
+            published.append(q.publish(_job()))  # nobody waits yet
+        return job
+
+    worker._claim = claim_then_publish
+    intakes = _Intakes(worker, 1, poll_interval_s=5.0)
+    try:
+        assert intakes.ready(1, timeout=2.0) == published
+        assert intakes.new_claims() == (1, 0)
+    finally:
+        assert intakes.close()
+
+
+def test_a_backlog_is_drained_without_signal_or_timer(stack):
+    """Jobs that were there before anybody waited raise no signal: a claim
+    says whether another is behind it, and the thread claims on."""
+    s, hub, q, store, worker = stack
+    ids = [q.publish(_job(i)) for i in range(6)]
+    intakes = _Intakes(worker, 2, poll_interval_s=5.0)
+    try:
+        assert intakes.ready(6, timeout=2.0) == ids
+        assert intakes.new_claims() == (0, 6)
+    finally:
+        assert intakes.close()
+
+
+def test_claim_says_whether_another_job_is_behind(stack):
+    q = stack[2]
+    first, second, third = (q.publish(_job(i)) for i in range(3))
+    assert q.claim(exclude=[second, third]).more is False
+    q.release(first)
+    assert [q.claim().more, q.claim().more, q.claim().more] == [
+        True, True, False]
+    assert q.claim() is None
+
+
+def test_wait_for_work_returns_at_once_when_the_number_has_moved(stack):
+    q = stack[2]
+    seq = q.work_seq()
+    assert q.claim() is None
+    q.publish(_job())
+    signalled, now = q.wait_for_work(seq, 5.0)
+    assert signalled and now == seq + 1
+    # The number is spent with the claim it prompted: nothing new, no signal.
+    assert q.wait_for_work(now, 0.01) == (False, now)
+
+
+@pytest.mark.parametrize("how", ["publish", "nack", "release"])
+def test_whatever_makes_a_job_deliverable_wakes_a_waiter(stack, how):
+    q = stack[2]
+    if how != "publish":
+        q.publish(_job())
+        claimed = q.claim()
+    seq = q.work_seq()
+    woken = []
+    t = threading.Thread(
+        target=lambda: woken.append(q.wait_for_work(seq, 5.0)), daemon=True)
+    t.start()
+    while True:  # until it waits; the sequence number covers the gap anyway
+        with q._wake:
+            if q._waiting:
+                break
+        time.sleep(0.002)
+    if how == "publish":
+        q.publish(_job())
+    elif how == "nack":
+        assert q.nack(claimed.id) == "pending"
+    else:
+        q.release(claimed.id)
+    t.join(2.0)
+    assert not t.is_alive() and woken == [(True, seq + 1)]
+
+
+def test_what_makes_nothing_deliverable_wakes_nobody(stack):
+    s, hub, q, store, worker = stack
+    q.max_delivery_attempts = 1
+    q.publish(_job())
+    job = q.claim()
+    seq = q.work_seq()
+    assert q.nack(job.id) == "dead"  # dead-lettered, not requeued
+    q.release(job.id)                # no longer in flight: nothing to do
+    q.ack(job.id)
+    assert q.work_seq() == seq
+
+
+def test_one_signal_wakes_one_waiter(stack):
+    """Three wait, one job comes: one of them is told to claim. The others
+    time out unsignalled, and the number they are handed is current, so
+    their next wait does not end on the job that was not theirs."""
+    q = stack[2]
+    seq = q.work_seq()
+    out = []
+    threads = [threading.Thread(
+        target=lambda: out.append(q.wait_for_work(seq, 0.5)), daemon=True)
+        for _ in range(3)]
+    for t in threads:
+        t.start()
+    while True:
+        with q._wake:
+            if q._waiting == 3:
+                break
+        time.sleep(0.002)
+    q.publish(_job())
+    for t in threads:
+        t.join(5.0)
+    assert sorted(out) == [(False, seq + 1)] * 2 + [(True, seq + 1)]
+    assert q.wait_for_work(seq + 1, 0.01) == (False, seq + 1)
+
+
+def test_stop_ends_every_waiter_within_the_poll_interval(stack):
+    s, hub, q, store, worker = stack
+    intakes = _Intakes(worker, 4, poll_interval_s=0.5)
+    assert intakes.all_waiting()
+    intakes.stop.set()  # and nothing else: no signal, no publish
+    t0 = time.monotonic()
+    for t in intakes.threads:
+        t.join(5.0)
+    assert not any(t.is_alive() for t in intakes.threads)
+    # Generous for a loaded box, and still far from "never": each thread
+    # looks at stop once a poll interval.
+    assert time.monotonic() - t0 < 0.5 + 2.0
+    assert intakes.ready(0, 0.0) == []
+
+
+@pytest.mark.parametrize("how", ["second_queue_object", "visibility_timeout"])
+def test_the_timed_claim_finds_what_raises_no_signal(stack, how):
+    from vilbert_multitask_tpu.serve.queue import DurableQueue
+
+    s, hub, q, store, worker = stack
+    if how == "visibility_timeout":
+        # A consumer that claimed and died: in flight, nobody will ack it.
+        expected = q.publish(_job())
+        assert q.claim().id == expected
+        q.visibility_timeout_s = 0.3
+    intakes = _Intakes(worker, 4, poll_interval_s=0.1)
+    try:
+        assert intakes.all_waiting()
+        seq = q.work_seq()
+        if how == "second_queue_object":
+            # What another process does: its own object on the same file.
+            expected = DurableQueue(s.queue_db_path).publish(_job())
+        assert intakes.ready(1, timeout=10.0) == [expected]
+        assert q.work_seq() == seq  # no signal was raised for it
+        assert intakes.new_claims() == (0, 1)
+    finally:
+        assert intakes.close()
+
+
+def test_idle_intakes_poll_like_one_thread_and_count_every_claim(stack):
+    s, hub, q, store, worker = stack
+    n, poll, jobs = 4, 0.05, 5
+    intakes = _Intakes(worker, n, poll_interval_s=poll)
+    try:
+        assert intakes.all_waiting()
+        t0 = time.monotonic()
+        ids = []
+        for i in range(jobs):
+            time.sleep(0.2)
+            ids.append(q.publish(_job(i)))
+        assert intakes.ready(jobs, timeout=5.0) == ids
+        elapsed = time.monotonic() - t0
+        empty, claims = intakes.empty_polls(), intakes.new_claims()
+    finally:
+        assert intakes.close()
+    # One timed claim a poll interval whatever the number of threads (four
+    # sleepers of their own made n of them), plus the n first claims and at
+    # most one lost race a job. A loaded box polls late, never more often.
+    assert n < empty <= elapsed / poll + n + jobs + 1
+    assert empty < 0.6 * n * elapsed / poll
+    assert sum(claims) == jobs

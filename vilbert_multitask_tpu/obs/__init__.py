@@ -123,6 +123,7 @@ __all__ = [
     "RESULT_CACHE_HITS", "RESULT_CACHE_MISSES",
     "RESULT_CACHE_INVALIDATIONS", "COALESCED_SUBMITS", "TENANT_DEFICIT",
     "SCHED_READY_JOBS", "INTAKE_EMPTY_POLLS", "INTAKE_BACKPRESSURE_POLLS",
+    "INTAKE_CLAIMS_SIGNALLED", "INTAKE_CLAIMS_UNSIGNALLED",
     "FEATURE_STORE_HITS", "FEATURE_STORE_MISSES", "FEATURE_STORE_READ_BYTES",
     "FEATURE_STORE_LOAD_SECONDS", "INPUT_CACHE_HITS", "INPUT_CACHE_MISSES",
     "INPUT_CACHE_INSERTS",
@@ -202,7 +203,18 @@ SCHED_READY_JOBS = REGISTRY.histogram(
 )
 INTAKE_EMPTY_POLLS = REGISTRY.counter(
     "vmt_intake_empty_polls_total",
-    "Intake poll-interval sleeps taken because the claim came back empty.",
+    "Intake claims that came back empty: the timed fallback claim of one "
+    "idle thread at a time, and claims another thread got ahead of.",
+)
+INTAKE_CLAIMS_SIGNALLED = REGISTRY.counter(
+    "vmt_intake_claims_signalled_total",
+    "Intake claims that returned a job on a thread the queue's signal had "
+    "just woken (a publish, nack or release in this process).",
+)
+INTAKE_CLAIMS_UNSIGNALLED = REGISTRY.counter(
+    "vmt_intake_claims_unsignalled_total",
+    "Intake claims that returned a job after a timed wake or straight "
+    "after the thread's last job.",
 )
 INTAKE_BACKPRESSURE_POLLS = REGISTRY.counter(
     "vmt_intake_backpressure_polls_total",

@@ -18,9 +18,10 @@ from __future__ import annotations
 import json
 import os
 import sqlite3
+import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 from vilbert_multitask_tpu import obs
 from vilbert_multitask_tpu.resilience.faults import fault_point
@@ -32,6 +33,10 @@ class Job:
     body: Dict[str, Any]
     attempts: int
     deliveries: int = 0
+    # From claim(): was another job deliverable behind this one, in the same
+    # transaction's view. False spares the consumer the claim that would
+    # only find the queue empty; None: this queue does not say.
+    more: Optional[bool] = None
 
 
 class DurableQueue:
@@ -59,6 +64,14 @@ class DurableQueue:
         self.max_delivery_attempts = max_delivery_attempts
         self.max_deliveries = max_deliveries
         self.visibility_timeout_s = visibility_timeout_s
+        # In-process wake-up from whoever makes a job deliverable (publish,
+        # nack to pending, release) to consumers waiting in wait_for_work().
+        # Nothing durable: the claim stays the hand-over, this only says
+        # when to try. _wake guards the three numbers; no sqlite under it.
+        self._wake = threading.Condition()
+        self._work_seq = 0    # signals so far
+        self._waiting = 0     # threads inside wait_for_work()
+        self._wake_tokens = 0  # signals granted to a waiter, not yet taken
         if os.path.dirname(path):
             os.makedirs(os.path.dirname(path), exist_ok=True)
         with self._conn() as c:
@@ -114,7 +127,56 @@ class DurableQueue:
                 "INSERT INTO jobs (queue, body, created_at) VALUES (?, ?, ?)",
                 (self.queue_name, json.dumps(body), time.time()),
             )
-            return int(cur.lastrowid)
+            job_id = int(cur.lastrowid)
+        self._signal_work()  # after the commit: a woken claim must see the row
+        return job_id
+
+    # ------------------------------------------------------------------ wake-up
+    def work_seq(self) -> int:
+        """The number of signals so far. Read it BEFORE a claim: handed to
+        :meth:`wait_for_work` after the claim came back empty, it turns a
+        publish that landed in between into an immediate return."""
+        with self._wake:
+            return self._work_seq
+
+    def _signal_work(self) -> None:
+        """One more job is deliverable: wake one waiter, if one is free.
+
+        A token is granted only while fewer are out than threads wait, so
+        one signal costs at most one claim; a signal nobody waits for is
+        seen through the sequence number by the next thread that would."""
+        with self._wake:
+            self._work_seq += 1
+            if self._wake_tokens < self._waiting:
+                self._wake_tokens += 1
+                self._wake.notify()
+
+    def wait_for_work(self, seen_seq: int, timeout_s: float
+                      ) -> Tuple[bool, int]:
+        """Wait until this process makes a job deliverable, ``timeout_s`` at
+        most. Returns ``(signalled, seq)``: ``signalled`` says a claim is
+        worth trying now, ``seq`` is what to pass to the next wait if the
+        caller goes back to waiting without a claim in between.
+
+        Returns at once when the sequence number has moved since
+        ``seen_seq``. Jobs another process writes into the file, and claims
+        whose visibility timeout ran out, raise no signal: consumers keep a
+        timed claim for those."""
+        with self._wake:
+            if self._work_seq != seen_seq:
+                return True, self._work_seq
+            self._waiting += 1
+            try:
+                # The token, not the notify, is the hand-over: a notify spent
+                # on a waiter that was timing out is not lost, and a waiter
+                # passed over does not mistake a moved number for its turn.
+                signalled = self._wake.wait_for(
+                    lambda: self._wake_tokens > 0, timeout_s)
+                if signalled:
+                    self._wake_tokens -= 1
+                return signalled, self._work_seq
+            finally:
+                self._waiting -= 1
 
     # ---------------------------------------------------------------- consumer
     def claim(self, exclude: Sequence[int] = (),
@@ -165,17 +227,18 @@ class DurableQueue:
                 f" AND id NOT IN ({','.join('?' * len(exclude))})"
                 if exclude else ""
             )
-            row = c.execute(
+            # The oldest is claimed; the second only says whether one is left.
+            rows = c.execute(
                 "SELECT id, body, attempts, delivery_count FROM jobs "
                 f"WHERE queue=? AND status='pending'{not_in} "
-                "ORDER BY id LIMIT 1",
+                "ORDER BY id LIMIT 2",
                 (self.queue_name, *exclude),
-            ).fetchone()
-            if row is None:
+            ).fetchall()
+            if not rows:
                 if poisoned:
                     obs.POISON_COUNTER.inc(poisoned)
                 return None
-            job_id, body, attempts, deliveries = row
+            job_id, body, attempts, deliveries = rows[0]
             c.execute(
                 "UPDATE jobs SET status='inflight', attempts=attempts+1, "
                 "delivery_count=delivery_count+1, claimed_at=?, "
@@ -185,7 +248,7 @@ class DurableQueue:
         if poisoned:
             obs.POISON_COUNTER.inc(poisoned)
         return Job(id=job_id, body=json.loads(body), attempts=attempts + 1,
-                   deliveries=deliveries + 1)
+                   deliveries=deliveries + 1, more=len(rows) > 1)
 
     def ack(self, job_id: int) -> None:
         """Success: remove the job (reference basic_ack, worker.py:650)."""
@@ -225,6 +288,8 @@ class DurableQueue:
             # claim-side sweep — the autoscaler's storm gate reads the
             # counter's windowed rate and must see BOTH paths.
             obs.POISON_COUNTER.inc()
+        else:
+            self._signal_work()
         return status
 
     def release(self, job_id: int) -> None:
@@ -233,12 +298,14 @@ class DurableQueue:
         shutdown with claims in hand). The batch worker's failure path uses
         ``claim(exclude=...)`` instead — release is for *unprocessed* jobs."""
         with self._conn() as c:
-            c.execute(
+            requeued = c.execute(
                 "UPDATE jobs SET status='pending', claimed_at=NULL, "
                 "claimed_by=NULL, attempts=MAX(attempts-1, 0) "
                 "WHERE id=? AND status='inflight'",
                 (job_id,),
-            )
+            ).rowcount
+        if requeued:
+            self._signal_work()
 
     # ------------------------------------------------------------------ introspection
     def counts(self) -> Dict[str, int]:

@@ -250,6 +250,13 @@ class ContinuousScheduler:
         self._tenant_wait_ms: dict = {}
         self._completions: stdlib_queue.Queue = stdlib_queue.Queue(
             maxsize=self.serving.sched_completion_depth)
+        # The intake's timed claim, one thread at a time: when the next one
+        # is due (time.monotonic), poll_interval_s after the last claim any
+        # intake thread began. Idle threads all wake at that moment, the
+        # first moves it on and claims, the rest go back to waiting.
+        # Guarded by _poll_lock, under which nothing blocks.
+        self._poll_lock = threading.Lock()
+        self._next_poll_at = 0.0
         # Replica-pool mode: when the worker's engine is a ReplicaPool
         # (duck-typed on the checkout seam), batches PIN to one replica —
         # checkout here, dispatch on an executor thread (one in-flight
@@ -268,7 +275,17 @@ class ContinuousScheduler:
 
     # -------------------------------------------------------- intake stage
     def _intake_loop(self) -> None:
-        """Claim continuously; prep on this thread; park ready items.
+        """Claim when there may be work; prep on this thread; park ready
+        items.
+
+        When: straight after this thread's last job, unless that claim saw
+        the queue empty behind it; when the queue signals that this process
+        made a job deliverable (publish, nack, release); and, on one idle
+        thread at a time, every ``poll_interval_s``. The timed claim is
+        what finds a job another process wrote into the file or a claim
+        whose visibility timeout ran out, and what sends the dead-letter
+        notices. The claim itself stays the durable hand-over; the signal
+        only chooses its moment.
 
         Backpressure: while the ready set is at ``sched_ready_depth`` this
         thread idles instead of claiming — ready jobs stay 'inflight' in
@@ -285,7 +302,34 @@ class ContinuousScheduler:
         with obs.crash_guard(threading.current_thread().name):
             self._intake_pump()
 
+    def _await_work(self, seq: int) -> bool:
+        """After a claim that left the queue empty: wait for the queue's
+        signal or for this thread's turn at the timed claim, whichever is
+        first. True when a signal ended the wait. ``seq`` is the queue's
+        ``work_seq()`` from before that claim, so a publish since then is
+        not slept through. ``stop`` is looked at every ``poll_interval_s``
+        at the latest."""
+        wait_for_work = getattr(self.worker.queue, "wait_for_work", None)
+        if wait_for_work is None:
+            # A queue that cannot signal (serve/remote.py: the jobs are on
+            # another host): the timer alone, on every thread.
+            self.stop.wait(self.poll_interval_s)
+            return False
+        while not self.stop.is_set():
+            now = time.monotonic()
+            with self._poll_lock:
+                if now >= self._next_poll_at:
+                    self._next_poll_at = now + self.poll_interval_s
+                    return False
+                due_in = self._next_poll_at - now
+            signalled, seq = wait_for_work(seq, due_in)
+            if signalled:
+                return True
+        return False
+
     def _intake_pump(self) -> None:
+        work_seq = getattr(self.worker.queue, "work_seq", lambda: 0)
+        signalled = False  # did a signal end this thread's last wait
         while not self.stop.is_set():
             with self._cond:
                 backlog = len(self._ready)
@@ -293,35 +337,53 @@ class ContinuousScheduler:
                 obs.INTAKE_BACKPRESSURE_POLLS.inc()
                 self.stop.wait(self.poll_interval_s)
                 continue
+            seq = work_seq()
+            with self._poll_lock:
+                # Whatever prompted it, a claim looks at the whole queue:
+                # the timed one is not due before poll_interval_s from now.
+                self._next_poll_at = time.monotonic() + self.poll_interval_s
             job = self.worker._claim()
             if job is None:
                 obs.INTAKE_EMPTY_POLLS.inc()
-                self.stop.wait(self.poll_interval_s)
+                signalled = self._await_work(seq)
                 continue
-            if self.worker._check_deadline(job):
-                continue  # expired on arrival: terminal push already sent
-            enq_t = self.clock()
-            deadline = self.worker._deadline_of(job)
-            tenant = str(job.body.get("tenant") or "anon")
-            if job.body.get("collect_attention"):
-                # Per-request forward flag: step_one runs the whole
-                # pipeline solo at dispatch, so no shared intake here.
-                item = ReadyItem(job, None, None, None, deadline, enq_t,
-                                 solo=True, tenant=tenant)
-            else:
-                try:
-                    with obs.trace_scope(job.body.get("trace_id")), \
-                            obs.span("worker.intake", job_id=job.id,
-                                     task_id=job.body.get("task_id", "")):
-                        qa_id, prepared, t0 = self.worker._intake(job)
-                except Exception:
-                    self.worker._fail_job(job)
-                    continue
-                item = ReadyItem(job, qa_id, prepared, t0, deadline, enq_t,
-                                 tenant=tenant)
-            with self._cond:
-                self._ready.append(item)
-                self._cond.notify()
+            (obs.INTAKE_CLAIMS_SIGNALLED if signalled
+             else obs.INTAKE_CLAIMS_UNSIGNALLED).inc()
+            signalled = False
+            self._park(job)
+            if job.more is False:
+                # The claim saw nothing deliverable behind this job, and the
+                # number tells whether anything has been made so since: no
+                # second claim only to find the queue empty.
+                signalled = self._await_work(seq)
+
+    def _park(self, job: Job) -> None:
+        """Deadline check and prep of one claimed job, on the intake thread;
+        the ready item is handed to the dispatch stage."""
+        if self.worker._check_deadline(job):
+            return  # expired on arrival: terminal push already sent
+        enq_t = self.clock()
+        deadline = self.worker._deadline_of(job)
+        tenant = str(job.body.get("tenant") or "anon")
+        if job.body.get("collect_attention"):
+            # Per-request forward flag: step_one runs the whole
+            # pipeline solo at dispatch, so no shared intake here.
+            item = ReadyItem(job, None, None, None, deadline, enq_t,
+                             solo=True, tenant=tenant)
+        else:
+            try:
+                with obs.trace_scope(job.body.get("trace_id")), \
+                        obs.span("worker.intake", job_id=job.id,
+                                 task_id=job.body.get("task_id", "")):
+                    qa_id, prepared, t0 = self.worker._intake(job)
+            except Exception:
+                self.worker._fail_job(job)
+                return
+            item = ReadyItem(job, qa_id, prepared, t0, deadline, enq_t,
+                             tenant=tenant)
+        with self._cond:
+            self._ready.append(item)
+            self._cond.notify()
 
     # ------------------------------------------------------ dispatch stage
     def _next_batch(self) -> Tuple[List[ReadyItem], List[ReadyItem]]:

@@ -17,13 +17,12 @@ Numbers compared, each with its limit (``benchmark/limits/<cell>.json``):
 
 from __future__ import annotations
 
-import importlib
 import math
 
 import numpy as np
 
+from ...reference import inputs
 from . import catalog
-from ..reference import inputs
 
 LABEL_TASKS = {1: "vqa", 2: "vqa", 15: "gqa"}
 GROUNDING_TASKS = (4, 11, 16)
@@ -191,15 +190,14 @@ def compare(picked: list, stamps: dict, references: list) -> dict:
             "per_head_rms": per_head, "per_head_scale": per_head_scale}
 
 
-def run_reference(config: dict, params: dict, picked: list,
+def run_reference(config: dict, module, params: dict, picked: list,
                   feature_root: str, vocab_path: str, lower=None) -> list:
-    """The reference's head outputs for each picked request, one request to
-    a call, all through one compiled float32 program (``lower``: with the
+    """The head outputs of the reference (``module``: the configuration's
+    file under ``reference/``) for each picked request, one request to a
+    call, all through one compiled float32 program (``lower``: with the
     dense layers' operands rounded to that precision, for the control)."""
     import jax
 
-    module = importlib.import_module(
-        f"benchmark.reference.{config['reference']}")
     model, engine = config["model"], config["engine"]
     vocab = inputs.load_vocab(vocab_path)
     fwd = jax.jit(lambda p, b: module.forward(p, model, b, lower=lower))
@@ -214,12 +212,3 @@ def run_reference(config: dict, params: dict, picked: list,
                 REFERENCE_ROWS)
             out.append(jax.device_get(fwd(params, batch)))
     return out
-
-
-def verdict(numbers: dict, limits: dict) -> tuple:
-    """(correct, the compared numbers each beside its limit)."""
-    beside = {name: {"value": numbers[name], "limit": limits[name]}
-              for name in limits}
-    ok = all(numbers[name] <= limits[name] and numbers["compared"] > 0
-             for name in limits)
-    return ok, beside

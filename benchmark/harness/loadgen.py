@@ -15,8 +15,11 @@ Commands, one JSON object per line; each is answered by one line:
    "threads": n, "grace_s": s}
   {"cmd": "quit"}
 
-``run`` reads a schedule (``traffic.schedule``), sends it and writes one
-stamp line per request to ``out``. In an open-loop schedule request ``r`` is
+``run`` reads a schedule (``harness/arrivals.py``), sends it and writes one
+stamp line per request to ``out``. A request's ``body`` is POSTed as it is,
+with the ``socket_id`` of its session's websocket added; the frame whose
+``result[key_field]`` equals the request's ``key`` is its answer, whatever
+else either holds. In an open-loop schedule request ``r`` is
 sent at ``t0 + r.due`` (sleep, then spin the last millisecond) whatever
 became of the others; in a closed-loop one each client sends its next
 request when the last is answered, from ``t0 - warm_seconds`` (the callers'
@@ -45,7 +48,9 @@ class Clients:
 
         self.http_port = http_port
         self.lock = threading.Lock()
-        self.waiting: dict = {}   # question → stamp dict of an open request
+        # (key_field, key) → stamp dict of an open request
+        self.waiting: dict = {}
+        self.key_fields: tuple = ()
         self.socket_ids = [f"bench-{k}" for k in range(sockets)]
         self.sockets = []
         self.readers = []
@@ -71,8 +76,14 @@ class Clients:
             result = frame.get("result")
             if result is None:
                 continue
+            stamp = None
             with self.lock:
-                stamp = self.waiting.get(result.get("question", ""))
+                for field in self.key_fields:
+                    value = result.get(field)
+                    if isinstance(value, (str, int)):
+                        stamp = self.waiting.get((field, value))
+                    if stamp is not None:
+                        break
             if stamp is None:
                 continue
             if "recv" in stamp:
@@ -86,15 +97,12 @@ class Clients:
         """Submit one request; returns its stamp (filled in as it goes)."""
         stamp = {"i": request["i"], "due": due,
                  "answered": threading.Event()}
-        # The server lower-cases questions; frames carry them that way.
         with self.lock:
-            self.waiting[request["question"].lower()] = stamp
-        body = json.dumps({
-            "task_id": request["task_id"],
-            "socket_id": self.socket_ids[request["session"]
-                                         % len(self.socket_ids)],
-            "question": request["question"],
-            "image_list": request["images"]})
+            self.waiting[(request["key_field"], request["key"])] = stamp
+        body = json.dumps(dict(
+            request["body"],
+            socket_id=self.socket_ids[request["session"]
+                                      % len(self.socket_ids)]))
         stamp["send"] = time.monotonic()
         try:
             conn = http.client.HTTPConnection("127.0.0.1", self.http_port,
@@ -194,6 +202,7 @@ def run(clients: Clients, cmd: dict) -> dict:
         sched = json.load(f)
     t0 = cmd["t0"]
     requests = sched["requests"]
+    clients.key_fields = tuple(sorted({r["key_field"] for r in requests}))
     if sched["arrivals"] == "open":
         stamps = run_open(clients, requests, t0, cmd["threads"])
     else:

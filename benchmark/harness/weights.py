@@ -1,7 +1,10 @@
-"""The served weights, made by the benchmark: on the device, in one jitted
-call, from ``--seed``, in float32 (the type the configurations store and
-serve them in). The program is handed this tree and so is the reference;
-neither takes anything from the other."""
+"""Served weights made by the benchmark: on the device, in one jitted call,
+from ``--seed``, in float32. A helper for the families whose configurations
+store float32 (``families/<family>``: ``weights``); the program is handed
+this tree and so is the reference, and neither takes anything from the
+other. The one draw holds the whole tree twice for a moment, so a family
+whose tree does not fit beside a second copy of itself draws leaf by leaf,
+in its stored type, and not through here."""
 
 from __future__ import annotations
 

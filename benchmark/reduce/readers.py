@@ -6,16 +6,22 @@ and the metric is left out of the line; none ever returns a made-up 0.
 
 Context keys: ``spans`` [(name, start_s, dur_s)] of the program's tracer in
 the window; ``histograms`` {instrument: {label tuple: [samples in window]}};
-``counters`` {"before"/"after": {name: value}} (label sets summed, plus the
-engine's ``input_cache.hits`` / ``.misses``); ``stamps`` the generator's
-stamps of the window's requests; ``setup`` {phase: seconds}; ``trace``
-{"ops", "modules", "busy_s", "window_s"} of the traced part of the window,
-on the device that was busiest; ``rows_in_trace`` image rows the scheduler
-dispatched in that part; ``flops_per_row``; ``peaks``.
+``counters`` {"before"/"after": {name: value}} of the program's registry
+(label sets summed); ``stamps`` the generator's stamps of the window's
+requests; ``setup`` {phase: seconds}; ``seconds`` the window's length;
+``trace`` {"ops", "modules", "busy_s", "window_s"} of the traced part of
+the window, on the device that was busiest; ``units_in_trace`` the units of
+work (the family's: image rows, tokens) dispatched in that part;
+``flops_per_unit`` the matmul FLOPs of one; ``peaks``.
+
+A kind is a function here (:data:`KINDS`) or a file brought beside this one:
+``kinds/<kind>.py`` with ``read(ctx, **params)``.
 """
 
 from __future__ import annotations
 
+import importlib
+import os
 import re
 import statistics
 
@@ -110,18 +116,18 @@ def trace_module_ms(ctx, module_contains: str):
 
 
 def trace_mfu(ctx, module_contains: str, over: str = "modules"):
-    """A share of the chip's bf16 peak: matmul FLOPs of the image rows
+    """A share of the chip's bf16 peak: matmul FLOPs of the units of work
     dispatched in the traced part, over the peak times either the device
     time of the forward executables there (``over: "modules"``) or the whole
     traced part's length (``over: "window"``, the end-to-end utilization)."""
     trace = ctx.get("trace")
-    if not trace or not ctx.get("rows_in_trace"):
+    if not trace or not ctx.get("units_in_trace"):
         return None
     seconds = (trace["window_s"] if over == "window" else
                sum(d for _, _, d in _module_events(ctx, module_contains)))
     if not seconds:
         return None
-    flops = ctx["rows_in_trace"] * ctx["flops_per_row"]
+    flops = ctx["units_in_trace"] * ctx["flops_per_unit"]
     return 100.0 * flops / (seconds * ctx["peaks"]["bf16_flops_per_s"])
 
 
@@ -184,12 +190,21 @@ KINDS = {
 }
 
 
+def find_kind(kind: str):
+    """The reader of that kind: one of :data:`KINDS`, else ``read`` of
+    ``kinds/<kind>.py`` beside this file; an unknown kind is an error."""
+    if kind in KINDS:
+        return KINDS[kind]
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)), "kinds")
+    if os.path.exists(os.path.join(here, kind + ".py")):
+        return importlib.import_module(f".kinds.{kind}", __package__).read
+    raise SystemExit(f"metric reader kind {kind!r} is neither one of "
+                     f"{sorted(KINDS)} nor a file benchmark/reduce/kinds/"
+                     f"{kind}.py")
+
+
 def read(reader: dict, ctx: dict):
-    """Apply one metric file's reader; unknown kinds are an error."""
-    kind = reader["kind"]
-    if kind not in KINDS:
-        raise SystemExit(f"metric reader kind {kind!r} is not one of "
-                         f"{sorted(KINDS)}")
+    """Apply one metric file's reader."""
     params = {("percentile_" if k == "percentile" else k): v
               for k, v in reader.get("params", {}).items()}
-    return KINDS[kind](ctx, **params)
+    return find_kind(reader["kind"])(ctx, **params)

@@ -10,14 +10,22 @@ against the plain reference, and prints one JSON object as its last line.
 the last seconds of the same window and reports the per-layer metrics.
 Without a TPU it exits 1 and prints no result.
 
-Three flags are for the builder, never for the driver:
+This file holds what is true of every cell: the phases, the generator
+child, the window's rules, counters before and after, spans, the profiler,
+the result line. What a cell needs of its architecture (assets, schedule,
+weights, the server, the unit of work and its cost, the check) it asks of
+the *family* the cell's configuration names (``benchmark/families/``).
+
+Four flags are for the builder, never for the driver:
 ``--rehearsal`` runs the same phases on the CPU at the tiny size of
-``benchmark/tests/tiny.json``; its result says ``"platform": "cpu"`` and
-``"rehearsal": true`` and is no measurement. ``--control fp8`` puts the
-reference, computed in float8, a precision below the configurations'
-bfloat16, in the program's place in the comparison: ``correct`` has to come
-out false. ``--override`` merges JSON over the cell's files, for a sweep
-(the knee of a new traffic mix).
+``benchmark/tests/tiny.<family>.json``; its result says ``"platform":
+"cpu"`` and ``"rehearsal": true`` and is no measurement. ``--control
+<precision>`` puts the reference, computed in a precision below the one the
+configuration states (``fp8`` under bfloat16), in the program's place in
+the comparison: ``correct`` has to come out false. ``--override`` merges
+JSON over the cell's files, for a sweep (the knee of a new traffic mix).
+``--manifest`` names another manifest than ``BENCHMARK.json``, whose own
+directories are searched first (``benchmark/tests/stub``).
 """
 
 from __future__ import annotations
@@ -39,12 +47,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.harness import catalog, check, traffic  # noqa: E402
-from benchmark.harness.spec import BENCH_DIR, CACHE_DIR, Spec, peaks_for  # noqa: E402
-from benchmark.reduce import flops, readers  # noqa: E402
+from benchmark.harness.spec import (  # noqa: E402
+    BENCH_DIR,
+    CACHE_DIR,
+    MANIFEST,
+    Spec,
+    peaks_for,
+)
+from benchmark.reduce import readers  # noqa: E402
 from benchmark.reduce import trace as trace_reduce  # noqa: E402
 
-VOCAB = os.path.join(BENCH_DIR, "assets", "vocab.txt")
 TRACE_SECONDS = 6.0     # the traced part: the window's last seconds
 TRACE_LEAD_S = 1.5      # the profiler is started this long before it
 GRACE_S = 60.0          # how long after the window a frame may still come
@@ -115,15 +127,9 @@ def read_stamps(path: str) -> dict:
         return {s["i"]: s for s in map(json.loads, f)}
 
 
-def counters_now(obs, app) -> dict:
-    out = {}
-    for inst in obs.REGISTRY.instruments():
-        if inst.kind == "counter":
-            out[inst.name] = float(sum(inst.collect().values()))
-    stats = app.engine.input_cache_stats
-    out["input_cache.hits"] = float(stats["hits"])
-    out["input_cache.misses"] = float(stats["misses"])
-    return out
+def counters_now(obs) -> dict:
+    return {inst.name: float(sum(inst.collect().values()))
+            for inst in obs.REGISTRY.instruments() if inst.kind == "counter"}
 
 
 def histograms_since(obs, window_s: float) -> dict:
@@ -181,7 +187,8 @@ def mark(jax, name: str, marks: dict) -> None:
 def window_numbers(sched: dict, stamps: dict, t0: float, seconds: float,
                    wait_end: float) -> dict:
     """What the window's requests say: attempted, failed, latencies (ms) and
-    rows answered, by the rules in PERF.md section 2."""
+    rows (the family's unit of work) answered, by the rules in PERF.md
+    section 2."""
     requests = sched["requests"]
     rows_answered = 0
     latencies, window_ids, failed, lost_before = [], [], 0, 0
@@ -197,7 +204,7 @@ def window_numbers(sched: dict, stamps: dict, t0: float, seconds: float,
             start = s["send"]
         answered = s.get("status") == 200 and "recv" in s
         if answered and t0 <= s["recv"] < t0 + seconds:
-            rows_answered += len(r["images"])
+            rows_answered += r["rows"]
         if not in_window:
             # The warm phase is not judged, but a frame lost there is said.
             lost_before += s.get("status") == 200 and not answered
@@ -221,10 +228,15 @@ def parse_args(argv):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearsal", action="store_true")
-    ap.add_argument("--control", choices=("fp8",), default=None)
+    ap.add_argument("--control", default=None, metavar="PRECISION",
+                    help="builder only: the reference in this lower "
+                         "precision (fp8) takes the program's place")
     ap.add_argument("--override", type=json.loads, default={},
                     help="builder's sweeps only: JSON merged over the files, "
                          '{"config": {...}, "traffic": {...}}')
+    ap.add_argument("--manifest", default=MANIFEST,
+                    help="builder only: another manifest than "
+                         "BENCHMARK.json")
     return ap.parse_args(argv)
 
 
@@ -233,9 +245,7 @@ def cell_files(args, spec: Spec) -> tuple:
     uses them."""
     config, traffic_file, limits = spec.config, spec.traffic, spec.limits
     if args.rehearsal:
-        with open(os.path.join(BENCH_DIR, "tests", "tiny.json"),
-                  encoding="utf-8") as f:
-            tiny = json.load(f)
+        tiny = spec.rehearsal_sizes()
         config = merge(config, tiny["config"])
         traffic_file = merge(traffic_file, tiny["traffic"])
         limits = merge(limits, tiny["limits"])
@@ -261,45 +271,30 @@ def find_device(jax, args, spec: Spec) -> tuple:
     return dev, device
 
 
-def set_up(jax, args, config, traffic_file, generator, state_dir) -> dict:
-    """Everything before the first timed request: the catalog, the schedule,
-    weights from the seed, and the server with this cell's buckets warmed
-    and the gallery put into its device cache by the program's own inserts.
-    Returns what the window and the check need."""
-    model = config["model"]
-    phases = {}
-    t = time.monotonic()
-    feature_root = catalog.ensure(traffic_file, model["v_feature_size"],
-                                  CACHE_DIR)
-    phases["feature_store_s"] = time.monotonic() - t
-    sched = traffic.schedule(traffic_file, args.seed, args.seconds,
-                             traffic.load_words(VOCAB))
+def set_up(jax, args, family, config, traffic_file, generator,
+           state_dir) -> dict:
+    """Everything before the first timed request, each step the family's:
+    its assets on disk, the schedule, weights from the seed, and the server
+    booted and warmed for this cell's traffic. Returns what the window and
+    the check need."""
+    assets, phases = family.assets(config, traffic_file, CACHE_DIR)
+    sched = family.schedule(traffic_file, args.seed, args.seconds, assets)
     with open(os.path.join(state_dir, "schedule.json"), "w",
               encoding="utf-8") as f:
         json.dump(sched, f)
 
-    import importlib
-
-    from benchmark.harness import server, weights
     from vilbert_multitask_tpu.engine import cachedir
 
     # Before the first compile: every program of a run after the
     # checkout's first comes out of the persistent cache.
     cachedir.enable_compilation_cache()
-    shapes = importlib.import_module(
-        f"benchmark.reference.{config['reference']}").param_shapes(model)
     t = time.monotonic()
-    params = weights.make(shapes, args.seed)
+    params, n_params = family.weights(config, args.seed)
     jax.block_until_ready(params)
     phases["weights_s"] = time.monotonic() - t
-    say(f"parameters: {weights.count(shapes)}")
-    labels_root = os.path.join(state_dir, "labels")
-    server.write_label_maps(labels_root, model)
-    cfg = server.framework_config(config, state_dir, labels_root, VOCAB,
-                                  args.rehearsal)
-    app, boot_phases = server.boot(cfg, params, feature_root,
-                                   traffic_file["row_buckets"],
-                                   traffic.gallery(traffic_file))
+    say(f"parameters: {n_params}")
+    app, boot_phases = family.boot(config, traffic_file, params, assets,
+                                   state_dir, args.rehearsal)
     phases.update(boot_phases)
     try:
         generator.ask(cmd="connect", http_port=app.http_port,
@@ -309,11 +304,11 @@ def set_up(jax, args, config, traffic_file, generator, state_dir) -> dict:
         app.stop()
         raise
     return {"app": app, "params": params, "sched": sched,
-            "feature_root": feature_root, "phases": phases}
+            "assets": assets, "phases": phases}
 
 
-def drive_window(jax, args, traffic_file, generator, app, sched, state_dir,
-                 trace_dir) -> dict:
+def drive_window(jax, args, family, traffic_file, generator, app, sched,
+                 state_dir, trace_dir) -> dict:
     """The warm phase and the window: the generator sends, this process
     only watches (counters before and after, the tracer's spans, and with
     ``--trace 1`` the profiler over the window's last seconds)."""
@@ -333,8 +328,8 @@ def drive_window(jax, args, traffic_file, generator, app, sched, state_dir,
     sleep_until(t0)
     tick("window_start")
     seen = {"t0": t0, "t_end": t_end, "setup_s": t0 - T_PROCESS_START,
-            "before": counters_now(obs, app), "marks": {}, "spans": [],
-            "histograms": {}, "rows_in_trace": 0.0}
+            "before": counters_now(obs), "marks": {}, "spans": [],
+            "histograms": {}, "units_in_trace": 0.0}
     traced = args.trace == 1
     poller = None
     if traced:
@@ -350,13 +345,10 @@ def drive_window(jax, args, traffic_file, generator, app, sched, state_dir,
     tick("window_end")
     if traced:
         mark(jax, "end", seen["marks"])
-        fill = obs.BATCH_FILL
-        for labels in fill.series_counts():
-            shares = fill.window_samples(
-                time.monotonic() - seen["marks"]["start"], bucket=labels[0])
-            seen["rows_in_trace"] += sum(shares) * float(labels[0])
+        seen["units_in_trace"] = family.units_since(app,
+                                                    seen["marks"]["start"])
         jax.profiler.stop_trace()
-    seen["after"] = counters_now(obs, app)
+    seen["after"] = counters_now(obs)
     if poller:
         seen["histograms"] = histograms_since(obs, time.monotonic() - t0)
     worker.join()
@@ -369,48 +361,51 @@ def drive_window(jax, args, traffic_file, generator, app, sched, state_dir,
     return seen
 
 
-def read_memory(dev, app, config) -> int:
+def read_memory(dev, family, app, config) -> int:
     """Prints the device's memory as the window left it; returns the peak.
-    ``written_share`` leaves out the device cache's rows that nothing was
-    ever written to: reserved, not held."""
+    ``written_share`` leaves out what the family says the device holds
+    reserved and unwritten: reserved, not held."""
     stats = dev.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     limit = stats.get("bytes_limit") or 0
     in_use = stats.get("bytes_in_use", 0)
-    engine, model = config["engine"], config["model"]
-    # One cached image: features as they are shipped (two bytes each under
-    # a 16-bit compute type), float32 boxes, an int32 mask.
-    wide = 2 if engine["compute_dtype"] in ("bfloat16", "float16") else 4
-    row_bytes = engine["max_regions"] * (model["v_feature_size"] * wide + 24)
-    entries = engine["device_input_cache_entries"]
-    written = app.engine.input_cache_stats["entries"]
+    unwritten, details = family.unwritten_bytes(app, config)
     say("memory_stats: " + json.dumps({
         "bytes_in_use": in_use, "peak_bytes_in_use": peak,
-        "bytes_limit": limit, "cache_entries": entries,
-        "cache_entries_written": written,
+        "bytes_limit": limit, **details,
         "resident_share": in_use / limit if limit else None,
-        "written_share": ((in_use - (entries - written) * row_bytes) / limit
-                          if limit else None),
+        "written_share": (in_use - unwritten) / limit if limit else None,
         "peak_share": peak / limit if limit else None}))
     return peak
 
 
-def judge(args, config, limits, up, stamps, window_ids,
+def verdict(numbers: dict, limits: dict) -> tuple:
+    """(correct, the compared numbers each beside its limit)."""
+    beside = {name: {"value": numbers[name], "limit": limits[name]}
+              for name in limits}
+    ok = all(numbers[name] <= limits[name] and numbers["compared"] > 0
+             for name in limits)
+    return ok, beside
+
+
+def judge(args, family, config, limits, up, stamps, window_ids,
           compiles) -> tuple:
     """(correct, each compared number beside its limit): a sample of the
-    window's answered requests against the reference."""
-    picked = check.sample(
+    window's answered requests against the reference. The compared numbers
+    are the keys of the cell's limits file; the family's ``compare`` gives
+    each, with ``compared`` (how many requests) and ``unanswered``."""
+    picked = family.sample(
         [r for r in up["sched"]["requests"] if r["i"] in window_ids],
         {i: s for i, s in stamps.items() if "recv" in s},
         args.seed, limits["requests"])
     t = time.monotonic()
-    outputs = check.run_reference(config, up["params"], picked,
-                                  up["feature_root"], VOCAB)
-    compared = check.compare(picked, stamps, outputs)
+    outputs = family.run_reference(config, up["params"], picked,
+                                   up["assets"])
+    compared = family.compare(picked, stamps, outputs)
     say(f"reference: {len(picked)} requests in "
-        f"{time.monotonic() - t:.1f}s; per head "
-        f"{json.dumps(compared['per_head_rms'])}; the reference's spread "
-        f"of logits per head {json.dumps(compared['per_head_scale'])}")
+        f"{time.monotonic() - t:.1f}s; " + json.dumps(
+            {k: v for k, v in compared.items()
+             if k not in limits["limits"]}))
     # A frame that never came, however long it was waited for, is a wrong
     # answer; a refused submit is only a failed request.
     compared["unanswered"] += sum(
@@ -418,15 +413,13 @@ def judge(args, config, limits, up, stamps, window_ids,
         if stamps[i].get("status") == 200 and "recv" not in stamps[i])
     if args.control:
         say("program: " + json.dumps(
-            {k: compared[k] for k in ("score_err_rms", "score_err_max",
-                                      "unanswered")}))
-        lower = check.run_reference(config, up["params"], picked,
-                                    up["feature_root"], VOCAB,
-                                    lower=args.control)
-        compared = check.compare(
-            picked, {r["i"]: {"result": check.frame_of(r, out)}
+            {k: compared[k] for k in limits["limits"]}))
+        lower = family.run_reference(config, up["params"], picked,
+                                     up["assets"], lower=args.control)
+        compared = family.compare(
+            picked, {r["i"]: {"result": family.frame_of(r, out)}
                      for r, out in zip(picked, lower)}, outputs)
-    correct, beside = check.verdict(compared, limits["limits"])
+    correct, beside = verdict(compared, limits["limits"])
     beside["compiles_in_window"] = {"value": compiles, "limit": 0}
     return correct and not compiles, beside
 
@@ -454,7 +447,8 @@ def device_trace(args, trace_dir, seen) -> tuple:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    spec = Spec(args.workload)
+    spec = Spec(args.workload, args.manifest)
+    family = spec.family
     config, traffic_file, limits = cell_files(args, spec)
     traced = args.trace == 1
 
@@ -472,14 +466,15 @@ def main(argv=None) -> int:
         dev, device = find_device(jax, args, spec)
         tick("device_found")
         peaks = None if args.rehearsal else peaks_for(dev.device_kind)
-        up = set_up(jax, args, config, traffic_file, generator, state_dir)
+        up = set_up(jax, args, family, config, traffic_file, generator,
+                    state_dir)
         app = up["app"]
         tick("server_ready")
-        seen = drive_window(jax, args, traffic_file, generator, app,
+        seen = drive_window(jax, args, family, traffic_file, generator, app,
                             up["sched"], state_dir, trace_dir)
         compiles = (seen["after"].get("vmt_engine_compiles_total", 0.0)
                     - seen["before"].get("vmt_engine_compiles_total", 0.0))
-        device["memory_peak_bytes"] = read_memory(dev, app, config)
+        device["memory_peak_bytes"] = read_memory(dev, family, app, config)
 
         # The program is done: stop it and free its state.
         stamps = read_stamps(os.path.join(state_dir, "window.stamps"))
@@ -491,7 +486,7 @@ def main(argv=None) -> int:
 
         numbers = window_numbers(up["sched"], stamps, seen["t0"],
                                  args.seconds, seen["wait_end"])
-        correct, beside = judge(args, config, limits, up, stamps,
+        correct, beside = judge(args, family, config, limits, up, stamps,
                                 set(numbers["window_ids"]), compiles)
         tick("reference_done")
 
@@ -510,9 +505,9 @@ def main(argv=None) -> int:
                              "after": seen["after"]},
                 "stamps": [stamps[i] for i in numbers["window_ids"]],
                 "setup": dict(up["phases"], setup_s=seen["setup_s"]),
-                "flops_per_row": flops.forward_flops_per_row(
-                    config["model"], config["engine"]),
-                "peaks": peaks, "rows_in_trace": seen["rows_in_trace"],
+                "seconds": args.seconds,
+                "flops_per_unit": family.flops_per_unit(config),
+                "peaks": peaks, "units_in_trace": seen["units_in_trace"],
             }
             if not args.rehearsal:
                 ctx["trace"], breakdown = device_trace(args, trace_dir, seen)

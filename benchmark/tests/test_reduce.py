@@ -94,7 +94,7 @@ def test_readers_on_the_recorded_trace(recorded):
     ctx = {"trace": {"ops": trace.clip(ops, a, b),
                      "modules": trace.clip(modules, a, b),
                      "busy_s": busy, "window_s": b - a},
-           "rows_in_trace": 10.0, "flops_per_row": 1e9,
+           "units_in_trace": 10.0, "flops_per_unit": 1e9,
            "peaks": {"bf16_flops_per_s": 197e12}}
     idle = readers.read({"kind": "trace_idle"}, ctx)
     assert idle == pytest.approx(100 * (1 - busy / (b - a)))
@@ -125,8 +125,19 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
             {"kind": "setup_phase", "params": {"phase": "x"}},
             {"kind": "trace_idle"},
             {"kind": "trace_module_ms", "params": {"module_contains": "x"}},
-            {"kind": "trace_mfu", "params": {"module_contains": "x"}}):
+            {"kind": "trace_mfu", "params": {"module_contains": "x"}},
+            {"kind": "counter_rate", "params": {"counter": "x"}}):
         assert readers.read(reader, empty) is None
+
+
+def test_a_kind_is_a_function_here_or_a_file_brought_beside():
+    ctx = {"counters": {"before": {"n": 2.0}, "after": {"n": 12.0}},
+           "seconds": 4.0}
+    assert "counter_rate" not in readers.KINDS
+    assert readers.read({"kind": "counter_rate",
+                         "params": {"counter": "n"}}, ctx) == 2.5
+    with pytest.raises(SystemExit, match="reduce/kinds/no_such_kind.py"):
+        readers.read({"kind": "no_such_kind"}, ctx)
 
 
 def test_percentile_and_counters():

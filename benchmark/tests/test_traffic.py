@@ -1,9 +1,14 @@
+"""The ``vilbert`` family's schedules (``families/vilbert/traffic.py``) over
+the shared arrival disciplines (``harness/arrivals.py``)."""
+
+import hashlib
 import json
 import os
 
 import pytest
 
-from benchmark.harness import traffic
+from benchmark.families.vilbert import traffic
+from benchmark.harness import arrivals
 from benchmark.harness.spec import BENCH_DIR
 
 WORDS = traffic.load_words(os.path.join(BENCH_DIR, "assets", "vocab.txt"))
@@ -19,7 +24,7 @@ def test_every_seed_sends_the_same_multiset_in_another_order(seconds):
     mix = load("sessions")
     a = traffic.schedule(mix, 7, seconds, WORDS)["requests"]
     b = traffic.schedule(mix, 2**31 + 12345, seconds, WORDS)["requests"]
-    assert traffic.composition(a) == traffic.composition(b)
+    assert arrivals.composition(a) == arrivals.composition(b)
     order = lambda rs: [(r["task_id"], len(r["images"]), r["source"])
                         for r in rs if r["due"] >= 0]
     assert order(a) != order(b)
@@ -47,7 +52,7 @@ def test_an_upload_is_asked_about_by_one_session_only():
 
 def test_the_deck_is_dealt_in_its_shares():
     mix = load("sessions")
-    kinds = traffic.deal(mix["deck"], 700)
+    kinds = arrivals.deal(mix["deck"], 700)
     share = lambda pred: sum(1 for k in kinds if pred(k)) / len(kinds)
     assert share(lambda k: k["task_id"] == 7) == pytest.approx(0.2)
     assert share(lambda k: k["task_id"] == 12) == pytest.approx(0.2)
@@ -75,3 +80,34 @@ def test_the_gallery_set_up_holds_is_the_one_requests_draw_from(mix):
     drawn = {n for r in sched["requests"] if r["source"] == "gallery"
              for n in r["images"]}
     assert drawn and drawn <= set(held)
+
+
+with open(os.path.join(BENCH_DIR, "tests", "data",
+                       "schedule_digests.json")) as f:
+    DIGESTS = json.load(f)
+
+
+@pytest.mark.parametrize("name", sorted(DIGESTS["digests"]))
+def test_a_seeds_schedule_is_the_parents_field_for_field(name):
+    """``data/schedule_digests.json`` was written by ``harness/traffic.py``
+    on the tree before the family seam (PR 27's parent): the same seed
+    still sends the same requests at the same times."""
+    mix, seed = name.split(":")
+    sched = traffic.schedule(load(mix), int(seed), DIGESTS["seconds"], WORDS)
+    rows = [[r.get(k) for k in DIGESTS["fields"]] for r in sched["requests"]]
+    want = DIGESTS["digests"][name]
+    assert len(rows) == want["requests"]
+    assert hashlib.sha256(json.dumps(rows, sort_keys=True).encode()
+                          ).hexdigest() == want["sha256"]
+
+
+def test_a_request_says_what_the_generator_and_the_window_need():
+    sched = traffic.schedule(load("sessions"), 9, 10.0, WORDS)
+    for r in sched["requests"]:
+        assert r["body"] == {"task_id": r["task_id"],
+                             "question": r["question"],
+                             "image_list": r["images"]}
+        assert (r["key_field"], r["key"]) == ("question",
+                                              r["question"].lower())
+        assert r["rows"] == len(r["images"])
+        assert r["kind"] == (r["task_id"], len(r["images"]), r["source"])

@@ -161,7 +161,8 @@ TASK_QUESTIONS = {
 }
 
 
-@pytest.mark.parametrize("task_id", sorted(TASK_REGISTRY))
+@pytest.mark.parametrize("task_id", sorted(
+    t for t, spec in TASK_REGISTRY.items() if spec.decode != "generate"))
 def test_all_tasks_end_to_end(engine, task_id):
     spec = TASK_REGISTRY[task_id]
     n = spec.min_images

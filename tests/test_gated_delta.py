@@ -1,0 +1,112 @@
+"""The gated delta rule's three forms agree (ISSUE 28, test c): the chunked
+scan in ``jax.numpy`` and the Pallas kernel in interpret mode against the
+recurrence one token at a time, with beta in (1, 2) present (negative
+eigenvalues) and alpha near 0 and near 1.
+
+Tolerance: all three are float32 at ``highest`` precision; they differ by
+summation order and by the triangular solve, read at 3e-6 on outputs of
+size 2: 5e-5 allowed. The TPU kernel's real-size compile is the one thing
+here that is not CPU arithmetic (section 2 of the on-chip-measurement guide:
+the chip's compiler runs without the chip)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vilbert_multitask_tpu.ops import gated_delta as gd
+
+ATOL = 5e-5
+
+
+def inputs(alpha, T=192, H=3, dk=8, dv=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (T, H, dk))
+    k = jax.random.normal(ks[1], (T, H, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (T, H, dv))
+    beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(ks[3], (T, H)))
+    state = jax.random.normal(ks[5], (H, dk, dv))
+    return q, k, v, jnp.log(alpha(ks[4], (T, H))), beta, state
+
+
+ALPHAS = {
+    "near_0": lambda key, shape: jnp.full(shape, 1e-4),
+    "near_1": lambda key, shape: jnp.full(shape, 0.9999),
+    "between": lambda key, shape: jax.random.uniform(
+        key, shape, minval=0.5, maxval=1.0),
+    "mixed": lambda key, shape: jnp.where(
+        jax.random.uniform(key, shape) < 0.3, 1e-3, 0.999),
+}
+
+
+@pytest.mark.parametrize("form", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("alpha", sorted(ALPHAS))
+def test_chunked_scan_equals_the_recurrence(alpha, form):
+    q, k, v, g, beta, state = inputs(ALPHAS[alpha])
+    assert float(beta.max()) > 1.5 and float(beta.min()) < 0.5
+    want_o, want_s = gd.gated_delta_recurrent(q, k, v, g, beta, state)
+    got_o, got_s = gd.gated_delta_chunked(
+        q, k, v, g, beta, state, use_pallas=form != "jnp",
+        interpret=form != "jnp")
+    assert float(jnp.abs(want_o - got_o).max()) < ATOL
+    assert float(jnp.abs(want_s - got_s).max()) < ATOL
+
+
+def test_padding_tokens_leave_the_state_alone():
+    """g = 0, beta = 0 (what the model gives a padded token): nothing is
+    written, nothing decays."""
+    q, k, v, g, beta, state = inputs(ALPHAS["between"], T=128)
+    real = (jnp.arange(128) < 70)[:, None]
+    g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    _, padded = gd.gated_delta_chunked(q, k, v, g, beta, state)
+    _, short = gd.gated_delta_recurrent(q[:70], k[:70], v[:70], g[:70],
+                                        beta[:70], state)
+    assert float(jnp.abs(padded - short).max()) < ATOL
+
+
+def test_one_step_is_the_equations():
+    """S_t = a S + b (v - a S k) k^T, o = S_t q, on the transposed state."""
+    rng = np.random.default_rng(0)
+    S = rng.standard_normal((16, 8))            # [dv, dk]
+    q, k, v = (rng.standard_normal(n) for n in (8, 8, 16))
+    a, b = 0.9, 1.7
+    want_S = a * S + b * np.outer(v - a * S @ k, k)
+    o, new = gd.recurrent_step(jnp.asarray(S.T, jnp.float32), q, k, v,
+                               jnp.log(a), jnp.asarray(b))
+    assert np.abs(np.asarray(new).T - want_S).max() < 1e-5
+    assert np.abs(np.asarray(o) - want_S @ q).max() < 1e-5
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_kernel_compiles_for_the_chip_at_the_served_size(one_chip):
+    """30 heads, a 2048-token chunk, d_k 96, d_v 192: what the interpreter
+    cannot show (tiling, VMEM) the chip's compiler refuses here."""
+    H, N, C, dk, dv = 30, 32, gd.CHUNK, 96, 192
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(gd.gated_delta_scan).lower(
+        sd(H, N, C, dv), sd(H, N, C, dk), sd(H, N, C, dk), sd(H, N, C, C),
+        sd(H, N, dk, C), sd(H, N, 1, 1), sd(H, dk, dv)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The profile's event carries the kernel's name and both results'
+    # shapes: what benchmark/reduce/kinds/trace_gated_delta_roofline.py
+    # reads.
+    assert "%gated_delta_scan" in text
+    assert "(f32[30,32,64,192]" in text and "f32[30,96,192]" in text

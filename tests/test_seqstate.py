@@ -1,0 +1,117 @@
+"""The sequence-state manager's accounting (ISSUE 28, test e): admit,
+release, refuse by slots, pages and bytes; pages never shared; bytes in use
+equal to the sum over live sequences; nothing leaked."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+from vilbert_multitask_tpu import obs
+from vilbert_multitask_tpu.config import GenerateConfig, OlmoHybridConfig
+from vilbert_multitask_tpu.engine.seqstate import SequenceState
+
+GEN = GenerateConfig(model=OlmoHybridConfig().tiny(), param_dtype="float32",
+                     slots=4, kv_pages=32, page_size=16,
+                     decode_attention_pages=4)
+
+
+def counter(name, **labels):
+    inst = obs.REGISTRY.counter(name, labelnames=tuple(labels))
+    return inst.collect().get(tuple(labels.values()), 0.0)
+
+
+def test_sizes_follow_the_model():
+    st = SequenceState(GEN)
+    m = GEN.model
+    # 6 linear layers: [4, 8, 16] float32 + 3 rows of 4 * (8 + 8 + 16)
+    assert st.slot_bytes == 6 * (4 * 8 * 16 * 4 + 3 * 128 * 4)
+    # 2 full layers, K and V, 16 tokens of 4 heads of 16
+    assert st.page_bytes == 2 * 2 * 16 * 4 * 16 * 4
+    assert st.capacity_bytes == 4 * st.slot_bytes + 32 * st.page_bytes
+    assert st.rec_shape == (2, 3, 4, 4, 8, 16)
+    assert st.pool_shape == (2, 33, 16, 4, 16)   # one page is nobody's
+    assert st.max_pages_per_seq == m.max_position_embeddings // 16
+    full = SequenceState(GenerateConfig(model=dataclasses.replace(
+        OlmoHybridConfig(), num_hidden_layers=16,
+        layer_types=OlmoHybridConfig().layer_types[:16])))
+    assert full.slot_bytes == 12 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)
+    assert full.page_bytes == 4 * 2 * 256 * 30 * 128 * 2
+    assert full.pages * full.page_size == 65536
+
+
+def test_admit_reserves_slot_and_pages_and_release_frees_them():
+    st = SequenceState(GEN)
+    seq = st.admit(prompt_len=40, max_new_tokens=8)   # 48 tokens: 3 pages
+    assert (seq.slot, seq.pages) == (0, [0, 1, 2])
+    assert st.bytes_in_use == st.slot_bytes + 3 * st.page_bytes
+    assert list(st.page_slot[:4]) == [0, 0, 0, -1]
+    assert list(st.page_pos[:3]) == [0, 1, 2]
+    row = st.page_row(seq)
+    assert list(row[:4]) == [0, 1, 2, 32]            # padding: nobody's page
+    assert st.page_of(seq, 33) == 2 and st.pool_blocks(4) == 1
+    st.release(seq)
+    assert st.bytes_in_use == 0 and not st.live()
+    assert (st.page_slot == -1).all() and st.pool_blocks(4) == 0
+    with pytest.raises(ValueError):
+        st.release(seq)
+
+
+@pytest.mark.parametrize("reason,gen,first,second", [
+    ("no_slot", dataclasses.replace(GEN, slots=1), (8, 8), (8, 8)),
+    ("no_pages", GEN, (400, 8), (200, 8)),
+    ("no_bytes", dataclasses.replace(GEN, state_bytes_budget=200000),
+     (100, 8), (100, 8)),
+])
+def test_refuses_by_what_runs_out(reason, gen, first, second):
+    st = SequenceState(gen)
+    before = counter("vmt_seq_admit_refused_total", reason=reason)
+    held = st.admit(*first)
+    assert held is not None
+    assert st.admit(*second) is None
+    assert counter("vmt_seq_admit_refused_total",
+                   reason=reason) == before + 1
+    st.release(held)
+    assert st.admit(*second) is not None
+
+
+def test_200_random_rounds_leak_nothing_and_share_no_page():
+    st = SequenceState(GEN)
+    rng = np.random.default_rng(7)
+    live = []
+    for _ in range(200):
+        if live and rng.random() < 0.45:
+            st.release(live.pop(int(rng.integers(len(live)))))
+        else:
+            seq = st.admit(int(rng.integers(1, 200)), int(rng.integers(1, 9)))
+            if seq is not None:
+                live.append(seq)
+        pages = [p for s in live for p in s.pages]
+        assert len(pages) == len(set(pages))
+        assert len({s.slot for s in live}) == len(live)
+        assert st.bytes_in_use == sum(
+            st.slot_bytes + len(s.pages) * st.page_bytes for s in live)
+        assert st.bytes_in_use <= st.budget_bytes
+        for s in live:
+            assert (st.page_slot[s.pages] == s.slot).all()
+            assert list(st.page_pos[s.pages]) == list(range(len(s.pages)))
+        assert (st.page_slot >= 0).sum() == len(pages)
+        assert st.stats()["kv_pages_in_use"] == len(pages)
+    for seq in live:
+        st.release(seq)
+    assert st.bytes_in_use == 0 and not st.live()
+    assert st.stats() == {"seq_slots_in_use": 0.0, "kv_pages_in_use": 0.0,
+                          "seqstate_bytes_in_use": 0.0}
+    assert (st.page_slot == -1).all()
+
+
+def test_unwritten_bytes_count_what_nothing_was_written_to():
+    st = SequenceState(GEN)
+    assert st.unwritten_bytes() == st.capacity_bytes
+    seq = st.admit(40, 8)
+    st.note_written(seq, 20)                        # 2 of its 3 pages
+    assert st.unwritten_bytes() == (st.capacity_bytes - st.slot_bytes
+                                    - 2 * st.page_bytes)
+    st.release(seq)                                  # written stays written
+    assert st.unwritten_bytes() == (st.capacity_bytes - st.slot_bytes
+                                    - 2 * st.page_bytes)

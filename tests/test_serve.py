@@ -85,7 +85,9 @@ def test_queue_visibility_timeout(tmp_path):
 def test_result_store_catalog_and_qa(tmp_path):
     store = ResultStore(str(tmp_path / "r.sqlite3"))
     tasks = store.list_tasks()
-    assert {t["unique_id"] for t in tasks} == {1, 2, 4, 7, 11, 12, 13, 15, 16}
+    # The nine ViLBERT tasks and the decoder's generate task (20).
+    assert {t["unique_id"] for t in tasks} == {1, 2, 4, 7, 11, 12, 13, 15, 16,
+                                               20}
     qa_id = store.create_question(1, "what is this", ["img_a.jpg"], "sock1")
     store.save_answer(qa_id, {"answers": [{"answer": "cat"}]})
     row = store.get_question(qa_id)
@@ -203,7 +205,7 @@ def test_http_api_roundtrip(stack):
         conn = http.client.HTTPConnection("127.0.0.1", port, timeout=5)
         conn.request("GET", "/")
         root = json.loads(conn.getresponse().read())
-        assert len(root["tasks"]) == 9 and root["socket_id"]
+        assert len(root["tasks"]) == 10 and root["socket_id"]
 
         conn.request("GET", "/get_task_details/1/")
         task = json.loads(conn.getresponse().read())

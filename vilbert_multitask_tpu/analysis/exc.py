@@ -169,6 +169,10 @@ class ExcFlow:
         self.cg = project.callgraph
         self._mro_cache: Dict[str, Tuple[str, ...]] = {}
         self.classes: Dict[str, dict] = {}
+        # Every node of a module's tree in ``ast.walk`` order, listed once:
+        # six passes below look at each whole tree, and the walk itself
+        # was most of this tier's wall time.
+        self._module_nodes: Dict[int, List[ast.AST]] = {}
         self._build_class_index()
         # Leaf method name -> qualname iff unique among library
         # functions, plus the full leaf -> candidates map for the
@@ -212,6 +216,12 @@ class ExcFlow:
         self._check_frame_drift()
 
     # ------------------------------------------------------------ taxonomy
+    def _walk(self, tree: ast.AST) -> List[ast.AST]:
+        nodes = self._module_nodes.get(id(tree))
+        if nodes is None:
+            nodes = self._module_nodes[id(tree)] = list(ast.walk(tree))
+        return nodes
+
     def _build_class_index(self) -> None:
         """Project exception classes: every library ``ClassDef`` whose
         base chain roots in a known exception, to a fixed point (so
@@ -222,7 +232,7 @@ class ExcFlow:
             ctx = mod.ctx
             if not _is_library(ctx.rel_path):
                 continue
-            for node in ast.walk(ctx.tree):
+            for node in self._walk(ctx.tree):
                 if not isinstance(node, ast.ClassDef) or not node.bases:
                     continue
                 leaves = []
@@ -698,7 +708,7 @@ class ExcFlow:
             ctx = mod.ctx
             if not _is_library(ctx.rel_path):
                 continue
-            for node in ast.walk(ctx.tree):
+            for node in self._walk(ctx.tree):
                 if isinstance(node, ast.Call):
                     self._thread_boundary(mod, node)
                     self._tick_boundary(mod, node)
@@ -839,7 +849,7 @@ class ExcFlow:
                           key=lambda m: m.name):
             if not _is_library(mod.ctx.rel_path):
                 continue
-            for node in ast.walk(mod.ctx.tree):
+            for node in self._walk(mod.ctx.tree):
                 if isinstance(node, ast.Call) \
                         and isinstance(node.func, ast.Attribute) \
                         and node.func.attr == "call" \
@@ -1097,7 +1107,7 @@ class ExcFlow:
             spans = [
                 (h.lineno, getattr(h, "end_lineno", h.lineno) or
                  h.lineno)
-                for n in ast.walk(ctx.tree) if isinstance(n, ast.Try)
+                for n in self._walk(ctx.tree) if isinstance(n, ast.Try)
                 for h in n.handlers]
 
             def in_handler(node: ast.AST) -> bool:
@@ -1126,8 +1136,7 @@ class ExcFlow:
                     f"failure class on the floor"),
             })
 
-    @staticmethod
-    def _verdict_literals(ctx) -> Iterator[Tuple[str, ast.AST]]:
+    def _verdict_literals(self, ctx) -> Iterator[Tuple[str, ast.AST]]:
         """String literals used as an outbound error *verdict*: the 2nd
         positional of ``job_finish``, a ``verdict=`` kwarg, a
         ``"verdict"`` dict value, or a ``verdict`` assignment."""
@@ -1149,7 +1158,7 @@ class ExcFlow:
                 or (isinstance(expr, ast.Attribute)
                     and expr.attr == "verdict")
 
-        for node in ast.walk(ctx.tree):
+        for node in self._walk(ctx.tree):
             if isinstance(node, ast.Call):
                 func = node.func
                 leaf = func.attr if isinstance(func, ast.Attribute) \

@@ -182,7 +182,9 @@ class TaskSpec:
             )
 
 
-# The 8 served task types. task_id values are the reference's wire protocol —
+GENERATE_TASK_ID = 20
+
+# The 8 served task types (and the text-generation task of the decoder). task_id values are the reference's wire protocol —
 # they appear in queue messages (demo/sender.py:26-31) and the UI (result.html:318-336).
 TASK_REGISTRY: Mapping[int, TaskSpec] = {
     t.task_id: t
@@ -223,6 +225,15 @@ TASK_REGISTRY: Mapping[int, TaskSpec] = {
                  max_images=10, top_k=0,  # top_k=#images, resolved at decode time
                  description="Caption-based image retrieval over the uploaded set",
                  placeholder="e.g. A man riding a horse on the beach."),
+        # Text generation (serve/http_api.py: ``prompt_ids``,
+        # ``max_new_tokens``, ``logit_ids``). Served only by an app built
+        # around a GenerateEngine (GenerateConfig.model set): that app
+        # serves no ViLBERT task, and a ViLBERT app refuses this one.
+        TaskSpec(GENERATE_TASK_ID, "Generate", head="lm_head",
+                 decode="generate", min_images=0, max_images=0, top_k=0,
+                 description="Greedy text generation from a prompt of token "
+                             "ids; one terminal frame with the tokens and "
+                             "the logits asked for"),
     ]
 }
 
@@ -606,12 +617,168 @@ class ServingConfig:
     autoscale_decision_history: int = 128
 
 
+
+LINEAR_ATTENTION, FULL_ATTENTION = "linear_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoHybridConfig:
+    """A causal decoder of ``model_type: olmo_hybrid`` (gated linear
+    attention in ``k`` layers of ``k + 1``): the source ``config.json``'s
+    keys under their own names. The layers are ``models/olmo_hybrid.py``."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 3840
+    intermediate_size: int = 11008
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 30
+    num_key_value_heads: int = 30
+    hidden_act: str = "silu"
+    max_position_embeddings: int = 65536
+    attention_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    layer_types: Sequence[str] = (
+        (LINEAR_ATTENTION,) * 3 + (FULL_ATTENTION,)) * 8
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    # Kernel choice (not the source's): the chunked scan as the Pallas
+    # kernel of ops/gated_delta.py; ``pallas_interpret`` is the CPU tests'
+    # explicit choice, never inferred from the backend.
+    use_pallas_scan: bool = True
+    pallas_interpret: bool = False
+
+    def __post_init__(self):
+        types = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", types)
+        if len(types) != self.num_hidden_layers:
+            raise ValueError("layer_types names another depth than "
+                             "num_hidden_layers")
+        if FULL_ATTENTION not in types:
+            raise ValueError("a pattern without full_attention has no "
+                             "period")
+        period = types.index(FULL_ATTENTION) + 1
+        one = (LINEAR_ATTENTION,) * (period - 1) + (FULL_ATTENTION,)
+        if len(types) % period or types != one * (len(types) // period):
+            raise ValueError("layer_types must repeat one period of linear "
+                             "layers closed by a full one")
+        if (self.hidden_act != "silu" or self.attention_bias
+                or self.tie_word_embeddings):
+            raise ValueError("only silu, no attention bias, untied head")
+        if self.num_attention_heads != self.num_key_value_heads:
+            raise ValueError("grouped key/value heads are not implemented")
+        if self.linear_num_key_heads != self.linear_num_value_heads:
+            raise ValueError("linear key and value head counts must agree")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size must divide into the heads")
+
+    @property
+    def period(self) -> int:
+        """Layers a period: the linear ones and the full one closing it."""
+        return self.layer_types.index(FULL_ATTENTION) + 1
+
+    @property
+    def periods(self) -> int:
+        return self.num_hidden_layers // self.period
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the convolution runs over: q~ | k~ | v~."""
+        return self.linear_num_key_heads * (2 * self.linear_key_head_dim
+                                            + self.linear_value_head_dim)
+
+    def tiny(self) -> "OlmoHybridConfig":
+        """The size of the CPU tests: 2 periods of 64-wide layers."""
+        return dataclasses.replace(
+            self, vocab_size=512, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=8, num_attention_heads=4,
+            num_key_value_heads=4, max_position_embeddings=512,
+            layer_types=((LINEAR_ATTENTION,) * 3 + (FULL_ATTENTION,)) * 2,
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=8, linear_value_head_dim=16,
+            use_pallas_scan=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class GenerateConfig:
+    """The text-generation engine (engine/generate.py). ``model`` None: the
+    app serves ViLBERT and refuses the ``generate`` task. One ``ServeApp``
+    holds one model: a generate app serves no ViLBERT task."""
+
+    model: OlmoHybridConfig | None = None
+    param_dtype: str = "bfloat16"
+    # Compiled shapes: tokens a prefill chunk (multiples of the page size
+    # and of the scan's 64), sequences a decode step.
+    prefill_buckets: Sequence[int] = (256, 1024, 2048)
+    decode_buckets: Sequence[int] = (8, 16, 24, 32)
+    # The sequence-state manager (engine/seqstate.py): sequences resident
+    # at once, and the key/value pool in pages of ``page_size`` tokens.
+    slots: int = 32
+    kv_pages: int = 256
+    page_size: int = 256
+    # None: what the slots and pages hold when all are in use.
+    state_bytes_budget: int | None = None
+    max_logit_ids: int = 16
+    # Pages of the pool a decode attention step reads at once (``kv_pages``
+    # must be a multiple).
+    decode_attention_pages: int = 32
+
+    def prefill_bucket_for(self, tokens: int) -> int:
+        for b in sorted(self.prefill_buckets):
+            if tokens <= b:
+                return b
+        return max(self.prefill_buckets)
+
+    def decode_bucket_for(self, sequences: int) -> int:
+        for b in sorted(self.decode_buckets):
+            if sequences <= b:
+                return b
+        raise ValueError(f"no decode bucket holds {sequences} sequences")
+
+    def problem_with(self, prompt_ids, max_new_tokens, logit_ids) -> str:
+        """Why a generate request cannot be served ('' if it can): the one
+        rule the HTTP door and the engine both apply."""
+        model = self.model
+        for name, ids in (("prompt_ids", prompt_ids),
+                          ("logit_ids", logit_ids)):
+            if not isinstance(ids, (list, tuple)) or not all(
+                    type(i) is int for i in ids):
+                return f"{name} must be a list of whole numbers"
+            if ids and not (0 <= min(ids) and max(ids) < model.vocab_size):
+                return f"{name} must lie in [0, {model.vocab_size})"
+        if type(max_new_tokens) is not int or max_new_tokens < 1:
+            return "max_new_tokens must be a whole number of at least 1"
+        if not prompt_ids:
+            return "prompt_ids must hold at least one token"
+        total = len(prompt_ids) + max_new_tokens
+        if total > model.max_position_embeddings:
+            return (f"prompt ({len(prompt_ids)}) + max_new_tokens "
+                    f"({max_new_tokens}) exceed the context of "
+                    f"{model.max_position_embeddings}")
+        if -(-total // self.page_size) > self.kv_pages:
+            return (f"the request needs {-(-total // self.page_size)} "
+                    f"key/value pages; the pool has {self.kv_pages}")
+        if len(logit_ids) > self.max_logit_ids:
+            return f"at most {self.max_logit_ids} logit_ids"
+        return ""
+
+
 @dataclasses.dataclass(frozen=True)
 class FrameworkConfig:
     model: ViLBertConfig = dataclasses.field(default_factory=ViLBertConfig)
     engine: EngineConfig = dataclasses.field(default_factory=EngineConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     serving: ServingConfig = dataclasses.field(default_factory=ServingConfig)
+    generate: GenerateConfig = dataclasses.field(
+        default_factory=GenerateConfig)
 
 
 def config_fingerprint(cfg: FrameworkConfig) -> str:

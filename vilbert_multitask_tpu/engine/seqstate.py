@@ -1,0 +1,246 @@
+"""The sequence-state manager of the generate engine: one object, one byte
+budget, two kinds of state side by side.
+
+*Slots*: what a running sequence holds at a fixed size whatever its length:
+for every linear-attention layer a recurrent state ``[heads, d_k, d_v]``
+float32 (the transpose of the equations' ``S``) and the last ``taps - 1``
+rows that entered the convolution. *Pages*: what grows with the sequence:
+for every full-attention layer keys and values in pages of ``page_size``
+tokens, ``[layer, page, token, head, d]``, and per sequence the list of its
+pages in order.
+
+The device side is a dict of arrays (:meth:`allocate`), handed to the
+compiled programs *donated* and taken back updated: a prefill chunk writes
+one slot's slice and the pages of its tokens, a decode step scatters the
+rows of its sequences; nothing is ever copied whole. The host side, here,
+is the accounting: :meth:`admit` reserves a slot and ``ceil((prompt + new)
+/ page_size)`` pages, or says no (no slot, no pages, or the byte budget);
+:meth:`release` frees both. Pages are handed out lowest number first, so
+the part of the pool decode has to read (:meth:`pool_blocks`) stays as
+short as the load allows.
+
+Invariants (``tests/test_seqstate.py`` holds them): a page belongs to at
+most one live sequence; ``bytes_in_use`` is the sum over live sequences of
+their slot and page bytes; after every sequence is released nothing is in
+use.
+
+One thread (the scheduler's dispatch loop) admits and releases; the
+sampler reads the gauges. A lock makes that safe, and nothing blocks under
+it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import heapq
+import threading
+from typing import Dict, List, Optional
+
+import jax.numpy as jnp
+import numpy as np
+
+from vilbert_multitask_tpu import obs
+from vilbert_multitask_tpu.config import GenerateConfig
+
+_ADMITTED = obs.REGISTRY.counter(
+    "vmt_seq_admitted_total", "Sequences the state manager admitted.")
+_REFUSED = obs.REGISTRY.counter(
+    "vmt_seq_admit_refused_total",
+    "Admissions the state manager refused, by what ran out.",
+    labelnames=("reason",))
+_RELEASED = obs.REGISTRY.counter(
+    "vmt_seq_released_total", "Sequences released (slot and pages freed).")
+_SLOTS_IN_USE = obs.REGISTRY.gauge(
+    "vmt_seq_slots_in_use", "Sequence slots held by live sequences.")
+_PAGES_IN_USE = obs.REGISTRY.gauge(
+    "vmt_kv_pages_in_use", "Key/value pages held by live sequences.")
+_BYTES_IN_USE = obs.REGISTRY.gauge(
+    "vmt_seqstate_bytes_in_use",
+    "Device bytes of sequence state held by live sequences.")
+_PAGES_TOTAL = obs.REGISTRY.gauge(
+    "vmt_kv_pages_total", "Key/value pages the pool has.")
+
+
+@dataclasses.dataclass
+class Sequence:
+    """One admitted sequence: where its state lives, and how far it is."""
+
+    slot: int
+    pages: List[int]
+    prompt_len: int
+    max_new_tokens: int
+    prefilled: int = 0      # prompt tokens dispatched
+    generated: int = 0      # tokens dispatched (the first by the prefill)
+
+    @property
+    def prefilling(self) -> bool:
+        return self.prefilled < self.prompt_len
+
+    @property
+    def done(self) -> bool:
+        return self.generated >= self.max_new_tokens
+
+
+class SequenceState:
+    def __init__(self, gen: GenerateConfig):
+        model = gen.model
+        self.gen = gen
+        self.slots = int(gen.slots)
+        self.pages = int(gen.kv_pages)
+        self.page_size = int(gen.page_size)
+        if self.pages % min(gen.decode_attention_pages, self.pages):
+            raise ValueError("kv_pages must be a multiple of "
+                             "decode_attention_pages")
+        item = jnp.dtype(gen.param_dtype).itemsize
+        linear_layers = model.periods * (model.period - 1)
+        self.rec_shape = (model.periods, model.period - 1, self.slots,
+                          model.linear_num_value_heads,
+                          model.linear_key_head_dim,
+                          model.linear_value_head_dim)
+        self.conv_shape = (model.periods, model.period - 1, self.slots,
+                           model.linear_conv_kernel_dim - 1,
+                           model.conv_width)
+        # One page more than the pool counts: the last belongs to nobody,
+        # and is where a padding row's keys and values are written.
+        self.pool_shape = (model.periods, self.pages + 1, self.page_size,
+                           model.num_key_value_heads, model.head_dim)
+        self.slot_bytes = linear_layers * (
+            int(np.prod(self.rec_shape[3:])) * 4
+            + int(np.prod(self.conv_shape[3:])) * item)
+        self.page_bytes = 2 * int(np.prod(
+            (model.periods, model.num_key_value_heads, self.page_size,
+             model.head_dim))) * item
+        self.capacity_bytes = (self.slots * self.slot_bytes
+                               + self.pages * self.page_bytes)
+        self.budget_bytes = (self.capacity_bytes
+                             if gen.state_bytes_budget is None
+                             else int(gen.state_bytes_budget))
+        self.max_pages_per_seq = min(
+            self.pages, -(-model.max_position_embeddings // self.page_size))
+        self.arrays: Optional[dict] = None
+        self._lock = threading.Lock()
+        self._free_slots = list(range(self.slots))
+        heapq.heapify(self._free_slots)
+        self._free_pages = list(range(self.pages))
+        heapq.heapify(self._free_pages)
+        self._live: Dict[int, Sequence] = {}
+        # Whose each page is (-1: nobody's) and which of its sequence's
+        # pages: what decode's ownership mask is made of.
+        self.page_slot = np.full((self.pages,), -1, np.int32)
+        self.page_pos = np.zeros((self.pages,), np.int32)
+        # What was ever written to: the rest is reserved, not held
+        # (the benchmark's ``written_share``).
+        self._slots_written = set()
+        self._pages_written = set()
+        self.bytes_in_use = 0
+        _PAGES_TOTAL.set(self.pages)
+        self._publish()
+
+    # ---------------------------------------------------------- device side
+    def allocate(self) -> dict:
+        """The device arrays, zeroed; kept as ``self.arrays``, which the
+        engine replaces with what each donated call returns."""
+        dtype = jnp.dtype(self.gen.param_dtype)
+        self.arrays = {
+            "rec": jnp.zeros(self.rec_shape, jnp.float32),
+            "conv": jnp.zeros(self.conv_shape, dtype),
+            "k": jnp.zeros(self.pool_shape, dtype),
+            "v": jnp.zeros(self.pool_shape, dtype),
+            "token": jnp.zeros((self.slots,), jnp.int32),
+        }
+        return self.arrays
+
+    def drop_arrays(self) -> None:
+        """Free the device side (the app is stopping)."""
+        arrays, self.arrays = self.arrays, None
+        for a in (arrays or {}).values():
+            a.delete()
+
+    # ------------------------------------------------------------ host side
+    def pages_for(self, prompt_len: int, max_new_tokens: int) -> int:
+        return -(-(prompt_len + max_new_tokens) // self.page_size)
+
+    def admit(self, prompt_len: int, max_new_tokens: int
+              ) -> Optional[Sequence]:
+        """A slot and the pages of ``prompt_len + max_new_tokens`` tokens,
+        or None: the caller keeps the job and asks again later."""
+        with obs.span("seqstate.admit", prompt_len=prompt_len) as sp, \
+                self._lock:
+            need = self.pages_for(prompt_len, max_new_tokens)
+            reason = None
+            if not self._free_slots:
+                reason = "no_slot"
+            elif need > len(self._free_pages):
+                reason = "no_pages"
+            elif (self.bytes_in_use + self.slot_bytes
+                  + need * self.page_bytes > self.budget_bytes):
+                reason = "no_bytes"
+            if reason is not None:
+                _REFUSED.inc(reason=reason)
+                sp.set(refused=reason)
+                return None
+            slot = heapq.heappop(self._free_slots)
+            pages = [heapq.heappop(self._free_pages) for _ in range(need)]
+            for pos, page in enumerate(pages):
+                self.page_slot[page] = slot
+                self.page_pos[page] = pos
+            seq = Sequence(slot, pages, prompt_len, max_new_tokens)
+            self._live[slot] = seq
+            self.bytes_in_use += self.slot_bytes + need * self.page_bytes
+            _ADMITTED.inc()
+            self._publish()
+            return seq
+
+    def release(self, seq: Sequence) -> None:
+        with self._lock:
+            if self._live.pop(seq.slot, None) is not seq:
+                raise ValueError(f"slot {seq.slot} is not this sequence's")
+            heapq.heappush(self._free_slots, seq.slot)
+            for page in seq.pages:
+                self.page_slot[page] = -1
+                heapq.heappush(self._free_pages, page)
+            self.bytes_in_use -= (self.slot_bytes
+                                  + len(seq.pages) * self.page_bytes)
+            _RELEASED.inc()
+            self._publish()
+
+    def page_row(self, seq: Sequence) -> np.ndarray:
+        """The sequence's pages in order, padded with nobody's page (the
+        pool's last): what a padding entry writes lands there."""
+        row = np.full((self.max_pages_per_seq,), self.pages, np.int32)
+        row[:len(seq.pages)] = seq.pages
+        return row
+
+    def page_of(self, seq: Sequence, position: int) -> int:
+        return seq.pages[position // self.page_size]
+
+    def pool_blocks(self, block: int) -> int:
+        """Blocks of ``block`` pages that reach past the last page in use."""
+        used = np.nonzero(self.page_slot >= 0)[0]
+        return 0 if used.size == 0 else int(used[-1]) // block + 1
+
+    def note_written(self, seq: Sequence, tokens: int) -> None:
+        """The sequence's state now holds ``tokens`` tokens."""
+        self._slots_written.add(seq.slot)
+        self._pages_written.update(
+            seq.pages[:-(-tokens // self.page_size)])
+
+    def unwritten_bytes(self) -> int:
+        return ((self.slots - len(self._slots_written)) * self.slot_bytes
+                + (self.pages - len(self._pages_written)) * self.page_bytes)
+
+    def live(self) -> List[Sequence]:
+        with self._lock:
+            return list(self._live.values())
+
+    def stats(self) -> Dict[str, float]:
+        with self._lock:
+            return {"seq_slots_in_use": float(len(self._live)),
+                    "kv_pages_in_use":
+                        float(self.pages - len(self._free_pages)),
+                    "seqstate_bytes_in_use": float(self.bytes_in_use)}
+
+    def _publish(self) -> None:
+        _SLOTS_IN_USE.set(len(self._live))
+        _PAGES_IN_USE.set(self.pages - len(self._free_pages))
+        _BYTES_IN_USE.set(self.bytes_in_use)

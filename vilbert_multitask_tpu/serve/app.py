@@ -88,6 +88,20 @@ class ServeApp:
             max_delivery_attempts=s.max_delivery_attempts,
             max_deliveries=s.queue_max_deliveries)
         self.store = ResultStore(s.results_db_path)
+        if engine is None and self.cfg.generate.model is not None:
+            # One ServeApp, one model: with ``generate.model`` set the app
+            # is built around the generate engine (engine/generate.py: a
+            # decoder served step by step over the sequence-state manager)
+            # and serves the ``generate`` task alone; ViLBERT jobs and
+            # generate jobs are never mixed in one running process. One
+            # replica: the sequence state is not shared between engines.
+            from vilbert_multitask_tpu.engine.generate import GenerateEngine
+
+            t0 = time.perf_counter()
+            with obs.span("serve.boot"):
+                engine = [GenerateEngine(self.cfg, replica_id="r0")]
+            self.boot_info["engine_init_s"] = round(
+                time.perf_counter() - t0, 1)
         if engine is None:
             # Multi-device host → serve through the dp×tp mesh; a 1-chip box
             # gets plain single-device jit. Same binary either way (the
@@ -211,6 +225,7 @@ class ServeApp:
         # Which program family serves is decided above from the device
         # count alone (no option): say so where operators look.
         self.boot_info["program_family"] = (
+            "prefill+decode" if self.engine.generates else
             "batched" if getattr(self.engine, "mesh", None) is not None
             else "rows")
         self._refresh_boot_phases()
@@ -307,7 +322,8 @@ class ServeApp:
             slos=self.slos, timeseries=self.timeseries,
             pool=self.engine, swap_fn=self.rolling_swap, fleet=self.fleet,
             attrib=self.attrib, tracestore=self.tracestore,
-            cache=self.cache, autoscaler=self.autoscaler)
+            cache=self.cache, autoscaler=self.autoscaler,
+            generate=(self.cfg.generate if self.engine.generates else None))
         self.ws = WebSocketBridge(self.hub, s.http_host, s.ws_port)
         self.http_port: Optional[int] = None  # actual bound port after start
         self._stop = threading.Event()

@@ -59,7 +59,13 @@ class ApiServer:
         tracestore=None,
         cache: Optional[ResultCache] = None,
         autoscaler=None,
+        generate=None,
     ):
+        # GenerateConfig of an app built around the generate engine
+        # (``model`` set): POST / then takes the ``generate`` task and no
+        # other; None or model-less: the ViLBERT tasks and not that one.
+        self.generate = (generate if generate is not None
+                         and generate.model is not None else None)
         self.queue = queue
         self.store = store
         self.hub = hub
@@ -157,6 +163,14 @@ class ApiServer:
         spec = TASK_REGISTRY.get(task_id)
         if spec is None:
             return 400, {"error": f"unknown task_id {task_id}"}
+        if (spec.decode == "generate") != (self.generate is not None):
+            # One app holds one model: ViLBERT jobs and generate jobs are
+            # never mixed in one process.
+            return 400, {"error": f"task {task_id} ({spec.name}) is not "
+                                  "served by this server's model"}
+        if self.generate is not None:
+            return self._submit_generate(payload, spec, socket_id, question,
+                                         trace_id, budget, sp)
         try:
             spec.validate_num_images(len(images))
         except ValueError as e:
@@ -238,6 +252,36 @@ class ApiServer:
         if key:
             body["cache"] = "miss"
         return 200, body
+
+    def _submit_generate(self, payload: Dict[str, Any], spec, socket_id: str,
+                         question: str, trace_id: str, budget,
+                         sp) -> Tuple[int, Dict[str, Any]]:
+        """The ``generate`` task: ``prompt_ids``, ``max_new_tokens`` and up
+        to ``max_logit_ids`` ``logit_ids`` checked at the door (a request
+        past the context or the pool is a 400, never a queued job), then
+        published like any job. Every prompt is distinct, so the result
+        cache and coalescing are bypassed for this task."""
+        prompt = payload.get("prompt_ids")
+        new = payload.get("max_new_tokens")
+        logit_ids = payload.get("logit_ids") or []
+        problem = self.generate.problem_with(prompt, new, logit_ids)
+        if problem:
+            return 400, {"error": problem}
+        log_to_terminal(self.hub, socket_id,
+                        {"info": f"Starting {spec.name} job..."})
+        message = make_job_message(
+            [], question, spec.task_id, socket_id, trace_id=trace_id,
+            tenant=str(payload.get("tenant", "") or "") or None,
+            deadline=(Deadline(budget).to_wire()
+                      if budget and budget > 0 else None),
+            published_unix=time.time())
+        message.update(prompt_ids=prompt, max_new_tokens=new,
+                       logit_ids=logit_ids)
+        with obs.span("queue.publish") as psp:
+            job_id = self.queue.publish(message)
+            psp.set(job_id=job_id)
+        sp.set(task_id=spec.task_id, job_id=job_id, prompt_len=len(prompt))
+        return 200, {"job_id": job_id, "task": spec.name}
 
     def _serve_cache_hit(self, spec, socket_id: str, trace_id: str,
                          tenant: Optional[str], payload: Dict[str, Any],

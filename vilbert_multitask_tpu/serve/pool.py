@@ -190,8 +190,17 @@ class ReplicaPool:
     def stage_times(self):
         return self._host.stage_times
 
+    @property
+    def generates(self) -> bool:
+        """Whether the pool serves the generate task (one
+        engine/generate.py engine) instead of the ViLBERT tasks."""
+        return bool(getattr(self._host, "generates", False))
+
     def prepare(self, *args, **kwargs):
         return self._host.prepare(*args, **kwargs)
+
+    def prepare_generate(self, *args, **kwargs):
+        return self._host.prepare_generate(*args, **kwargs)
 
     def prepare_from_store(self, *args, **kwargs):
         return self._host.prepare_from_store(*args, **kwargs)

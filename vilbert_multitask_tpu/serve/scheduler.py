@@ -257,6 +257,9 @@ class ContinuousScheduler:
         # Guarded by _poll_lock, under which nothing blocks.
         self._poll_lock = threading.Lock()
         self._next_poll_at = 0.0
+        # Generate apps only: sequences whose last token is dispatched and
+        # whose tokens the host has not fetched yet, by id of the request.
+        self._awaiting_tokens: dict = {}
         # Replica-pool mode: when the worker's engine is a ReplicaPool
         # (duck-typed on the checkout seam), batches PIN to one replica —
         # checkout here, dispatch on an executor thread (one in-flight
@@ -613,6 +616,112 @@ class ContinuousScheduler:
             except Exception:
                 self.worker._fail_job(item.job)
 
+    # ------------------------------------------------------ generate stage
+    # How often a waiting job may be passed over by later ones that fit
+    # before nothing behind it is admitted any more (no starvation of a
+    # long prompt by a stream of short ones).
+    GENERATE_MAX_PASSED_OVER = 8
+
+    def _generate_loop(self) -> None:
+        """The dispatch stage of an app that serves the ``generate`` task
+        (engine/generate.py): continuous batching over a *running set* of
+        sequences. Each iteration admits parked jobs while the sequence-
+        state manager says yes (first fit in arrival order; a job that
+        does not fit waits, never fails), runs at most one prefill chunk,
+        then one decode step over every running sequence. A sequence
+        whose last token is dispatched gives its slot and pages back at
+        once; its job goes to the completion stage when the tokens have
+        been fetched, as one terminal frame like every task's."""
+        eng = self.pool.replicas[0].engine if self.pool is not None \
+            else self.worker.engine
+        running: List[ReadyItem] = []   # admitted, not yet done
+        passed_over: dict = {}
+        try:
+            while not self.stop.is_set():
+                with self._cond:
+                    parked = list(self._ready)
+                    if not parked and not running and eng.idle:
+                        self._cond.wait(self.poll_interval_s)
+                        continue
+                self._generate_iteration(eng, parked, running, passed_over)
+        finally:
+            # Whatever is admitted goes back to the ready set, which run()
+            # releases to the queue: a restart serves it from the start.
+            for item in running:
+                eng.release(item.prepared)
+            eng.collect(drain=True)
+            with self._cond:
+                self._ready[:0] = running
+
+    def _generate_iteration(self, eng, parked, running, passed_over) -> None:
+        now = self.clock()
+        with obs.span("sched.generate_iter", parked=len(parked),
+                      running=len(running)) as sp:
+            admitted, expired = [], []
+            for item in parked:
+                if item.deadline is not None and item.deadline.expired():
+                    expired.append(item)
+                    continue
+                if eng.admit(item.prepared):
+                    admitted.append(item)
+                    passed_over.pop(item.job.id, None)
+                    continue
+                n = passed_over[item.job.id] = passed_over.get(
+                    item.job.id, 0) + 1
+                if n > self.GENERATE_MAX_PASSED_OVER:
+                    break
+            if admitted or expired:
+                gone = {id(i) for i in admitted + expired}
+                with self._cond:
+                    self._ready[:] = [i for i in self._ready
+                                      if id(i) not in gone]
+                    self._stats["jobs"] += len(admitted)
+                    self._stats["shed"] += len(expired)
+            for item in expired:
+                passed_over.pop(item.job.id, None)
+                self.worker._expire_job(item.job)
+            for item in admitted:
+                obs.SCHED_WAIT.observe(max(now - item.enq_t, 0.0) * 1e3)
+                obs.job_charge(item.job.body.get("trace_id", ""),
+                               "ready_wait", max(now - item.enq_t, 0.0))
+            running.extend(admitted)
+            try:
+                prefilling = [i for i in running
+                              if i.prepared.seq.prefilling]
+                if prefilling:
+                    eng.prefill_next(prefilling[0].prepared)
+                decoding = [i for i in running
+                            if not i.prepared.seq.prefilling
+                            and not i.prepared.seq.done]
+                if decoding:
+                    eng.decode([i.prepared for i in decoding])
+                    obs.BATCHES_DISPATCHED.inc()
+                    with self._cond:
+                        self._stats["batches"] += 1
+                for item in [i for i in running if i.prepared.seq.done]:
+                    eng.release(item.prepared)
+                    running.remove(item)
+                    self._awaiting_tokens[id(item.prepared)] = item
+                finished = eng.collect(
+                    drain=not prefilling and not decoding)
+            except Exception:
+                # The step failed, and with it the state of every sequence
+                # it touched: each job ends by the usual failure path.
+                for item in running + list(self._awaiting_tokens.values()):
+                    if item in running:
+                        eng.release(item.prepared)
+                    self.worker._fail_job(item.job)
+                running.clear()
+                self._awaiting_tokens.clear()
+                eng.drop_pending()
+                return
+            sp.set(admitted=len(admitted), decoding=len(decoding))
+        for req in finished:
+            item = self._awaiting_tokens.pop(id(req))
+            # The completion queue's blocking put is the backpressure
+            # point, as in _dispatch_packed.
+            self._completions.put((item, req.result()))
+
     # -------------------------------------------------------------- driver
     def run(self) -> None:
         intakes = [
@@ -626,6 +735,8 @@ class ContinuousScheduler:
             t.start()
         completion.start()
         try:
+            if getattr(self.worker.engine, "generates", False):
+                self._generate_loop()
             while not self.stop.is_set():
                 batch, expired = self._next_batch()
                 for item in expired:

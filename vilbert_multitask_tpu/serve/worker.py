@@ -137,6 +137,17 @@ class ServeWorker:
         task_id = int(body["task_id"])  # reference eval()s this str; we don't
         question = body.get("question", "")
         socket_id = body.get("socket_id", "")
+        if TASK_REGISTRY[task_id].decode == "generate":
+            # No feature store to read: the prompt rides in the body.
+            log_to_terminal(self.hub, socket_id,
+                            {"terminal": "Running Generate inference..."})
+            qa_id = self.store.create_question(task_id, question, [],
+                                               socket_id,
+                                               queue_job_id=job.id)
+            prepared = self.engine.prepare_generate(body)
+            obs.job_charge(body.get("trace_id", ""), "intake",
+                           time.perf_counter() - t0)
+            return qa_id, prepared, t0
         image_paths = body["image_path"]
         if isinstance(image_paths, str):
             image_paths = [image_paths]

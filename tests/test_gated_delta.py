@@ -7,7 +7,10 @@ Tolerance: all three are float32 at ``highest`` precision; they differ by
 summation order and by the triangular solve, read at 3e-6 on outputs of
 size 2: 5e-5 allowed. The TPU kernel's real-size compile is the one thing
 here that is not CPU arithmetic (section 2 of the on-chip-measurement guide:
-the chip's compiler runs without the chip)."""
+the chip's compiler runs without the chip). The other kernel of the generate
+path, ``ops/paged_attention.py``, is compiled for the chip here too (its
+arithmetic is ``tests/test_paged_attention.py``'s): one file describes the
+chip, because one process at a time may load its compiler."""
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +18,7 @@ import numpy as np
 import pytest
 
 from vilbert_multitask_tpu.ops import gated_delta as gd
+from vilbert_multitask_tpu.ops import paged_attention
 
 ATOL = 5e-5
 
@@ -110,3 +114,26 @@ def test_kernel_compiles_for_the_chip_at_the_served_size(one_chip):
     # reads.
     assert "%gated_delta_scan" in text
     assert "(f32[30,32,64,192]" in text and "f32[30,96,192]" in text
+
+
+@pytest.mark.parametrize("B", [8, 16, 24, 32])
+def test_paged_attention_compiles_for_the_chip_at_the_served_size(one_chip,
+                                                                  B):
+    """Every decode bucket over the served pool: 4 layers of 256 + 1 pages
+    of 30 heads x 256 tokens x 128, bfloat16, read in place (no temporary:
+    a copy of a pool would be 2 GB)."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sd(jnp.bfloat16, 4, 257, 30, 256, 128)
+    compiled = jax.jit(
+        lambda q, k, v, positions, page_slot, page_pos, pool_blocks:
+        paged_attention.paged_decode_attention(
+            q, k, v, 1, positions, page_slot, page_pos, pool_blocks, 32)
+    ).lower(sd(jnp.bfloat16, B, 30, 128), pool, pool, sd(jnp.int32, B),
+            sd(jnp.int32, 256), sd(jnp.int32, 256),
+            sd(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20

@@ -44,9 +44,13 @@ def generate_cfg(**over) -> FrameworkConfig:
     return FrameworkConfig(generate=dataclasses.replace(gen, **over))
 
 
-@pytest.fixture(scope="module")
-def engine():
-    eng = GenerateEngine(generate_cfg())
+@pytest.fixture(scope="module", params=["jnp", "pallas_interpret"])
+def engine(request):
+    """The engine the CPU serves with, and the one the chip does with its
+    kernels (the scan, the paged decode attention) in the interpreter."""
+    model = MODEL if request.param == "jnp" else dataclasses.replace(
+        MODEL, use_pallas_scan=True, pallas_interpret=True)
+    eng = GenerateEngine(generate_cfg(model=model))
     eng.warmup()
     return eng
 

@@ -1,0 +1,125 @@
+"""The paged decode attention kernel against its oracle (ISSUE 29): the
+Pallas kernel of ``ops/paged_attention.py`` in interpret mode and
+``models/olmo_hybrid.py:_decode_attention`` (the ``jax.numpy`` form the CPU
+serves with) on the same pools, for every decode bucket and four states of
+the pool.
+
+Every pool has holes (pages nobody owns between owned ones), a slot that
+owns nothing (no key: the kernel gives exactly 0, never NaN), sequences
+that end in the middle of a page, pages handed out out of order, a page
+whose owner lies beyond the batch, and is read at the second layer index
+while the first holds other numbers.
+
+Tolerance: both are float32 on the CPU and differ by summation order (the
+oracle folds a block of pages at a time, the kernel a page), read at 1e-6
+on outputs of unit size: 5e-5 allowed, as ``tests/test_gated_delta.py``.
+The kernel's compile for the chip at the served size is in that file too,
+beside the scan's: one file describes the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vilbert_multitask_tpu.config import OlmoHybridConfig
+from vilbert_multitask_tpu.models import olmo_hybrid as model_lib
+from vilbert_multitask_tpu.ops import paged_attention
+
+ATOL = 5e-5
+LAYERS, PAGES, HEADS, PAGE, WIDTH, BLOCK = 2, 24, 3, 16, 32, 8
+LAYER = 1
+
+# pool state -> (pages the live sequences may take, ``pool_blocks``)
+POOLS = {
+    "empty": (0, 0),
+    "one_block": (BLOCK, 1),
+    "partly_used_last_block": (2 * BLOCK + 3, 3),
+    "every_block": (PAGES, 3),
+}
+
+
+def operands(B, seed):
+    """Queries and both pools, every layer filled."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (LAYERS, PAGES + 1, HEADS, PAGE, WIDTH)
+    return (jax.random.normal(ks[0], (B, HEADS, WIDTH)),
+            jax.random.normal(ks[1], shape), jax.random.normal(ks[2], shape))
+
+
+def tables(B, reach, rng):
+    """Sequences over the first ``reach`` pages: every third page left to
+    nobody, the rest dealt in shuffled order to the even slots of the batch
+    (the odd ones own nothing), one page to a slot beyond the batch; each
+    sequence ends somewhere inside its last page."""
+    page_slot = np.full((PAGES,), -1, np.int32)
+    page_pos = np.zeros((PAGES,), np.int32)
+    positions = np.zeros((B,), np.int32)
+    free = [i for i in range(reach) if i % 3 != 1]
+    rng.shuffle(free)
+    if free:
+        page_slot[free.pop()] = B + 1
+    owners = list(range(0, B, 2))
+    held = {b: [] for b in owners}
+    for n, page in enumerate(free):
+        held[owners[n % len(owners)]].append(page)
+    for b, pages in held.items():
+        for nth, page in enumerate(pages):
+            page_slot[page], page_pos[page] = b, nth
+        if pages:
+            positions[b] = (len(pages) - 1) * PAGE + rng.integers(0, PAGE)
+    return page_slot, page_pos, positions, [b for b in owners if held[b]]
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+@pytest.mark.parametrize("B", [8, 16, 24, 32])
+def test_kernel_equals_the_jnp_form(B, pool):
+    reach, pool_blocks = POOLS[pool]
+    rng = np.random.default_rng(B * 7 + reach)
+    page_slot, page_pos, positions, with_keys = tables(B, reach, rng)
+    args = (*operands(B, B + reach), LAYER, jnp.asarray(positions),
+            jnp.asarray(page_slot), jnp.asarray(page_pos),
+            jnp.int32(pool_blocks), BLOCK)
+    want = model_lib._decode_attention(OlmoHybridConfig().tiny(), *args)
+    got = paged_attention.paged_decode_attention(*args, interpret=True)
+    assert got.shape == want.shape == (B, HEADS, WIDTH)
+    assert got.dtype == jnp.float32
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    assert (reach == 0) == (not with_keys)
+    if with_keys:
+        assert np.abs(got[with_keys] - want[with_keys]).max() < ATOL
+        assert np.abs(got[with_keys]).max() > 0.1
+    without = sorted(set(range(B)) - set(with_keys))
+    assert (got[without] == 0).all()
+
+
+def test_a_key_beyond_its_slots_position_is_not_read():
+    """Two pages of one sequence, the position inside the first: the second
+    page's keys, and the first's behind the position, change nothing."""
+    B = 8
+    page_slot = np.full((PAGES,), -1, np.int32)
+    page_pos = np.zeros((PAGES,), np.int32)
+    page_slot[[5, 2]], page_pos[[5, 2]] = 3, [0, 1]
+    positions = np.zeros((B,), np.int32)
+    positions[3] = 6
+    q, k_pool, v_pool = operands(B, 0)
+
+    def run(k_pool, v_pool):
+        return np.asarray(paged_attention.paged_decode_attention(
+            q, k_pool, v_pool, LAYER, jnp.asarray(positions),
+            jnp.asarray(page_slot), jnp.asarray(page_pos), jnp.int32(1),
+            BLOCK, interpret=True))
+
+    seen = run(k_pool, v_pool)
+    unseen = run(k_pool.at[LAYER, 2].set(9.0).at[LAYER, 5, :, 7:].set(9.0),
+                 v_pool.at[LAYER, 2].set(9.0).at[LAYER, 5, :, 7:].set(9.0))
+    assert np.abs(seen[3]).max() > 0.1
+    assert (seen == unseen).all()
+    # The plain softmax over the seven keys the mask admits.
+    keys, values = (np.asarray(t[LAYER, 5, :, :7]) for t in (k_pool, v_pool))
+    scores = np.einsum("hd,hkd->hk", np.asarray(q[3]), keys) / np.sqrt(WIDTH)
+    weights = np.exp(scores - scores.max(-1, keepdims=True))
+    weights /= weights.sum(-1, keepdims=True)
+    assert np.abs(np.einsum("hk,hkd->hd", weights, values)
+                  - seen[3]).max() < ATOL

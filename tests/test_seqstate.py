@@ -30,7 +30,7 @@ def test_sizes_follow_the_model():
     assert st.page_bytes == 2 * 2 * 16 * 4 * 16 * 4
     assert st.capacity_bytes == 4 * st.slot_bytes + 32 * st.page_bytes
     assert st.rec_shape == (2, 3, 4, 4, 8, 16)
-    assert st.pool_shape == (2, 33, 16, 4, 16)   # one page is nobody's
+    assert st.pool_shape == (2, 33, 4, 16, 16)   # one page is nobody's
     assert st.max_pages_per_seq == m.max_position_embeddings // 16
     full = SequenceState(GenerateConfig(model=dataclasses.replace(
         OlmoHybridConfig(), num_hidden_layers=16,

@@ -646,8 +646,9 @@ class OlmoHybridConfig:
     linear_value_head_dim: int = 192
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
-    # Kernel choice (not the source's): the chunked scan as the Pallas
-    # kernel of ops/gated_delta.py; ``pallas_interpret`` is the CPU tests'
+    # Kernel choice (not the source's): the Pallas kernels on, which are
+    # the chunked scan of ops/gated_delta.py and the decode attention of
+    # ops/paged_attention.py; ``pallas_interpret`` is the CPU tests'
     # explicit choice, never inferred from the backend.
     use_pallas_scan: bool = True
     pallas_interpret: bool = False

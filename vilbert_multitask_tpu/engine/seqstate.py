@@ -6,8 +6,9 @@ for every linear-attention layer a recurrent state ``[heads, d_k, d_v]``
 float32 (the transpose of the equations' ``S``) and the last ``taps - 1``
 rows that entered the convolution. *Pages*: what grows with the sequence:
 for every full-attention layer keys and values in pages of ``page_size``
-tokens, ``[layer, page, token, head, d]``, and per sequence the list of its
-pages in order.
+tokens, ``[layer, page, head, token, d]`` (a head's keys of a page are one
+tile: what ``ops/paged_attention.py`` reads), and per sequence the list of
+its pages in order.
 
 The device side is a dict of arrays (:meth:`allocate`), handed to the
 compiled programs *donated* and taken back updated: a prefill chunk writes
@@ -102,8 +103,9 @@ class SequenceState:
                            model.conv_width)
         # One page more than the pool counts: the last belongs to nobody,
         # and is where a padding row's keys and values are written.
-        self.pool_shape = (model.periods, self.pages + 1, self.page_size,
-                           model.num_key_value_heads, model.head_dim)
+        self.pool_shape = (model.periods, self.pages + 1,
+                           model.num_key_value_heads, self.page_size,
+                           model.head_dim)
         self.slot_bytes = linear_layers * (
             int(np.prod(self.rec_shape[3:])) * 4
             + int(np.prod(self.conv_shape[3:])) * item)

@@ -28,10 +28,13 @@ chunk of one sequence's prompt: the recurrent state through the chunked
 scan, keys and values written to the sequence's pages, attention over the
 pages written so far. :func:`decode_step` runs one token of every running
 sequence: the rank-1 state update, one key/value row written, attention
-over the whole pool under an ownership mask. Both work on the *sequence
-state* of ``engine/seqstate.py`` (a dict of device arrays, donated and
-updated in place) and return the logits of the last real position only, as
-the arg-max token, its logit and the logits of the ids asked for.
+over the whole pool under an ownership mask (the Pallas kernel of
+``ops/paged_attention.py`` where ``use_pallas_scan`` says kernels are on,
+:func:`_decode_attention` in ``jax.numpy`` otherwise). Both work on the
+*sequence state* of ``engine/seqstate.py`` (a dict of device arrays, donated
+and updated in place; the key/value pools are ``[P, pages + 1, H, page,
+D]``) and return the logits of the last real position only, as the arg-max
+token, its logit and the logits of the ids asked for.
 
 Layers of one period (``k`` linear layers, then a full one) are stacked on
 a leading axis ``[periods, ...]``. The periods are walked in Python, not
@@ -51,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from vilbert_multitask_tpu.config import OlmoHybridConfig
-from vilbert_multitask_tpu.ops import gated_delta
+from vilbert_multitask_tpu.ops import gated_delta, paged_attention
 
 __all__ = ["OlmoHybridConfig", "param_shapes", "init_params",
            "prefill_chunk", "decode_step"]
@@ -182,13 +185,13 @@ def _full_qkv(cfg, x, fp):
 
 def _online_softmax(carry, scores, values):
     """One block of a streaming softmax. ``scores`` [H, R, K] float32
-    (masked entries at ``_NEG``), ``values`` [K, H, D]."""
+    (masked entries at ``_NEG``), ``values`` [H, K, D]."""
     m, l, acc = carry
     m_new = jnp.maximum(m, scores.max(-1))
     p = jnp.exp(scores - m_new[..., None])
     fade = jnp.exp(m - m_new)
     acc = acc * fade[..., None] + jnp.einsum(
-        "hrk,khd->hrd", p.astype(values.dtype), values,
+        "hrk,hkd->hrd", p.astype(values.dtype), values,
         preferred_element_type=jnp.float32)
     return m_new, l * fade + p.sum(-1), acc
 
@@ -233,9 +236,9 @@ def _prefill_linear(cfg, x, lp, rec, tail, real, length):
 def _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start, block):
     """Causal attention of a chunk's queries [T, H, D] over the sequence's
     pages, ``block`` pages at a time up to the chunk's end. The pools are
-    [P, H, pages, page, D]; ``page_row`` names the sequence's pages."""
+    [P, pages, H, page, D]; ``page_row`` names the sequence's pages."""
     T, H, D = q.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     span = block * page
     qh = jnp.swapaxes(q, 0, 1)                          # [H, T, D]
     q_pos = start + jnp.arange(T)
@@ -243,11 +246,11 @@ def _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start, block):
     def gather(pool, j):
         parts = [jax.lax.dynamic_slice(
             pool, (p, page_row[j * block + i], 0, 0, 0),
-            (1, 1, page, H, D)).reshape(page, H, D) for i in range(block)]
-        return jnp.concatenate(parts, axis=0)           # [span, H, D]
+            (1, 1, H, page, D)).reshape(H, page, D) for i in range(block)]
+        return jnp.concatenate(parts, axis=1)           # [H, span, D]
 
     def body(j, carry):
-        scores = jnp.einsum("htd,khd->htk", qh, gather(k_pool, j),
+        scores = jnp.einsum("htd,hkd->htk", qh, gather(k_pool, j),
                             preferred_element_type=jnp.float32)
         k_pos = j * span + jnp.arange(span)
         seen = k_pos[None, :] <= q_pos[:, None]
@@ -263,13 +266,15 @@ def _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start, block):
 
 
 def _write_rows(pool, p, rows, pages, offsets):
-    """Write ``rows`` [N, R, H, D] into the pool [P, pages, page, H, D] of
-    layer ``p``, row n at page ``pages[n]`` from token ``offsets[n]``: one
-    dynamic-update-slice a row, unrolled, each in place (a scatter, or a
-    loop that carries the pool, has the compiler copy the whole pool)."""
+    """Write ``rows`` [N, R, H, D] (R tokens of every head) into the pool
+    [P, pages, H, page, D] of layer ``p``, row n at page ``pages[n]`` from
+    token ``offsets[n]``: one dynamic-update-slice a row, unrolled, each in
+    place (a scatter, or a loop that carries the pool, has the compiler
+    copy the whole pool)."""
+    rows = jnp.swapaxes(rows, 1, 2)                     # [N, H, R, D]
     for n in range(rows.shape[0]):
         pool = jax.lax.dynamic_update_slice(
-            pool, rows[n][None, None], (p, pages[n], offsets[n], 0, 0))
+            pool, rows[n][None, None], (p, pages[n], 0, offsets[n], 0))
     return pool
 
 
@@ -284,7 +289,7 @@ def prefill_chunk(cfg: OlmoHybridConfig, params, state, tokens, slot, start,
     is written there), ``logit_ids`` [n]. Returns the updated state and the
     head's output at row ``length - 1``."""
     T = tokens.shape[0]
-    page = state["k"].shape[2]
+    page = state["k"].shape[3]
     trash = state["k"].shape[1] - 1
     real = jnp.arange(T) < length
     x = params["embed"][tokens].astype(jnp.float32)
@@ -353,21 +358,23 @@ def _decode_attention(cfg, q, k_pool, v_pool, p, positions, page_slot,
                       page_pos, pool_blocks, block):
     """One query a slot [B, H, D] (row b is slot b) over the whole pool,
     ``block`` pages at a time, each key masked by who owns its page and
-    where it lies in its sequence: no gather, the pool is read once, as far
-    as ``pool_blocks`` says pages are in use."""
+    where it lies in its sequence, as far as ``pool_blocks`` says pages are
+    in use. The ``jax.numpy`` form: every block is copied out of the pool
+    and its scores go through memory. It is the CPU's path and the oracle
+    of ``ops/paged_attention.py``, which the chip runs."""
     B, H, D = q.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     span = block * page
     qh = jnp.swapaxes(q, 0, 1)                          # [H, B, D]
     slots = jnp.arange(B)
 
     def take(pool, j):
-        return jax.lax.dynamic_slice(
-            pool, (p, j * block, 0, 0, 0),
-            (1, block, page, H, D)).reshape(span, H, D)
+        pages = jax.lax.dynamic_slice(
+            pool, (p, j * block, 0, 0, 0), (1, block, H, page, D))
+        return jnp.swapaxes(pages[0], 0, 1).reshape(H, span, D)
 
     def body(j, carry):
-        scores = jnp.einsum("hbd,khd->hbk", qh, take(k_pool, j),
+        scores = jnp.einsum("hbd,hkd->hbk", qh, take(k_pool, j),
                             preferred_element_type=jnp.float32)
         owner = jax.lax.dynamic_slice_in_dim(page_slot, j * block, block)
         where = jax.lax.dynamic_slice_in_dim(page_pos, j * block, block)
@@ -400,7 +407,7 @@ def decode_step(cfg: OlmoHybridConfig, params, state, active, positions,
     pages reach past the last page in use. Returns the updated state and
     the head's output [B]."""
     B = positions.shape[0]
-    page = state["k"].shape[2]
+    page = state["k"].shape[3]
     x = params["embed"][state["token"][:B]].astype(jnp.float32)
     offset = positions % page
 
@@ -422,9 +429,13 @@ def decode_step(cfg: OlmoHybridConfig, params, state, active, positions,
         q, k, v = _full_qkv(cfg, x, full)
         k_pool = _write_rows(k_pool, p, k[:, None], write_page, offset)
         v_pool = _write_rows(v_pool, p, v[:, None], write_page, offset)
-        ctx = _decode_attention(cfg, q, k_pool, v_pool, p, positions,
-                                page_slot, page_pos, pool_blocks,
-                                attention_block)
+        pool_args = (q, k_pool, v_pool, p, positions, page_slot, page_pos,
+                     pool_blocks, attention_block)
+        if cfg.use_pallas_scan:
+            ctx = paged_attention.paged_decode_attention(
+                *pool_args, interpret=cfg.pallas_interpret)
+        else:
+            ctx = _decode_attention(cfg, *pool_args)
         x = _close_block(cfg, x, _mm(ctx.reshape(B, -1), full["wo"]), full)
     out = _head(cfg, params, x, logit_ids)
     token = jnp.where(active, out["token"], state["token"][:B])

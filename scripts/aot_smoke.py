@@ -19,9 +19,6 @@ Gates:
 - warm boot wall < 50% of the cold boot (hardware target is <10% of the
   ~150 s cold boot; CPU-tiny measures the same mechanism at smaller scale).
 
-Appends an ``aot.smoke`` line to the $VMT_PERF_LEDGER ledger so the warm/cold split
-trends round over round.
-
 Usage: python scripts/aot_smoke.py [--out AOT_SMOKE.json]
 """
 
@@ -155,24 +152,6 @@ def main() -> int:
     if args.out:
         with open(args.out, "w") as f:
             f.write(line + "\n")
-    if not failures:
-        # Ledger ride-along: warm/cold restart wall trends per round
-        # (the ``_s`` keys carry direction=lower in perf_ledger check).
-        try:
-            from vilbert_multitask_tpu import obs
-            from vilbert_multitask_tpu.config import (
-                FrameworkConfig,
-                config_fingerprint,
-            )
-
-            obs.ledger_append(
-                "aot.smoke",
-                {"cold_boot_s": cold["wall_s"],
-                 "warm_cache_s": warm["wall_s"],
-                 "warm_over_cold": round(ratio, 4)},
-                config_fingerprint=config_fingerprint(FrameworkConfig()))
-        except Exception as e:  # noqa: BLE001 — the gate already passed
-            print(f"# ledger append skipped: {e}", file=sys.stderr)
     return 0 if not failures else 1
 
 

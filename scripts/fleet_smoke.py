@@ -11,9 +11,6 @@ into the same ``fleet.sqlite3``, then interrogate the app's HTTP face:
 - ``/debug/trace?scope=fleet&trace_id=`` returns ONE stitched timeline
   carrying spans recorded in both processes
 
-Appends a perf-ledger entry (boot + fleet-query latency) so fleet-plane
-cost drift surfaces in ``perf_ledger.py check``, not a pager.
-
 CPU-only dryrun: this process pins the CPU platform before ServeApp touches
 JAX, and the peer process imports no JAX at all — neither ever holds (or
 waits for) a chip.
@@ -87,7 +84,6 @@ def main(argv=None) -> int:
     app = ServeApp(cfg, engine=DryrunEngine(cfg, "r0"))
     app.start(worker=False)
     boot_s = time.perf_counter() - t0
-    assert app.fleet is not None, "fleet spine disabled in serving config"
 
     failures = []
     report = {"metric": "fleet_smoke", "boot_s": round(boot_s, 3)}
@@ -163,17 +159,6 @@ def main(argv=None) -> int:
     verdict = not failures
     report["failures"] = failures
     report["verdict"] = verdict
-    try:
-        from vilbert_multitask_tpu.config import config_fingerprint
-
-        obs.ledger_append(
-            "fleet.smoke",
-            {"boot_s": report["boot_s"],
-             "fleet_query_ms": report.get("fleet_query_ms", 0.0)},
-            config_fingerprint=config_fingerprint(cfg),
-            extra={"verdict": "pass" if verdict else "fail"})
-    except Exception as e:
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)
     print(json.dumps(report), flush=True)

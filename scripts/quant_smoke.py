@@ -8,11 +8,11 @@ mode, on the tiny model so it runs in seconds:
 - **parity**: one representative task per decode family (labels / binary /
   grounding) decodes within per-channel quantization noise of the f32
   engine, through the FUSED head path (the serving default);
-- **knee**: the analytic batch-knee (engine/flops.knee_rows — the number
-  bench.py emits as ``knee_rows``) is finite, >= 1, and strictly smaller
-  for int8 than for f32 storage: fewer weight bytes flip the roofline
-  verdict to compute-bound at a smaller batch. ``weight_bytes_per_row``
-  must shrink with batch and with the storage dtype.
+- **knee**: the analytic batch-knee (engine/flops.knee_rows) is finite,
+  >= 1, and strictly smaller for int8 than for f32 storage: fewer weight
+  bytes flip the roofline verdict to compute-bound at a smaller batch.
+  ``weight_bytes_per_row`` must shrink with batch and with the storage
+  dtype.
 
 Usage: python scripts/quant_smoke.py [--out QUANT_SMOKE.json]
 """

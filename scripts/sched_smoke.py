@@ -31,12 +31,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from serve_soak import (  # noqa: E402
     PATTERN,
     _build_cfg,
-    _ledger_verdict,
     _make_features,
 )
 
 
-def _fresh_stack(cfg, engine, root, tag, **serving_overrides):
+def _fresh_stack(cfg, engine, root, tag):
     from vilbert_multitask_tpu.serve import (
         DurableQueue,
         PushHub,
@@ -47,8 +46,7 @@ def _fresh_stack(cfg, engine, root, tag, **serving_overrides):
     s = dataclasses.replace(
         cfg.serving,
         queue_db_path=os.path.join(root, f"q_{tag}.sqlite3"),
-        results_db_path=os.path.join(root, f"r_{tag}.sqlite3"),
-        **serving_overrides)
+        results_db_path=os.path.join(root, f"r_{tag}.sqlite3"))
     hub = PushHub()
     q = DurableQueue(s.queue_db_path,
                      max_delivery_attempts=s.max_delivery_attempts)
@@ -116,8 +114,7 @@ def main(argv=None) -> int:
     solo_done = min(solo_done, _count_results(sub, solo_done, timeout_s=10))
 
     # --- scheduler: the pipelined three-stage data plane ----------------
-    _s, hub, q, _store, worker = _fresh_stack(cfg, engine, root, "sched",
-                                              sched_enabled=True)
+    _s, hub, q, _store, worker = _fresh_stack(cfg, engine, root, "sched")
     sub = hub.subscribe("smoke")
     _publish_burst(q, args.jobs, "smoke")
     stop = threading.Event()
@@ -154,7 +151,6 @@ def main(argv=None) -> int:
         "no_lost_jobs": no_lost,
         "verdict": verdict,
     }
-    _ledger_verdict(report, verdict, prefix="smoke.")
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)
     print(json.dumps(report), flush=True)

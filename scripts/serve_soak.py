@@ -27,7 +27,7 @@ ratio, plus a rolling checkpoint swap mid-burst (zero requests lost, >=1
 replica ready throughout). ``--kill-replica`` adds a seeded chaos burst:
 one replica is silently killed mid-burst and the run asserts exactly one
 terminal per job, zero double-executions, and the dead replica visible in
-/healthz within about one sampler cadence. Artifact: SERVE_SOAK_POOL.json.
+/healthz within about one sampler cadence.
 
 ``--autoscale`` runs the CLOSED-LOOP AUTOSCALER soak: a diurnal +
 flash-crowd load shape (ramp → spike → trough) over dryrun replicas with
@@ -36,10 +36,12 @@ pool within one AOT-boot latency of the sustained-breach decision with
 nothing shed, and the trough must retire capacity back to the floor;
 ``--autoscale --chaos`` instead floods poisoned jobs (seeded
 ``worker.intake`` faults + slow claims) and asserts the controller never
-scales into the poison storm. Artifact: SERVE_SOAK_AUTOSCALE.json;
-ledger metric: ``autoscale.soak``.
+scales into the poison storm.
 
-Usage: python scripts/serve_soak.py [--jobs 96] [--out SERVE_SOAK.json]
+Every mode prints its report as one JSON line and exits by its verdict;
+``--out`` also writes the report to a file.
+
+Usage: python scripts/serve_soak.py [--jobs 96] [--out report.json]
        [--full] [--chaos] [--seed 0]
        [--replicas 2 --dryrun [--kill-replica]]
        [--autoscale [--chaos]] [--zipf [--chaos]]
@@ -64,64 +66,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # CPU unless the caller explicitly wants the hardware path (--full implies
 # whatever backend jax picks).
 
-
-# config_fingerprint() of the run's FrameworkConfig, stamped by _build_cfg:
-# every perf-ledger entry this script appends carries the real
-# fingerprint, so cross-round baselines only compare like configs.
-_FP: "str | None" = None
-
-
-def _ledger_verdict(report: dict, verdict: bool,
-                    prefix: str = "soak.") -> None:
-    """Append this run's verdict line to the $VMT_PERF_LEDGER ledger (best-effort:
-    the artifact file is the soak's contract; a read-only checkout must
-    not fail the run). Variants ledger under distinct metric names —
-    full-model and chaos runs have different latency shapes than the CI
-    tiny burst, and check() baselines are per-metric medians.
-    (sched_smoke.py reuses this with its own prefix.)"""
-    try:
-        from vilbert_multitask_tpu import obs
-
-        metric = prefix + str(report.get("metric"))
-        if report.get("model") == "full":
-            metric += ".full"
-        if "chaos" in report:
-            metric += ".chaos"
-        if "threadkill" in report:
-            metric += ".threadkill"
-        values = {}
-        for k in ("value", "e2e_p50_ms", "e2e_p95_ms", "boot_s",
-                  "makespan_s", "qps_ratio_vs_1_replica", "baseline_qps",
-                  "solo_qps", "sched_qps", "speedup"):
-            v = report.get(k)
-            if isinstance(v, (int, float)) and not isinstance(v, bool):
-                values[k] = v
-        obs.ledger_append(metric, values, config_fingerprint=_FP, extra={
-            "verdict": "pass" if verdict else "fail",
-            "backend": report.get("backend"),
-        })
-    except Exception as e:  # noqa: BLE001 — ride-along must never fail the soak
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
-
-
-def _ledger_attrib(report: dict, verdict: bool) -> None:
-    """Ledger the cost-attribution verdict under its own metric: the
-    conservation ratio and tail-kept fraction trend independently of
-    qps, and check() baselines are per-metric medians."""
-    try:
-        from vilbert_multitask_tpu import obs
-
-        ca = report.get("cost_attrib") or {}
-        values = {k: v for k, v in ca.items()
-                  if isinstance(v, (int, float)) and not isinstance(v, bool)}
-        if values:
-            obs.ledger_append("soak.attrib", values, config_fingerprint=_FP,
-                              extra={
-                                  "verdict": "pass" if verdict else "fail",
-                                  "chaos": "chaos" in report,
-                              })
-    except Exception as e:  # noqa: BLE001 — ride-along must never fail the soak
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
+def _finish(report: dict, verdict: bool, out) -> int:
+    """The soak's contract: the report as one JSON line (also written to
+    ``--out`` when given) and the verdict as the exit code."""
+    if out:
+        with open(out, "w") as f:
+            json.dump(report, f, indent=2)
+    print(json.dumps(report), flush=True)
+    return 0 if verdict else 1
 
 
 def _build_cfg(root: str, full: bool, tenant_weights=None,
@@ -131,7 +83,6 @@ def _build_cfg(root: str, full: bool, tenant_weights=None,
         FrameworkConfig,
         ServingConfig,
         ViLBertConfig,
-        config_fingerprint,
     )
 
     model = ViLBertConfig() if full else ViLBertConfig().tiny()
@@ -154,15 +105,11 @@ def _build_cfg(root: str, full: bool, tenant_weights=None,
         tenant_weights=tenant_weights,
     )
     # Mode-specific knob overrides (the autoscale soak shrinks windows and
-    # cooldowns to CI scale) land BEFORE fingerprinting: the ledger must
-    # key baselines on the config that actually ran.
+    # cooldowns to CI scale).
     if extra_serving:
         serving_kwargs.update(extra_serving)
-    cfg = FrameworkConfig(model=model, engine=engine,
-                          serving=ServingConfig(**serving_kwargs))
-    global _FP
-    _FP = config_fingerprint(cfg)
-    return cfg
+    return FrameworkConfig(model=model, engine=engine,
+                           serving=ServingConfig(**serving_kwargs))
 
 
 def _make_features(root: str, dim: int, n: int = 4) -> str:
@@ -215,26 +162,6 @@ def _threadkill_plan(seed: int):
     return FaultPlan(seed, [
         FaultRule("queue.claim", "error", rate=1.0, max_injections=1),
     ])
-
-
-def _ledger_threadkill(report: dict, verdict: bool) -> None:
-    """Ledger the thread-kill verdict under its own metric: detection
-    latency trends independently of qps, and check() baselines are
-    per-metric medians."""
-    try:
-        from vilbert_multitask_tpu import obs
-
-        tk = report.get("threadkill") or {}
-        values = {k: v for k, v in tk.items()
-                  if isinstance(v, (int, float)) and not isinstance(v, bool)}
-        if values:
-            obs.ledger_append("soak.threadkill", values,
-                              config_fingerprint=_FP, extra={
-                                  "verdict": "pass" if verdict else "fail",
-                                  "dead_thread": tk.get("dead_thread"),
-                              })
-    except Exception as e:  # noqa: BLE001 — ride-along must never fail the soak
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
 
 
 def _chaos_worker(app, retry_budget_hint: float = 1e6):
@@ -313,7 +240,6 @@ class DryrunEngine:
         self.killed = False
         self.mesh = None
         self.pallas_enabled = False
-        self.stage_times = {}
         self.input_cache_stats = {}
         self.service_s = service_ms_per_row / 1e3
         self.jobs_served = 0
@@ -577,36 +503,10 @@ def run_pool_soak(args) -> int:
         })
     report["checks"] = checks
     verdict = all(checks.values())
-    _ledger_verdict(report, verdict)
-    out = args.out or "SERVE_SOAK_POOL.json"
-    with open(out, "w") as f:
-        json.dump(report, f, indent=2)
-    print(json.dumps(report), flush=True)
-    return 0 if verdict else 1
+    return _finish(report, verdict, args.out)
 
 
 # ----------------------------------------------------- duplicate-traffic soak
-def _ledger_coalesce(report: dict, verdict: bool) -> None:
-    """Ledger the duplicate-traffic verdict under ``soak.coalesce``: the
-    hit/forward speedup and the collapse ratio trend independently of the
-    plain soak's qps, and check() baselines are per-metric medians."""
-    try:
-        from vilbert_multitask_tpu import obs
-
-        values = {}
-        for k in ("hit_qps", "forward_qps", "coalesce_ratio"):
-            v = report.get(k)
-            if isinstance(v, (int, float)) and not isinstance(v, bool):
-                values[k] = v
-        obs.ledger_append("soak.coalesce", values, config_fingerprint=_FP,
-                          extra={
-                              "verdict": "pass" if verdict else "fail",
-                              "chaos": "chaos" in report,
-                          })
-    except Exception as e:  # noqa: BLE001 — ride-along must never fail the soak
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
-
-
 def _is_terminal_frame(frame: dict) -> bool:
     """A submit's terminal frames, by shape: a result payload, a dead-letter
     error, or a deadline push. Progress text ('Running…', the completion
@@ -639,8 +539,7 @@ def run_zipf_soak(args) -> int:
        survive a swap).
 
     Engines are dryrun stubs (GIL-releasing sleep per row): the subject is
-    the dedup planes, not the forward. Artifact: SERVE_SOAK_ZIPF.json;
-    ledger metric: ``soak.coalesce``.
+    the dedup planes, not the forward.
     """
     import jax
 
@@ -844,40 +743,13 @@ def run_zipf_soak(args) -> int:
         }
         checks["chaos_leader_dead_lettered"] = co_states == ["error"]
     verdict = all(checks.values())
-    _ledger_coalesce(report, verdict)
-    out = args.out or "SERVE_SOAK_ZIPF.json"
-    with open(out, "w") as f:
-        json.dump(report, f, indent=2)
-    print(json.dumps(report), flush=True)
-    return 0 if verdict else 1
+    return _finish(report, verdict, args.out)
 
 
 # ----------------------------------------------------- autoscale soak
-def _ledger_autoscale(report: dict, verdict: bool) -> None:
-    """Ledger the autoscaler verdict under ``autoscale.soak``: the
-    breach→capacity latency and the spike-phase tail trend independently
-    of qps, and check() baselines are per-metric medians. The chaos
-    variant carries no timing keys (its bar is "never scaled"), so only
-    the plain run appends."""
-    try:
-        from vilbert_multitask_tpu import obs
-
-        a = report.get("autoscale") or {}
-        values = {k: v for k, v in a.items()
-                  if isinstance(v, (int, float)) and not isinstance(v, bool)}
-        if values:
-            obs.ledger_append("autoscale.soak", values,
-                              config_fingerprint=_FP, extra={
-                                  "verdict": "pass" if verdict else "fail",
-                                  "chaos": "chaos" in report,
-                              })
-    except Exception as e:  # noqa: BLE001 — ride-along must never fail the soak
-        print(f"# perf-ledger append skipped: {e}", file=sys.stderr)
-
-
-# One warm AOT-cache replica boot costs ~2.6 s on the serving config
-# (perf-ledger ``aot.boot``): the ISSUE's promptness bar — capacity must
-# exist within one boot latency of the sustained-breach decision.
+# The promptness bar: capacity must exist within one warm AOT-cache
+# replica boot (taken as 2.6 s; not measured on the chip) of the
+# sustained-breach decision.
 _AOT_BOOT_BAR_S = 2.6
 
 
@@ -901,8 +773,6 @@ def run_autoscale_soak(args) -> int:
     signals scream "scale out" but the work is poison. The controller
     must hold (``poison_storm`` decisions), never add a replica, and the
     dead-letter fan must still close every socket exactly once.
-
-    Artifact: SERVE_SOAK_AUTOSCALE.json; ledger: ``autoscale.soak``.
     """
     import jax
 
@@ -1082,12 +952,7 @@ def run_autoscale_soak(args) -> int:
             "checks": checks,
         }
         verdict = all(checks.values())
-        _ledger_autoscale(report, verdict)
-        out = args.out or "SERVE_SOAK_AUTOSCALE.json"
-        with open(out, "w") as f:
-            json.dump(report, f, indent=2)
-        print(json.dumps(report), flush=True)
-        return 0 if verdict else 1
+        return _finish(report, verdict, args.out)
 
     # ---- phase 1: ramp (trickle below the band — no scale motion) -------
     n_ramp = 8
@@ -1212,12 +1077,7 @@ def run_autoscale_soak(args) -> int:
         "checks": checks,
     }
     verdict = all(checks.values())
-    _ledger_autoscale(report, verdict)
-    out = args.out or "SERVE_SOAK_AUTOSCALE.json"
-    with open(out, "w") as f:
-        json.dump(report, f, indent=2)
-    print(json.dumps(report), flush=True)
-    return 0 if verdict else 1
+    return _finish(report, verdict, args.out)
 
 
 # Mixed burst: single-image tasks, an NLVR2 pair, and a retrieval set —
@@ -1235,8 +1095,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--jobs", type=int, default=96)
     p.add_argument("--out", default=None,
-                   help="artifact path (default SERVE_SOAK.json, or "
-                        "SERVE_SOAK_POOL.json in pool mode)")
+                   help="also write the report to this file")
     p.add_argument("--full", action="store_true",
                    help="serving-size model on whatever backend jax picks")
     p.add_argument("--chaos", action="store_true",
@@ -1293,8 +1152,6 @@ def main(argv=None) -> int:
         # Pool mode is dryrun by definition: replica scaling on a shared
         # host only measures the dispatch plane with stub service times.
         return run_pool_soak(args)
-    if args.out is None:
-        args.out = "SERVE_SOAK.json"
 
     if not args.full:
         import jax
@@ -1488,7 +1345,7 @@ def main(argv=None) -> int:
         slo_verdict = {"error": repr(e)}
     app.stop()
 
-    # Same histogram + percentile code as serve/metrics and bench — the
+    # Same histogram + percentile code as serve/metrics — the
     # soak's numbers are computed the one shared way.
     e2e = Histogram("soak_e2e_ms", "Submit→result-frame latency (ms).")
     for q, t in submitted.items():
@@ -1674,14 +1531,7 @@ def main(argv=None) -> int:
                    or abs(cost_attrib["device_s_conservation"] - 1.0)
                    <= 0.10)
         verdict = report["all_completed"] and cons_ok
-    _ledger_verdict(report, verdict)
-    _ledger_attrib(report, verdict)
-    if args.kill_thread:
-        _ledger_threadkill(report, verdict)
-    with open(args.out, "w") as f:
-        json.dump(report, f, indent=2)
-    print(json.dumps(report), flush=True)
-    return 0 if verdict else 1
+    return _finish(report, verdict, args.out)
 
 
 if __name__ == "__main__":

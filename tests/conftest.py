@@ -150,12 +150,21 @@ def _no_leaked_project_threads():
     contract — leaking either keeps sampling freed state under every
     later test), any other non-daemon thread joined, and any *named*
     daemon thread registered with the obs watchdog (a crash-guarded
-    loop announces itself; an anonymous stdlib helper gets a pass)."""
+    loop announces itself; an anonymous stdlib helper gets a pass).
+
+    One thread may be born mid-test and not be the test's: the writer of a
+    flight recorder that a wider-scoped fixture installed before the test
+    began (a module's ``ServeApp``) starts lazily at the first trigger, and
+    a sampler tick can fire one at any time (an SLO page on a loaded
+    host). That app's ``stop()`` joins it. A recorder installed *during*
+    the test is still the test's to close."""
     import threading
 
-    before = {id(t) for t in threading.enumerate()}
-    yield
     from vilbert_multitask_tpu import obs
+
+    before = {id(t) for t in threading.enumerate()}
+    recorder_before = obs.active_recorder()
+    yield
 
     # Default/stdlib naming schemes: unnamed threads, pool workers, and
     # asyncio helpers — not project loops, not watchdog material.
@@ -165,6 +174,10 @@ def _no_leaked_project_threads():
     leaked = []
     for t in threading.enumerate():
         if id(t) in before or not t.is_alive():
+            continue
+        if (recorder_before is not None
+                and obs.active_recorder() is recorder_before
+                and getattr(recorder_before, "_thread", None) is t):
             continue
         if t.name in (obs.SAMPLER_THREAD_NAME,
                       obs.RECORDER_THREAD_NAME):

@@ -2,7 +2,7 @@
 (engine/flops.py) against XLA's own cost model for the compiled serving
 forward. The analytic count ignores elementwise ops, so it must come in at
 or just under XLA's figure — never above it (an overcount would inflate
-every MFU number the bench reports)."""
+every MFU number derived from it)."""
 
 import numpy as np
 import pytest
@@ -12,7 +12,6 @@ from vilbert_multitask_tpu.engine.flops import (
     knee_rows,
     peak_flops_for,
     serving_forward_flops,
-    serving_roofline,
 )
 from vilbert_multitask_tpu.engine.runtime import InferenceEngine
 
@@ -60,33 +59,12 @@ def test_peak_lookup():
     assert np.isfinite(peak_flops_for("TPU v6 lite"))
 
 
-def test_roofline_refuses_a_device_it_does_not_know(tiny_config):
+def test_knee_refuses_a_device_it_does_not_know(tiny_config):
     """An unknown device kind is an error, never a silently substituted
-    reference chip; a caller that wants a named chip's analytic roofline
+    reference chip; a caller that wants a named chip's analytic knee
     off that chip passes the chip's name."""
     e = EngineConfig()
     for kind in ("cpu", "TPU v99"):
         with pytest.raises(ValueError, match="no peak"):
             knee_rows(tiny_config, e, kind, 10**6)
-        with pytest.raises(ValueError, match="no peak"):
-            serving_roofline(tiny_config, e, 8, kind, 10**6)
     assert knee_rows(tiny_config, e, "TPU v5e", 10**6) >= 1
-    assert serving_roofline(tiny_config, e, 8, "TPU v5 lite",
-                            10**6)["achievable_mfu"] > 0
-
-
-def test_bench_sweep_parse_is_forgiving():
-    """A malformed BENCH_SWEEP_ROWS env var must degrade to 'no sweep',
-    never raise: the parse runs at bench.py import time."""
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test",
-        pathlib.Path(__file__).resolve().parents[1] / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench._parse_sweep("64,128") == (64, 128)
-    assert bench._parse_sweep("") == ()
-    assert bench._parse_sweep("64;128") == ()          # wrong separator
-    assert bench._parse_sweep("64, oops,0,-3") == (64,)  # junk dropped

@@ -21,11 +21,10 @@ def _run(argv, timeout, **env):
 
 @pytest.mark.parametrize("argv", [
     ["chip_smoke.py"],
-    ["bench.py"],
     ["-m", "vilbert_multitask_tpu.serve.app"],
-], ids=["chip_smoke", "bench", "serve.app"])
+], ids=["chip_smoke", "serve.app"])
 def test_entry_points_refuse_the_cpu(argv):
-    r = _run(argv, timeout=120, BENCH_TINY="")
+    r = _run(argv, timeout=120)
     assert r.returncode != 0, r.stdout[-2000:]
     assert "'cpu'" in r.stdout + r.stderr  # the platform it found, named
     assert '"ok"' not in r.stdout and '"metric"' not in r.stdout

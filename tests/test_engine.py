@@ -391,7 +391,7 @@ def test_rows_dispatch_leaf_count_is_constant(engine, monkeypatch):
     (slab + pack) must have the SAME leaf count at bucket 1 and bucket 4 —
     3 slab tensors + 5 pack tensors, never 3×bucket image leaves. A leaf
     count that scales with bucket size is the round-5 per-dispatch
-    marshalling cost (bench.py ``manyarg_exec_ms``) creeping back in."""
+    marshalling cost creeping back in."""
     import jax
 
     counts = {}

@@ -1,5 +1,5 @@
 """Fleet observability: process identity, the shared metrics spine,
-cross-process trace stitching, and the perf ledger.
+and cross-process trace stitching.
 
 The two-OS-process tests are the contract the whole tentpole exists
 for: a REAL second python process (subprocess, its own registry and
@@ -9,7 +9,6 @@ stitched Chrome-trace timeline — and must evict it once its heartbeat
 goes stale after a SIGKILL (the crash case ``retire()`` never sees).
 """
 
-import json
 import os
 import signal
 import subprocess
@@ -26,12 +25,6 @@ from vilbert_multitask_tpu.obs.identity import (
     reset_process_identity,
 )
 from vilbert_multitask_tpu.obs.instruments import Registry
-from vilbert_multitask_tpu.obs.ledger import (
-    append_entry,
-    check,
-    key_direction,
-    read_entries,
-)
 from vilbert_multitask_tpu.obs.timeseries import TimeSeriesStore
 from vilbert_multitask_tpu.obs.trace import Tracer
 from vilbert_multitask_tpu.obs.tracestore import TraceStore
@@ -300,98 +293,6 @@ def test_fleet_flush_errors_instrument_registered():
     # app and the fleet-scope HTTP handlers share the one instrument.
     c = obs.REGISTRY.counter("vmt_fleet_flush_errors_total")
     assert c.kind == "counter"
-
-
-# ------------------------------------------------------------- perf ledger
-def test_ledger_append_read_and_direction(tmp_path):
-    path = str(tmp_path / "PERF_LEDGER.jsonl")
-    entry = append_entry("bench.p50_latency_ms", {"value": 120.0,
-                                                 "p95_ms": 180.0},
-                         path=path, config_fingerprint="abc123")
-    assert entry["metric"] == "bench.p50_latency_ms"
-    assert entry["config_fingerprint"] == "abc123"
-    got = read_entries(path)
-    assert len(got) == 1 and got[0]["p95_ms"] == 180.0
-    assert key_direction("p95_ms") == "lower"
-    assert key_direction("batch_qps") == "higher"
-    assert key_direction("knee_rows") == "higher"
-    # "_per_s" ends with "_s" too: rates must gate as throughput, not
-    # latency (a faster txn.stress run is not a regression).
-    assert key_direction("claims_per_s") == "higher"
-    assert key_direction("wall_s") == "lower"
-    assert key_direction("git_rev") is None  # meta, never gated
-
-
-def test_ledger_is_opt_in(tmp_path, monkeypatch):
-    """With VMT_PERF_LEDGER unset nothing is appended anywhere — the
-    repo-root PERF_LEDGER.jsonl is the benchmark driver's file, and no
-    test, smoke or bench run may touch it. Set, the variable names the
-    file."""
-    from vilbert_multitask_tpu.obs.ledger import default_ledger_path
-
-    monkeypatch.delenv("VMT_PERF_LEDGER", raising=False)
-    monkeypatch.chdir(tmp_path)
-    assert default_ledger_path() is None
-    entry = append_entry("m", {"value": 1.0})
-    assert entry["value"] == 1.0  # still returned for the caller's report
-    assert list(tmp_path.iterdir()) == []
-    assert read_entries() == [] and check()["verdict"] == "empty"
-
-    monkeypatch.setenv("VMT_PERF_LEDGER", str(tmp_path / "mine.jsonl"))
-    append_entry("m", {"value": 2.0})
-    assert [e["value"] for e in read_entries()] == [2.0]
-
-
-def test_ledger_check_verdicts(tmp_path):
-    path = str(tmp_path / "PERF_LEDGER.jsonl")
-    assert check(path)["verdict"] == "empty"
-    append_entry("m", {"value": 100.0}, path=path)
-    assert check(path)["verdict"] == "no-baseline"
-    for v in (101.0, 99.0, 100.0):
-        append_entry("m", {"value": v}, path=path)
-    assert check(path)["verdict"] == "pass"
-    # A 40% throughput drop against a ~100 baseline: regress.
-    append_entry("m", {"value": 60.0}, path=path)
-    result = check(path)
-    assert result["verdict"] == "regress"
-    assert result["regressions"][0]["key"] == "value"
-    # Half-written garbage lines are skipped, never fatal.
-    with open(path, "a") as f:
-        f.write('{"metric": "m", "val\n')
-    assert check(path)["verdict"] == "regress"
-
-
-def test_ledger_check_absolute_noise_floor_on_time_keys(tmp_path):
-    # Relative tolerance is meaningless near zero: a dryrun boot_s
-    # wobbling 31 ms -> 40 ms is +29% and pure scheduler noise. Time
-    # keys need an absolute floor too; a real 10x regression still gates.
-    path = str(tmp_path / "PERF_LEDGER.jsonl")
-    for v in (0.031, 0.030, 0.032):
-        append_entry("m2", {"boot_s": v}, path=path)
-    append_entry("m2", {"boot_s": 0.040}, path=path)
-    assert check(path)["verdict"] == "pass"
-    append_entry("m2", {"boot_s": 0.40}, path=path)
-    assert check(path)["verdict"] == "regress"
-
-
-def test_ledger_cli_exit_codes(tmp_path):
-    path = str(tmp_path / "PERF_LEDGER.jsonl")
-    cli = os.path.join(REPO, "scripts", "perf_ledger.py")
-
-    def run(*args):
-        return subprocess.run([sys.executable, cli, "--path", path, *args],
-                              capture_output=True, text=True, cwd=REPO)
-
-    assert run("check").returncode == 2  # empty, not tolerated
-    assert run("check", "--tolerate-empty").returncode == 0
-    for v in ("12.0", "11.5", "12.5", "12.1"):
-        assert run("append", "soak.qps", f"value={v}").returncode == 0
-    assert run("check").returncode == 0
-    assert run("append", "soak.qps", "value=4.0").returncode == 0
-    out = run("check")
-    assert out.returncode == 1
-    assert "REGRESS" in out.stderr
-    assert json.loads(out.stdout)["verdict"] == "regress"
 
 
 # -------------------------------------------- identity on the queue plane

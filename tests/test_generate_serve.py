@@ -102,6 +102,8 @@ def answered(app):
             if "result" in frame:
                 results.setdefault(frame["result"]["question"],
                                    []).append(frame["result"])
+        assert len(results) == len(prompts), (
+            f"only {sorted(results)} of {sorted(prompts)} answered in 120 s")
         time.sleep(0.5)   # a second result frame would arrive about now
         try:
             while True:

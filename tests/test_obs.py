@@ -505,6 +505,33 @@ def test_record_event_routes_to_installed_recorder(tmp_path):
     assert obs.record_spike("deadline_spike") is False
 
 
+class TestRecorderOfAWiderFixture:
+    """The thread check of ``tests/conftest.py`` blames a test for every
+    project thread born while it ran. A recorder's writer starts lazily, at
+    the first trigger, so a module's ``ServeApp`` whose sampler tick pages
+    an SLO on a loaded host gives birth to one inside whichever test is
+    running (``tests/test_generate_serve.py`` failed that way under six
+    workers). A recorder installed before the test began is its fixture's
+    to close, not the test's."""
+
+    @pytest.fixture(scope="class")
+    def installed(self, tmp_path_factory):
+        from vilbert_multitask_tpu import obs
+
+        rec = obs.install_recorder(obs.FlightRecorder(
+            str(tmp_path_factory.mktemp("postmortem")), min_interval_s=0.0))
+        yield rec
+        obs.clear_recorder()
+        rec.close()
+
+    def test_its_writer_may_start_inside_a_test(self, installed):
+        from vilbert_multitask_tpu import obs
+
+        assert obs.record_event("slo_page", slo="e2e_latency") is True
+        assert any(t.name == "flight-recorder"
+                   for t in threading.enumerate())
+
+
 def test_recorder_disabled_mode_overhead_under_5us():
     """Tier-1 guard (mirrors the tracer's): trigger sites live on prod
     paths because an uninstalled recorder costs a global read + compare."""

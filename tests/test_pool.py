@@ -379,10 +379,6 @@ class WrapEngine:
         return self._host.input_cache_stats
 
     @property
-    def stage_times(self):
-        return self._host.stage_times
-
-    @property
     def mesh(self):
         return self._host.mesh
 

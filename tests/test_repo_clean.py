@@ -1,7 +1,7 @@
 """Tier-1 gate: the repo's own code passes its own static analyzer.
 
 Runs vmtlint over the configured scan set (``[tool.vmtlint]`` in
-pyproject.toml: the library, bench.py, scripts/) and fails on any finding
+pyproject.toml: the library, scripts/, tests/) and fails on any finding
 that is not grandfathered in vmtlint_baseline.json — so a PR that
 introduces a host transfer inside jit, a jit-in-loop recompile, a
 donated-buffer reuse, or an unblocked timed dispatch fails fast CI, not

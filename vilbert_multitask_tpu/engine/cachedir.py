@@ -34,7 +34,7 @@ def default_aot_cache_dir() -> str:
 
 def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process and
-    return its directory. Idempotent; every serving/bench entry point calls
+    return its directory. Idempotent; every serving entry point calls
     it before its first compile. Every compile persists (floor 0 s): the
     small per-bucket programs dominate warmup COUNT, and JAX's 1 s default
     floor would skip them."""

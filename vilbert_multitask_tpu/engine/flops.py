@@ -162,8 +162,7 @@ def param_tree_bytes(params) -> int:
 
 def weight_bytes_per_row(param_bytes: int, batch: int) -> float:
     """HBM weight bytes amortized per batch row at ``batch`` — the number
-    bigger batches and smaller storage dtypes both shrink; emitted in the
-    bench roofline block next to ``param_bytes``."""
+    bigger batches and smaller storage dtypes both shrink."""
     return param_bytes / max(1, batch)
 
 
@@ -180,33 +179,3 @@ def knee_rows(mcfg: ViLBertConfig, ecfg: EngineConfig, device_kind: str,
     peak, bw = _peaks_for(device_kind)
     flops_per_row = serving_forward_flops(mcfg, ecfg, 1)
     return max(1, math.ceil(param_bytes * peak / (bw * flops_per_row)))
-
-
-def serving_roofline(mcfg: ViLBertConfig, ecfg: EngineConfig, batch: int,
-                     device_kind: str, param_bytes: int) -> dict:
-    """Roofline cap on serving MFU at ``batch`` rows: a forward must read
-    all ``param_bytes`` from HBM once (t_mem) and execute the analytic
-    FLOPs (t_compute); achievable_mfu = t_compute / max(t_compute, t_mem).
-
-    When that ratio is well below 1 the forward is weight-read-bound and
-    more MXU (or a measured MFU "gap") is not the story — fewer weight
-    bytes (``EngineConfig.param_dtype="bfloat16"``) or bigger batches are.
-    Returns ``{"achievable_mfu", "reason"}``; raises ``ValueError`` for a
-    device kind the peak tables do not know.
-    """
-    peak, bw = _peaks_for(device_kind)
-    flops = serving_forward_flops(mcfg, ecfg, batch)
-    t_compute = flops / peak
-    t_mem = param_bytes / bw
-    mfu = t_compute / max(t_compute, t_mem)
-    if t_mem > t_compute:
-        reason = (
-            f"weight-read-bound at batch {batch}: {param_bytes / 1e6:.0f} MB "
-            f"params / {bw / 1e9:.0f} GB/s = {t_mem * 1e3:.2f} ms HBM vs "
-            f"{t_compute * 1e3:.2f} ms compute — MFU caps at {mfu:.3f}")
-    else:
-        reason = (
-            f"compute-bound at batch {batch}: {t_compute * 1e3:.2f} ms "
-            f"compute vs {t_mem * 1e3:.2f} ms weight reads — MFU can "
-            f"approach 1.0")
-    return {"achievable_mfu": round(mfu, 4), "reason": reason}

@@ -136,7 +136,6 @@ class GenerateEngine:
         self.replica_id = replica_id
         self.killed = False
         self.boot_times: Dict[str, float] = {}
-        self.stage_times: Dict[str, float] = {}
         self._boot_lock = threading.Lock()
         page, scan = gen.page_size, model_lib.gated_delta.CHUNK
         for b in gen.prefill_buckets:

@@ -334,7 +334,6 @@ class InferenceEngine:
         # keyed ('batched'|'rows', bucket, collect_attention) — see
         # _forward / _forward_rows
         self._compiled: Dict[Tuple[str, int, bool], callable] = {}
-        self.stage_times: Dict[str, float] = {}
         # Guards the _compiled dict itself (parallel warmup threads race
         # check-then-insert in the builders).
         self._compile_lock = threading.Lock()
@@ -932,7 +931,6 @@ class InferenceEngine:
             raise RuntimeError("prepare_from_store() needs a FeatureStore; "
                                "use prepare() with in-memory regions instead")
         fetch = getattr(self.feature_store, "fetch", None)
-        t_fetch = time.perf_counter()
         with obs.span("engine.features", n_images=len(image_paths),
                       task_id=task_id):
             if fetch is not None:
@@ -942,14 +940,8 @@ class InferenceEngine:
             else:
                 regions = self.feature_store.get_batch(image_paths)
                 cache_keys = None
-        fetch_s = time.perf_counter() - t_fetch
-        req = self.prepare(task_id, question, regions, image_paths,
-                           cache_keys=cache_keys)
-        # prepare() booked the host-side region encode; the store read
-        # belongs to the same "features" stage.
-        self.stage_times["features_s"] = (
-            self.stage_times.get("features_s", 0.0) + fetch_s)
-        return req
+        return self.prepare(task_id, question, regions, image_paths,
+                            cache_keys=cache_keys)
 
     @property
     def transfer_dtype(self) -> np.dtype:
@@ -989,14 +981,10 @@ class InferenceEngine:
         ecfg = self.cfg.engine
         bucket = n if n == 1 else ecfg.bucket_for(n)
 
-        t_tok = time.perf_counter()
         with obs.span("engine.tokenize", task_id=task_id):
             text = encode_question(
                 self.tokenizer, question, ecfg.max_text_len, task_id=task_id,
-                lowercase=self.cfg.serving.lowercase_questions,
             ).stack(bucket)
-        self.stage_times["tokenize_s"] = time.perf_counter() - t_tok
-        t_feat = time.perf_counter()
         with obs.span("engine.encode", n_images=n, task_id=task_id):
             # Feature files are confidence-ordered (extractor top-K order,
             # same as the reference's .npy dumps), so an over-provisioned
@@ -1006,7 +994,6 @@ class InferenceEngine:
             encoded = [encode_image(r, ecfg.max_regions) for r in regions]
             feats, spatials, image_mask = batch_images(encoded, pad_to=bucket)
             feats = feats.astype(self.transfer_dtype, copy=False)
-        self.stage_times["features_s"] = time.perf_counter() - t_feat
         task_ids = np.full((bucket, 1), task_id, np.int32)
         if cache_keys is not None:
             if len(cache_keys) != n:
@@ -1249,10 +1236,9 @@ class InferenceEngine:
             input_ids=req.text.input_ids, segment_ids=req.text.segment_ids,
             input_mask=req.text.input_mask, task_ids=req.task_ids,
         )
-        t0 = time.perf_counter()
         # The forward span closes only after the blocking device_get below —
         # jax dispatch is async, so fencing on the fetch is what makes the
-        # span (and forward_s) measure device time instead of enqueue time.
+        # span measure device time instead of enqueue time.
         with obs.span("engine.forward", bucket=req.bucket,
                       task_id=req.spec.task_id,
                       replica=self.replica_id or ""):
@@ -1273,15 +1259,12 @@ class InferenceEngine:
                 out, bundle = self._run_rows(
                     req.bucket, collect_attention, text,
                     self._request_rows(req))
-            # One blocking fetch of the few-KB decode bundle — forward_s
+            # One blocking fetch of the few-KB decode bundle: the span
             # includes the single device→host round trip; decode is then
             # pure host math.
             bundle = jax.device_get(bundle)
-        self.stage_times["forward_s"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
         with obs.span("engine.decode", task_id=req.spec.task_id):
             result = self.decode(req, bundle)
-        self.stage_times["decode_s"] = time.perf_counter() - t0
         return out, result
 
     def run_many(
@@ -1334,18 +1317,14 @@ class InferenceEngine:
         ]
         out: List[Optional[dec.TaskResult]] = [None] * len(reqs)
         pending: deque = deque()
-        dec_s = 0.0
-        t0 = time.perf_counter()
 
         def _drain_one() -> None:
-            nonlocal dec_s
             c, bundle = pending.popleft()
             # The fetch is where the host waits for the device: the
             # dispatch returned as soon as the program was enqueued.
             with obs.span("engine.result_wait",
                           rows=sum(r.n_images for _, r in c)):
                 bundle = jax.device_get(bundle)
-            td = time.perf_counter()
             with obs.span("engine.decode", n_requests=len(c)):
                 row = 0
                 for pos, r in c:
@@ -1353,7 +1332,6 @@ class InferenceEngine:
                     row += r.n_images
                     if on_result is not None:
                         on_result(pos, out[pos])
-            dec_s += time.perf_counter() - td
 
         with obs.span("engine.run_many", replica=self.replica_id or "",
                       n_requests=len(reqs),
@@ -1375,10 +1353,6 @@ class InferenceEngine:
                     _drain_one()
             while pending:
                 _drain_one()
-        # forward_s = dispatch + device + fetch wall time; host decode is
-        # booked separately (same split as run()).
-        self.stage_times["forward_s"] = time.perf_counter() - t0 - dec_s
-        self.stage_times["decode_s"] = dec_s
         return out
 
     # At most this many chunks in flight (inputs + un-fetched bundles in
@@ -1394,8 +1368,7 @@ class InferenceEngine:
         10-row retrieval cap on the image buckets doesn't bound a packed
         chunk; a 32-row chunk keeps the MXU fed instead of paying a
         dispatch round trip per 10 rows. ``chunk_rows`` overrides for
-        callers tuning backlog shape (and the bench's 10-vs-32
-        comparison); it must fit a compiled bucket.
+        callers tuning backlog shape; it must fit a compiled bucket.
 
         Mixed image counts SHARE chunks (round 5; the per-count grouping
         before it paid one partial chunk per count — a ragged
@@ -1410,11 +1383,6 @@ class InferenceEngine:
           one of those pairs (decode reads pair row offset//2). Sums of
           even numbers are even, so ordering evens first guarantees it
           without knowing task ids.
-
-        This is the ONE copy of the packing arithmetic: run_many executes
-        it and the bench's padded-row FLOP accounting consumes it
-        (:meth:`padded_rows`), so a change here cannot silently skew the
-        reported TFLOP/s (ADVICE r4 #4).
         """
         max_bucket = (chunk_rows if chunk_rows is not None
                       else self.cfg.engine.max_batch_rows())
@@ -1440,16 +1408,6 @@ class InferenceEngine:
         if cur:
             chunks.append(cur)
         return chunks
-
-    def padded_rows(self, image_counts: Sequence[int], *,
-                    chunk_rows: Optional[int] = None) -> int:
-        """Total device rows a run_many over these requests dispatches,
-        INCLUDING bucket padding — the denominator-side work term for
-        throughput/TFLOP accounting."""
-        counts = list(image_counts)
-        return sum(
-            self.cfg.engine.row_bucket_for(sum(counts[i] for i in chunk))
-            for chunk in self.chunk_plan(counts, chunk_rows=chunk_rows))
 
     def _dispatch_many(self, reqs: Sequence[PreparedRequest]):
         """Pack one ≤max-bucket chunk and dispatch its forward; returns the

@@ -6,7 +6,7 @@ Three pillars (see ARCHITECTURE.md "Observability"):
   with thread-local parenting and cross-queue trace-id resumption
   (:mod:`vilbert_multitask_tpu.obs.trace`);
 - ``obs.REGISTRY`` — counters / gauges / log-bucket histograms, plus the
-  one shared :func:`percentile` used by serve, bench, and the soak
+  one shared :func:`percentile` used by serve and the soak
   (:mod:`vilbert_multitask_tpu.obs.instruments`);
 - Prometheus text exposition, Chrome-trace JSON, and ``jax.profiler``
   toggles (:mod:`vilbert_multitask_tpu.obs.export`).
@@ -99,12 +99,6 @@ from vilbert_multitask_tpu.obs.fleet import (
     FleetSpine,
     default_spine_path,
 )
-from vilbert_multitask_tpu.obs.ledger import (
-    append_entry as ledger_append,
-    check as ledger_check,
-    default_ledger_path,
-    read_entries as ledger_entries,
-)
 
 __all__ = [
     "Span", "Tracer", "current_trace_id", "default_tracer", "new_trace_id",
@@ -136,8 +130,6 @@ __all__ = [
     "WorkerIdentity", "mint_identity", "process_identity",
     "reset_process_identity",
     "FleetSpine", "default_spine_path",
-    "ledger_append", "ledger_check", "ledger_entries",
-    "default_ledger_path",
 ]
 
 SPAN_HISTOGRAM = REGISTRY.histogram(

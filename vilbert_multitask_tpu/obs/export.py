@@ -8,7 +8,7 @@ the ``jax.profiler`` toggles behind ``POST /debug/profile/{start,stop}``.
 - :func:`chrome_trace` renders a tracer's span ring as a Chrome-trace /
   Perfetto JSON document (``ph: "X"`` complete events, µs timestamps,
   thread-name metadata) — ``GET /debug/trace`` serves it, and
-  :func:`dump_trace` writes it to a file for bench/smoke artifacts. Open
+  :func:`dump_trace` writes it to a file for smoke artifacts. Open
   at https://ui.perfetto.dev (drag the file in) or chrome://tracing.
 - :func:`start_profile`/:func:`stop_profile` wrap the existing device
   trace toggles (serve/metrics.py → ``jax.profiler``) with idempotence

@@ -2,15 +2,15 @@
 
 Before this package, percentile math lived in three places with three
 semantics: ``serve/metrics.py`` (upward-biased nearest-rank — p50 of two
-samples returned the max), ``bench.py`` (``statistics.median`` + manual
-ceil nearest-rank p95), and ``scripts/serve_soak.py`` (a third variant).
-:func:`percentile` below is now the only one; ``Metrics``, the bench, and
-the soak all route through it (linear interpolation — exact median, no
-off-by-one bias).
+samples returned the max), a ``statistics.median`` + manual ceil
+nearest-rank p95, and ``scripts/serve_soak.py`` (a third variant).
+:func:`percentile` below is now the only one; ``Metrics`` and the soak
+route through it (linear interpolation — exact median, no off-by-one
+bias).
 
 The :class:`Histogram` keeps fixed log-spaced buckets (Prometheus
 exposition needs cumulative bucket counts) *and* a bounded reservoir of
-raw samples (exact percentiles for JSON snapshots and bench artifacts) —
+raw samples (exact percentiles for JSON snapshots) —
 "replacing/augmenting the reservoir" per the round-6 telemetry design.
 """
 

@@ -14,7 +14,7 @@ verdict     every non-``ok`` terminal — dead_letter, deadline, error,
             requeued, failover (breaker-touched) — kept 100%
 slow        completion-time top-K slowest ``ok`` jobs per task
 pinned      SLO page offenders force-kept by trace id
-sampled     p-sampled ``ok`` normals (``tracestore_sample_rate``)
+sampled     p-sampled ``ok`` normals (``sample_rate``)
 =========== =========================================================
 
 Reads NEVER filter by peer liveness: a SIGKILL'd worker's heartbeat

@@ -46,6 +46,18 @@ _AUTOSCALE_TICK_ERRORS = obs.REGISTRY.counter(
     "vmt_autoscale_tick_errors_total",
     "Sampler ticks whose autoscale control step raised (tick unaffected).")
 
+# Graceful drain: how long stop() waits for the worker to finish the jobs
+# in hand before it releases them back to the queue.
+DRAIN_GRACE_S = 10.0
+# The serving plane's SLO targets: the allowed share of failed terminals
+# (fleet-wide and per replica), the end-to-end latency objective, and the
+# deadline slack a job must still have when it reaches the engine. The
+# latency and slack budgets are ``obs.latency_slo``'s and
+# ``obs.slack_floor_slo``'s defaults.
+SLO_AVAILABILITY_BUDGET = 0.02
+SLO_E2E_TARGET_MS = 2000.0
+SLO_SLACK_FLOOR_MS = 1000.0
+
 
 class ServeApp:
     def __init__(self, cfg: Optional[FrameworkConfig] = None, *,
@@ -83,6 +95,9 @@ class ServeApp:
                     self.cfg.engine, aot_cache_dir=aot_dir))
         self.extractor = None  # set when live_extract builds a detector
         self.hub = PushHub()
+        # Two poison bounds, both kept: the first counts *charged* attempts
+        # (claims less releases), the second every claim, so that a job a
+        # drain or a failover releases uncharged still ends (queue.py).
         self.queue = DurableQueue(
             s.queue_db_path, queue_name=s.queue_name,
             max_delivery_attempts=s.max_delivery_attempts,
@@ -235,28 +250,17 @@ class ServeApp:
         # story). Keyed on (task, image identity, canonical question,
         # fingerprint:generation) — a rolling swap bumps model_gen so every
         # pre-swap entry turns stale atomically. Coalescing rides the cache
-        # (followers attach to the leader's cache row), so coalesce without
-        # the cache is unsupported by construction.
+        # (followers attach to the leader's cache row).
         self.model_gen = 0
-        self.cache: Optional[ResultCache] = None
-        if s.result_cache_enabled:
-            self.cache = ResultCache(
-                s.queue_db_path,
-                fingerprint=self._cache_fingerprint(),
-                max_rows=s.result_cache_max_rows,
-                ttl_s=s.result_cache_ttl_s,
-                lease_s=s.coalesce_lease_s)
+        self.cache = ResultCache(
+            s.queue_db_path, fingerprint=self._cache_fingerprint())
         self.worker = ServeWorker(self.engine, self.queue, self.store,
                                   self.hub, s, cache=self.cache)
         # Live-health plane (obs/): the time-series store + sampler, the
         # SLO evaluator, and the flight recorder. Built here so /debug/slo
         # and /healthz see them from the first request; the sampler thread
         # and the recorder's global installation happen in start().
-        # Placeholders so _build_slos's page hook can close over them; the
-        # real instances are built after the fleet spine (shared db path).
-        self.attrib: Optional[obs.CostAttributor] = None
-        self.tracestore: Optional[obs.TraceStore] = None
-        self.timeseries = obs.TimeSeriesStore(points=s.timeseries_points)
+        self.timeseries = obs.TimeSeriesStore()
         self.slos = self._build_slos()
         self.sampler = obs.Sampler(self.timeseries, self._sample,
                                    cadence_s=s.sampler_cadence_s)
@@ -274,28 +278,18 @@ class ServeApp:
         # Every sampler tick flushes instruments/timeseries/spans/heartbeat
         # there; ?scope=fleet queries on any peer merge them back.
         self.identity = obs.process_identity("serve")
-        self.fleet: Optional[obs.FleetSpine] = None
-        if s.fleet_enabled:
-            self.fleet = obs.FleetSpine(
-                s.fleet_db_path or obs.default_spine_path(s.queue_db_path),
-                self.identity,
-                heartbeat_stale_s=s.fleet_heartbeat_stale_s,
-                max_spans_per_ident=s.fleet_max_spans,
-                spans_per_flush=s.fleet_spans_per_flush,
-                timeseries_window_s=s.fleet_timeseries_window_s,
-                timeseries=self.timeseries)
+        spine_path = (s.fleet_db_path
+                      or obs.default_spine_path(s.queue_db_path))
+        self.fleet = obs.FleetSpine(
+            spine_path, self.identity,
+            heartbeat_stale_s=s.fleet_heartbeat_stale_s,
+            timeseries=self.timeseries)
         # Cost-attribution plane: per-job stage/device-second records
         # (obs/attrib.py) feeding the durable tail-sampled trace store
         # (obs/tracestore.py) on the SAME sqlite file as the fleet spine —
         # one db to mount, and ?scope=fleet trace reads come for free.
-        if s.attrib_enabled:
-            self.tracestore = obs.TraceStore(
-                s.fleet_db_path or obs.default_spine_path(s.queue_db_path),
-                self.identity.ident,
-                keep_top_k=s.tracestore_keep_top_k,
-                sample_rate=s.tracestore_sample_rate,
-                retention_s=s.tracestore_retention_s)
-            self.attrib = obs.CostAttributor(on_finish=self._offer_trace)
+        self.tracestore = obs.TraceStore(spine_path, self.identity.ident)
+        self.attrib = obs.CostAttributor(on_finish=self._offer_trace)
         rec_dir = s.recorder_dir
         if rec_dir == "serve_state/postmortem":
             # Default follows the queue db (tests and the soak point that
@@ -305,15 +299,13 @@ class ServeApp:
                 "postmortem")
         self.recorder = obs.FlightRecorder(
             rec_dir, max_bundles=s.recorder_max_bundles,
-            max_bytes=s.recorder_max_bytes, spans=s.recorder_spans,
             min_interval_s=s.recorder_min_interval_s,
             sources={
                 "timeseries": self.timeseries.snapshot,
                 "config_fingerprint": lambda: self.fingerprint,
                 "boot_info": lambda: dict(self.boot_info),
                 "identity": self.identity.as_dict,
-                "fleet": lambda: (self.fleet.snapshot()
-                                  if self.fleet is not None else {}),
+                "fleet": self.fleet.snapshot,
             })
         self.api = ApiServer(
             self.queue, self.store, self.hub, s,
@@ -346,21 +338,20 @@ class ServeApp:
 
     # ------------------------------------------------------- live health
     def _build_slos(self) -> "obs.SloEvaluator":
-        """The serving plane's three SLOs (targets in ServingConfig):
-        availability, e2e latency vs. target, deadline-slack floor."""
+        """The serving plane's three SLOs (targets at the top of this
+        module): availability, e2e latency vs. target, deadline-slack
+        floor."""
         s = self.cfg.serving
         m = self.worker.metrics
         slos = [
             obs.availability_slo(
                 "availability", m.latency, m.failure_events,
-                error_budget=s.slo_availability_budget),
+                error_budget=SLO_AVAILABILITY_BUDGET),
             obs.latency_slo(
-                "e2e_latency", m.latency, target_ms=s.slo_e2e_target_ms,
-                error_budget=s.slo_e2e_budget),
+                "e2e_latency", m.latency, target_ms=SLO_E2E_TARGET_MS),
             obs.slack_floor_slo(
                 "deadline_slack", obs.DEADLINE_SLACK,
-                floor_ms=s.slo_slack_floor_ms,
-                error_budget=s.slo_slack_budget),
+                floor_ms=SLO_SLACK_FLOOR_MS),
         ]
         # One availability objective PER REPLICA, fed by the pool's
         # labelled dispatch histograms: a single sick replica burns its
@@ -374,28 +365,22 @@ class ServeApp:
             slos.append(obs.Slo(
                 f"replica_{rep.name}_availability",
                 f"dispatches on replica {rep.name} succeed", counts,
-                error_budget=s.slo_availability_budget))
+                error_budget=SLO_AVAILABILITY_BUDGET))
         def on_page(name: str, report: dict) -> None:
             # Default recorder trigger, plus: the page's exemplar traces
             # get pinned so the store force-keeps their next offers even
             # when the tail sampler would have dropped them.
             obs.SloEvaluator._page_event(name, report)
-            if self.tracestore is not None:
-                self.tracestore.pin(report.get("exemplar_trace_ids", []))
+            self.tracestore.pin(report.get("exemplar_trace_ids", []))
         return obs.SloEvaluator(
             slos, fast_window_s=s.slo_fast_window_s,
-            slow_window_s=s.slo_slow_window_s,
-            warn_burn=s.slo_warn_burn, page_burn=s.slo_page_burn,
-            on_page=on_page)
+            slow_window_s=s.slo_slow_window_s, on_page=on_page)
 
     def _offer_trace(self, cost: "obs.JobCost") -> None:
         """Attributor → store handoff (runs on the finishing worker
         thread, outside the attributor lock): the completed cost record
         plus its spans still in the local tracer ring."""
-        store = self.tracestore
-        if store is None:
-            return
-        store.offer(cost, obs.default_tracer().spans())
+        self.tracestore.offer(cost, obs.default_tracer().spans())
 
     def _sample(self) -> dict:
         """One sampler tick's worth of live signals. ``*_total`` keys get
@@ -417,20 +402,19 @@ class ServeApp:
         # for every guarded loop, so a crash-guarded death (or a silent
         # one) is visible in /healthz within one sampler cadence.
         vals.update(obs.watchdog().probe())
-        # Scheduler plane (empty dict while the legacy loop runs): ready
+        # Scheduler plane (empty dict outside run_forever): ready
         # depth, adaptive window, and *_total dispatch counters.
         vals.update(self.worker.scheduler_stats())
         # Result-cache plane: row/follower depths plus the three cache
         # counters (the sampler derives hit/miss/coalesce rates from the
         # *_total keys — the zipf soak's gates read those).
-        if self.cache is not None:
-            vals.update(self.cache.stats())
-            vals["result_cache_hits_total"] = sum(
-                obs.RESULT_CACHE_HITS.collect().values())
-            vals["result_cache_misses_total"] = sum(
-                obs.RESULT_CACHE_MISSES.collect().values())
-            vals["coalesced_submits_total"] = sum(
-                obs.COALESCED_SUBMITS.collect().values())
+        vals.update(self.cache.stats())
+        vals["result_cache_hits_total"] = sum(
+            obs.RESULT_CACHE_HITS.collect().values())
+        vals["result_cache_misses_total"] = sum(
+            obs.RESULT_CACHE_MISSES.collect().values())
+        vals["coalesced_submits_total"] = sum(
+            obs.COALESCED_SUBMITS.collect().values())
         # Per-tenant queueing delay (publish→claim p50), the deficit
         # scheduler's user-facing effect: a tenant throttled below its
         # weighted share queues longer, and that shows up HERE before it
@@ -461,18 +445,16 @@ class ServeApp:
         # Publish this tick to the fleet spine (heartbeat + instrument
         # snapshots + timeseries deltas + fresh spans). Isolated failure
         # domain: a locked/corrupt spine db must not cost the LOCAL tick.
-        if self.fleet is not None:
-            try:
-                self.fleet.flush({"phase": self.boot_info.get("phase"),
-                                  "slo_worst": worst})
-            except Exception:  # noqa: BLE001
-                _FLEET_FLUSH_ERRORS.inc()
+        try:
+            self.fleet.flush({"phase": self.boot_info.get("phase"),
+                              "slo_worst": worst})
+        except Exception:  # noqa: BLE001
+            _FLEET_FLUSH_ERRORS.inc()
         # Trace-store flush rides the same tick, isolated the same way.
-        if self.tracestore is not None:
-            try:
-                self.tracestore.flush()
-            except Exception:  # noqa: BLE001
-                _TRACESTORE_FLUSH_ERRORS.inc()
+        try:
+            self.tracestore.flush()
+        except Exception:  # noqa: BLE001
+            _TRACESTORE_FLUSH_ERRORS.inc()
         return vals
 
     def warm(self) -> None:
@@ -537,10 +519,9 @@ class ServeApp:
         # never a stale hit. In-flight leaders keep their follower rows —
         # their old-generation result still fans out, it just isn't cached.
         self.model_gen += 1
-        if self.cache is not None:
-            dropped = self.cache.invalidate(self._cache_fingerprint())
-            obs.RESULT_CACHE_INVALIDATIONS.inc(dropped)
-            report["cache_invalidated"] = dropped
+        dropped = self.cache.invalidate(self._cache_fingerprint())
+        obs.RESULT_CACHE_INVALIDATIONS.inc(dropped)
+        report["cache_invalidated"] = dropped
         self.boot_info["last_swap"] = report
         return report
 
@@ -590,8 +571,7 @@ class ServeApp:
         obs.install_recorder(self.recorder)
         # Same discipline for cost attribution: the module-plane helper
         # sites in worker/scheduler become live before the first claim.
-        if self.attrib is not None:
-            obs.set_attributor(self.attrib)
+        obs.set_attributor(self.attrib)
         # Websocket first: /config must never advertise an unbound ws port
         # (the browser caches it and would reconnect to ws://host:0 forever).
         self.ws.start()
@@ -609,15 +589,14 @@ class ServeApp:
         self.boot_info["phase"] = "ready"
         # First heartbeat immediately: peers must see this process in
         # ?scope=fleet without waiting out a sampler cadence.
-        if self.fleet is not None:
-            try:
-                self.fleet.flush({"phase": "ready"})
-            except Exception:  # noqa: BLE001
-                _FLEET_FLUSH_ERRORS.inc()
+        try:
+            self.fleet.flush({"phase": "ready"})
+        except Exception:  # noqa: BLE001
+            _FLEET_FLUSH_ERRORS.inc()
 
     def stop(self) -> None:
         """Graceful drain: signal the worker to stop CLAIMING, give it
-        ``drain_grace_s`` to finish jobs in hand, then release anything
+        ``DRAIN_GRACE_S`` to finish jobs in hand, then release anything
         still claimed back to pending (terminal "requeued" push, no
         delivery attempt charged) before tearing the web tiers down."""
         # Snapshot the pre-drain state while the queues/inflight are still
@@ -627,7 +606,7 @@ class ServeApp:
         self.boot_info["phase"] = "draining"
         self._stop.set()
         if self._worker_thread:
-            self._worker_thread.join(timeout=self.cfg.serving.drain_grace_s)
+            self._worker_thread.join(timeout=DRAIN_GRACE_S)
         # After the join (clean or timed out): anything still tracked as
         # in-flight goes back to the queue for the next worker. A clean
         # drain finds the set empty — at-least-once makes this idempotent.
@@ -639,20 +618,18 @@ class ServeApp:
         # spans stay stitchable) and un-stamp the process-global registry
         # and tracer — other apps in this process must not inherit a dead
         # incarnation's identity labels.
-        if self.fleet is not None:
-            try:
-                self.fleet.retire()
-            except Exception:  # noqa: BLE001 — teardown is best-effort
-                _FLEET_FLUSH_ERRORS.inc()
+        try:
+            self.fleet.retire()
+        except Exception:  # noqa: BLE001 — teardown is best-effort
+            _FLEET_FLUSH_ERRORS.inc()
         # Final trace-store flush (keeps buffered since the last tick must
         # survive the shutdown), then detach the module-plane attributor —
         # but only OUR OWN installation, like the recorder below.
-        if self.tracestore is not None:
-            try:
-                self.tracestore.flush()
-            except Exception:  # noqa: BLE001 — teardown is best-effort
-                _TRACESTORE_FLUSH_ERRORS.inc()
-        if self.attrib is not None and obs.get_attributor() is self.attrib:
+        try:
+            self.tracestore.flush()
+        except Exception:  # noqa: BLE001 — teardown is best-effort
+            _TRACESTORE_FLUSH_ERRORS.inc()
+        if obs.get_attributor() is self.attrib:
             obs.set_attributor(None)
         obs.REGISTRY.set_default_labels()
         obs.default_tracer().set_default_attrs()
@@ -710,7 +687,7 @@ def main(argv=None) -> None:
     print(f"http://{s.http_host}:{app.http_port}  "
           f"ws://{s.http_host}:{app.ws.bound_port}  queue={s.queue_db_path}")
     # Graceful drain on SIGTERM (the orchestrator's stop signal): stop
-    # claiming, finish in-flight within drain_grace_s, release the rest
+    # claiming, finish in-flight within DRAIN_GRACE_S, release the rest
     # with a terminal push, exit 0. Ctrl-C takes the same path.
     import signal
 
@@ -720,7 +697,7 @@ def main(argv=None) -> None:
         stop.wait()
     except KeyboardInterrupt:
         pass
-    print(f"draining (grace {s.drain_grace_s:.0f}s)...")
+    print(f"draining (grace {DRAIN_GRACE_S:.0f}s)...")
     app.stop()
 
 

@@ -39,6 +39,13 @@ from vilbert_multitask_tpu.serve.push import PushHub, log_to_terminal
 from vilbert_multitask_tpu.serve.queue import DurableQueue, make_job_message
 from vilbert_multitask_tpu.serve.resultcache import ResultCache, cache_key
 
+# Files one POST /upload_image keeps (demo_images.html:92-95 caps the same).
+MAX_UPLOAD_IMAGES = 10
+# Time budget minted at POST / and carried in the job body; the worker and
+# engine end an expired job with a terminal push instead of a forward. A
+# per-request "deadline_s" overrides it, null turns the deadline off.
+DEFAULT_DEADLINE_S = 300.0
+
 
 class ApiServer:
     def __init__(
@@ -117,7 +124,6 @@ class ApiServer:
         # 429 + Retry-After instead of joining a backlog they'd time out in.
         self.admission = AdmissionController(
             max_queue_depth=self.serving.admission_max_queue_depth,
-            max_queue_age_s=self.serving.admission_max_queue_age_s,
             retry_after_s=self.serving.admission_retry_after_s,
         )
         self._httpd: Optional[ThreadingHTTPServer] = None
@@ -156,7 +162,7 @@ class ApiServer:
                 "retry_after_s": decision.retry_after_s,
             }
         try:
-            budget = payload.get("deadline_s", self.serving.default_deadline_s)
+            budget = payload.get("deadline_s", DEFAULT_DEADLINE_S)
             budget = None if budget is None else float(budget)
         except (TypeError, ValueError):
             return 400, {"error": "deadline_s must be a number"}
@@ -175,8 +181,7 @@ class ApiServer:
             spec.validate_num_images(len(images))
         except ValueError as e:
             return 400, {"error": str(e)}
-        if self.serving.lowercase_questions:
-            question = question.lower()  # reference views.py:27
+        question = question.lower()  # reference views.py:27
         log_to_terminal(self.hub, socket_id,
                         {"info": f"Starting {spec.name} job..."})
         collect = payload.get("collect_attention", False)
@@ -198,7 +203,7 @@ class ApiServer:
             with obs.span("cache.admit") as csp:
                 verdict_c, value = self.cache.admit(
                     key, socket_id=socket_id, trace_id=trace_id,
-                    tenant=tenant, coalesce=self.serving.coalesce_enabled)
+                    tenant=tenant)
                 csp.set(verdict=verdict_c)
             if verdict_c == "hit":
                 return self._serve_cache_hit(spec, socket_id, trace_id,
@@ -581,7 +586,7 @@ class ApiServer:
                         "ws_port": api.ws_port,
                         "socket_id": str(uuid.uuid4()),
                         "tasks": api.store.list_tasks(),
-                        "max_upload_images": api.serving.max_upload_images,
+                        "max_upload_images": MAX_UPLOAD_IMAGES,
                         "live_extract": bool(
                             api.boot_info.get("live_extract")),
                     })
@@ -1152,8 +1157,8 @@ class ApiServer:
                         name = part.get_filename()
                         if not name:
                             continue
-                        if len(paths) >= api.serving.max_upload_images:
-                            break  # reference caps uploads (demo_images.html:92-95)
+                        if len(paths) >= MAX_UPLOAD_IMAGES:
+                            break
                         paths.append(api.save_upload(
                             name, part.get_payload(decode=True) or b""))
                     sp.set(n_files=len(paths))

@@ -187,10 +187,6 @@ class ReplicaPool:
         return bool(getattr(self._host, "pallas_enabled", False))
 
     @property
-    def stage_times(self):
-        return self._host.stage_times
-
-    @property
     def generates(self) -> bool:
         """Whether the pool serves the generate task (one
         engine/generate.py engine) instead of the ViLBERT tasks."""

@@ -210,16 +210,7 @@ def build_remote_worker(base_url: str, *, cfg=None, engine=None,
     from vilbert_multitask_tpu.serve.worker import ServeWorker
 
     cfg = cfg or FrameworkConfig()
-    s = cfg.serving
-    client = WorkerApiClient(
-        base_url, token=token,
-        retry=RetryPolicy(max_attempts=s.retry_max_attempts,
-                          base_delay_s=s.retry_base_delay_s,
-                          max_delay_s=s.retry_max_delay_s),
-        breaker=CircuitBreaker(name="remote.transport",
-                               failure_threshold=s.breaker_failure_threshold,
-                               window_s=s.breaker_window_s,
-                               reset_timeout_s=s.breaker_reset_timeout_s))
+    client = WorkerApiClient(base_url, token=token)
     if engine is None:
         params = None
         if checkpoint_path is not None:

@@ -58,10 +58,8 @@ def _image_identity(path: str) -> str:
 
 
 def canonical_question(question: str) -> str:
-    """Whitespace-canonical text: strip + collapse runs. Lowercasing is
-    an upstream serving policy (ServingConfig.lowercase_questions) and
-    happens before the key is derived, so both spellings of the policy
-    cache consistently."""
+    """Whitespace-canonical text: strip + collapse runs. The door lowercases
+    the question before the key is derived."""
     return " ".join(question.split())
 
 
@@ -166,7 +164,7 @@ class ResultCache:
           from a dead leader) and must publish the one real job, then
           :meth:`set_leader`.
 
-        ``coalesce=False`` (ServingConfig.coalesce_enabled off) turns
+        ``coalesce=False`` turns
         the attach branch into a plain lead: the duplicate publishes its
         own job, the shared ``'done'`` write-through stays last-wins.
 

@@ -18,7 +18,7 @@ Three rules govern the dispatch stage:
 
 - **window**: fire when a bucket fills, when the oldest ready job has
   lingered a full window, or when any member's deadline slack drops under
-  ``sched_near_deadline_ms``. The window adapts AIMD-style — a full batch
+  ``NEAR_DEADLINE_S``. The window adapts AIMD-style — a full batch
   doubles it (backlog: linger to pack more), a partial batch halves it
   (idle: fire immediately) — between ``sched_window_min_s`` and
   ``sched_window_max_s``.
@@ -51,6 +51,16 @@ from vilbert_multitask_tpu import obs
 from vilbert_multitask_tpu.serve.pool import NoReadyReplica
 from vilbert_multitask_tpu.serve.push import log_to_terminal
 from vilbert_multitask_tpu.serve.queue import Job
+
+# Intake pool width: threads claiming jobs and running feature I/O + prep
+# concurrently with the device forward.
+INTAKE_THREADS = 4
+# A ready member whose deadline slack drops below this fires the batch at
+# once (the EDF front of the queue must not wait out the window).
+NEAR_DEADLINE_S = 0.25
+# Bound on completed-but-unpersisted results queued to the completion
+# stage (persist/push backpressure on the dispatch thread).
+COMPLETION_DEPTH = 128
 
 
 class ReadyItem:
@@ -211,7 +221,7 @@ class ContinuousScheduler:
     """The three-stage data plane around one :class:`ServeWorker`.
 
     ``run()`` owns the dispatch loop in the calling thread (the serve
-    worker thread), spawns ``sched_intake_threads`` intake threads and one
+    worker thread), spawns ``INTAKE_THREADS`` intake threads and one
     completion thread, and tears all of them down on ``stop_event``:
     intake stops claiming first, in-hand ready jobs release back to
     pending (no attempt charged), the completion queue drains, and only
@@ -240,16 +250,12 @@ class ContinuousScheduler:
         # the persistent tenant→credit map, the configured weights, and
         # a per-tenant queue-wait EWMA for the sampler. All guarded by
         # _cond like the rest of the scheduler state.
-        self._fairness = bool(
-            getattr(self.serving, "tenant_fairness_enabled", False))
         self._weights = dict(
             getattr(self.serving, "tenant_weights", None) or {})
-        self._default_weight = float(
-            getattr(self.serving, "tenant_default_weight", 1.0))
         self._deficits: dict = {}
         self._tenant_wait_ms: dict = {}
         self._completions: stdlib_queue.Queue = stdlib_queue.Queue(
-            maxsize=self.serving.sched_completion_depth)
+            maxsize=COMPLETION_DEPTH)
         # The intake's timed claim, one thread at a time: when the next one
         # is due (time.monotonic), poll_interval_s after the last claim any
         # intake thread began. Idle threads all wake at that moment, the
@@ -410,7 +416,7 @@ class ContinuousScheduler:
                     nearest_expiry=min(i.expiry() for i in self._ready),
                     max_rows=max_rows,
                     window_s=self._window_s,
-                    near_deadline_s=self.serving.sched_near_deadline_ms / 1e3,
+                    near_deadline_s=NEAR_DEADLINE_S,
                 )
                 if not fire:
                     self._cond.wait(min(wait_s, self.poll_interval_s))
@@ -419,17 +425,14 @@ class ContinuousScheduler:
                 obs.SCHED_READY_JOBS.observe(len(self._ready))
                 batch, expired, rest = select_batch(
                     self._ready, now, max_rows,
-                    deficits=self._deficits if self._fairness else None,
-                    weights=self._weights,
-                    default_weight=self._default_weight)
+                    deficits=self._deficits, weights=self._weights)
                 # Slice-assign keeps the one list object (and is the
                 # truncation idiom VMT115 audits in this plane).
                 self._ready[:] = rest
-                if self._fairness:
-                    # In-memory gauge set — non-blocking, fine under
-                    # _cond (VMT116 audits blocking calls only).
-                    for t, credit in self._deficits.items():
-                        obs.TENANT_DEFICIT.set(credit, tenant=t)
+                # In-memory gauge set — non-blocking, fine under
+                # _cond (VMT116 audits blocking calls only).
+                for t, credit in self._deficits.items():
+                    obs.TENANT_DEFICIT.set(credit, tenant=t)
                 if batch:
                     fill = min(
                         sum(i.rows() for i in batch) / max_rows, 1.0)
@@ -727,7 +730,7 @@ class ContinuousScheduler:
         intakes = [
             threading.Thread(target=self._intake_loop,
                              name=f"sched-intake-{i}", daemon=True)
-            for i in range(max(1, self.serving.sched_intake_threads))
+            for i in range(INTAKE_THREADS)
         ]
         completion = threading.Thread(target=self._completion_loop,
                                       name="sched-completion", daemon=True)

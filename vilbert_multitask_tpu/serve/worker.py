@@ -119,9 +119,9 @@ class ServeWorker:
         # these back to the queue (no attempt charged) and tells the client.
         self._inflight_lock = threading.Lock()
         self._inflight: Dict[int, Job] = {}
-        # Set by run_forever when serving.sched_enabled — the continuous
-        # batching data plane (serve/scheduler.py) this worker drains
-        # through; None while running the legacy step_batch loop.
+        # Set while run_forever runs: the continuous batching data plane
+        # (serve/scheduler.py) this worker drains through. None otherwise
+        # (tests drive the worker through step / step_batch).
         self.scheduler = None
 
     # ------------------------------------------------------------- job cycle
@@ -437,7 +437,7 @@ class ServeWorker:
         while len(packable) < max_jobs:
             if stop_event is not None and stop_event.is_set():
                 # Graceful drain: stop CLAIMING; jobs already in hand below
-                # still finish (stop() waits drain_grace_s for them).
+                # still finish (stop() waits app.DRAIN_GRACE_S for them).
                 break
             job = self._claim(exclude=failed_ids)
             if job is None:
@@ -686,36 +686,23 @@ class ServeWorker:
         return len(abandoned)
 
     def scheduler_stats(self) -> Dict[str, float]:
-        """Continuous-batching scheduler state for the sampler (empty when
-        running the legacy loop)."""
+        """Continuous-batching scheduler state for the sampler (empty
+        outside run_forever)."""
         sched = self.scheduler
         return sched.stats() if sched is not None else {}
 
     def run_forever(self, *, poll_interval_s: float = 0.05,
-                    stop_event=None, batch_jobs: Optional[int] = None) -> None:
-        """The consume loop (reference worker.py:672-673).
-
-        With ``serving.sched_enabled`` (the default) this drains through
+                    stop_event=None) -> None:
+        """The consume loop (reference worker.py:672-673): drains through
         the continuous-batching scheduler — pipelined intake, adaptive
         EDF window dispatch, async completion (serve/scheduler.py).
-        Otherwise the legacy synchronous step_batch loop; ``batch_jobs``
-        applies only there (defaults to the engine's largest compiled row
-        bucket). ``stop_event`` is the drain signal either way: claiming
-        stops the moment it is set, in-hand work finishes, and the loop
-        exits clean."""
-        if self.serving.sched_enabled:
-            from vilbert_multitask_tpu.serve.scheduler import (
-                ContinuousScheduler,
-            )
+        ``stop_event`` is the drain signal: claiming stops the moment it
+        is set, in-hand work finishes, and the loop exits clean."""
+        from vilbert_multitask_tpu.serve.scheduler import ContinuousScheduler
 
-            self.scheduler = ContinuousScheduler(
-                self, stop_event=stop_event,
-                poll_interval_s=poll_interval_s)
-            try:
-                self.scheduler.run()
-            finally:
-                self.scheduler = None
-            return
-        while stop_event is None or not stop_event.is_set():
-            if self.step_batch(batch_jobs, stop_event=stop_event) == 0:
-                time.sleep(poll_interval_s)
+        self.scheduler = ContinuousScheduler(
+            self, stop_event=stop_event, poll_interval_s=poll_interval_s)
+        try:
+            self.scheduler.run()
+        finally:
+            self.scheduler = None

@@ -461,9 +461,10 @@ class SqliteThreadSharing(Rule):
 
     sqlite connections are not thread-safe; the serve tier runs HTTP,
     worker, and push threads against the same databases. The repo pattern
-    is connection-per-call (serve/db.py, serve/queue.py) — a connection
-    parked on ``self``/module scope, or ``check_same_thread=False``,
-    without a ``threading.Lock`` in the same class is a data race.
+    is a connection lent for one ``with`` block out of a list that a lock
+    guards (obs/sqlitestore.py) — a connection parked on ``self``/module
+    scope, or ``check_same_thread=False``, without a ``threading.Lock``
+    in the same class is a data race.
     """
 
     id = "VMT106"
@@ -506,8 +507,9 @@ class SqliteThreadSharing(Rule):
             yield self.finding(
                 ctx, node, f"sqlite3 connection stored {where} without a "
                 f"threading.Lock — sqlite connections are not "
-                f"thread-safe; open a connection per call (the "
-                f"serve/db.py pattern) or guard every use with a lock")
+                f"thread-safe; borrow one for the call (the "
+                f"obs/sqlitestore.py pattern) or guard every use with a "
+                f"lock")
 
 
 # --------------------------------------------------------------------- 107

@@ -32,7 +32,6 @@ from __future__ import annotations
 import json
 import math
 import os
-import sqlite3
 import threading
 import time
 from collections import deque
@@ -46,6 +45,7 @@ from vilbert_multitask_tpu.obs.export import (
 )
 from vilbert_multitask_tpu.obs.identity import WorkerIdentity
 from vilbert_multitask_tpu.obs.instruments import Registry, REGISTRY
+from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
 from vilbert_multitask_tpu.obs.timeseries import TimeSeriesStore
 from vilbert_multitask_tpu.obs.trace import Tracer, default_tracer
 
@@ -102,15 +102,18 @@ def default_spine_path(queue_db_path: str) -> str:
     return os.path.join(d, "fleet.sqlite3")
 
 
-class FleetSpine:
+class FleetSpine(SqliteStore):
     """One process's handle on the shared fleet telemetry db.
 
     Writer side (``flush``/``retire``) publishes this process; reader
     side (``render_prometheus``/``health``/``timeseries``/
-    ``chrome_trace``) merges every live peer. All sqlite access opens a
-    short-lived connection per call (the DurableQueue idiom — WAL mode
-    makes cross-process readers and the single writer coexist).
+    ``chrome_trace``) merges every live peer. Every call borrows a kept
+    connection for its ``with`` block (the DurableQueue idiom,
+    ``obs/sqlitestore.py`` — WAL mode makes cross-process readers and
+    the single writer coexist).
     """
+
+    label = "fleet"
 
     def __init__(self, path: str, identity: WorkerIdentity, *,
                  heartbeat_stale_s: float = 15.0,
@@ -120,7 +123,7 @@ class FleetSpine:
                  registry: Optional[Registry] = None,
                  tracer: Optional[Tracer] = None,
                  timeseries: Optional[TimeSeriesStore] = None):
-        self.path = path
+        super().__init__(path)
         self.identity = identity
         self.heartbeat_stale_s = float(heartbeat_stale_s)
         self.max_spans_per_ident = int(max_spans_per_ident)
@@ -136,16 +139,8 @@ class FleetSpine:
         self._ts_high_water: Dict[str, float] = {}
         self._exported_ids: deque = deque(maxlen=2 * max_spans_per_ident)
         self._exported_set: set = set()
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
         with self._conn() as c:
             c.executescript(_SCHEMA)
-
-    def _conn(self) -> sqlite3.Connection:
-        conn = sqlite3.connect(self.path, timeout=30.0)
-        conn.execute("PRAGMA journal_mode=WAL")
-        conn.execute("PRAGMA synchronous=NORMAL")
-        return conn
 
     # ------------------------------------------------------------ writer side
     def flush(self, health_payload: Optional[Dict[str, Any]] = None) -> None:

@@ -26,14 +26,13 @@ store exists for, so ``list()``/``get()`` see every ident on disk.
 from __future__ import annotations
 
 import json
-import os
 import random
-import sqlite3
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from vilbert_multitask_tpu.obs.attrib import JobCost
+from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
 
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS traces (
@@ -60,7 +59,7 @@ def _span_dict(span) -> Dict[str, Any]:
             "thread_name": span.thread_name, "attrs": dict(span.attrs)}
 
 
-class TraceStore:
+class TraceStore(SqliteStore):
     """One process's handle on the shared ``traces`` table.
 
     Writer side buffers kept traces in memory (``offer``/``pin``) and
@@ -71,10 +70,12 @@ class TraceStore:
     see the module docstring).
     """
 
+    label = "traces"
+
     def __init__(self, path: str, ident: str, *, keep_top_k: int = 8,
                  sample_rate: float = 0.05, retention_s: float = 3600.0,
                  rng: Optional[random.Random] = None):
-        self.path = path
+        super().__init__(path)
         self.ident = ident
         self.keep_top_k = int(keep_top_k)
         self.sample_rate = float(sample_rate)
@@ -90,16 +91,8 @@ class TraceStore:
         self._pinned: set = set()
         self.offered = 0
         self.kept = 0
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
         with self._conn() as c:
             c.executescript(_SCHEMA)
-
-    def _conn(self) -> sqlite3.Connection:
-        conn = sqlite3.connect(self.path, timeout=30.0)
-        conn.execute("PRAGMA journal_mode=WAL")
-        conn.execute("PRAGMA synchronous=NORMAL")
-        return conn
 
     # ------------------------------------------------------------- keep side
     def _keep_reason(self, cost: JobCost) -> Optional[str]:

@@ -639,6 +639,12 @@ class ServeApp:
             obs.clear_recorder()
         else:
             self.recorder.close()
+        # Last, the stores' kept connections (obs/sqlitestore.py): closing a
+        # file's last connection checkpoints its WAL. A call that still
+        # comes (a straggling handler thread, a test reading back) opens anew.
+        for store in (self.queue, self.store, self.cache, self.fleet,
+                      self.tracestore):
+            store.close()
 
 
 def main(argv=None) -> None:

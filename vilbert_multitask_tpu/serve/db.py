@@ -11,19 +11,20 @@ are gone: the store is a file next to the queue.
 from __future__ import annotations
 
 import json
-import os
 import sqlite3
 import time
 from typing import Any, Dict, List, Optional
 
 from vilbert_multitask_tpu.config import TASK_REGISTRY
+from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
 
 
-class ResultStore:
+class ResultStore(SqliteStore):
+    label = "results"
+    full_sync = True  # sqlite's default, FULL: every commit syncs the WAL
+
     def __init__(self, path: str):
-        self.path = path
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
+        super().__init__(path)
         with self._conn() as c:
             # One write transaction for the whole boot migration: DDL
             # autocommits per-statement under the implicit mode, so a crash
@@ -91,11 +92,6 @@ class ResultStore:
                      spec.description, spec.max_images, spec.min_images,
                      spec.max_images),
                 )
-
-    def _conn(self) -> sqlite3.Connection:
-        conn = sqlite3.connect(self.path, timeout=30.0)
-        conn.execute("PRAGMA journal_mode=WAL")
-        return conn
 
     # ------------------------------------------------------------------ tasks
     _TASK_COLS = ("unique_id", "name", "placeholder", "description",

@@ -16,14 +16,13 @@ to a dead-letter state after ``max_delivery_attempts``.
 from __future__ import annotations
 
 import json
-import os
-import sqlite3
 import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 from vilbert_multitask_tpu import obs
+from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
 from vilbert_multitask_tpu.resilience.faults import fault_point
 
 
@@ -39,7 +38,7 @@ class Job:
     more: Optional[bool] = None
 
 
-class DurableQueue:
+class DurableQueue(SqliteStore):
     """Embedded durable queue with at-least-once delivery + dead-lettering.
 
     Two independent poison bounds govern redelivery:
@@ -55,11 +54,13 @@ class DurableQueue:
       quarantined as dead regardless of its attempt balance.
     """
 
+    label = "queue"
+
     def __init__(self, path: str, *, queue_name: str = "vilbert_multitask_queue",
                  max_delivery_attempts: int = 3,
                  max_deliveries: int = 3,
                  visibility_timeout_s: float = 300.0):
-        self.path = path
+        super().__init__(path)
         self.queue_name = queue_name
         self.max_delivery_attempts = max_delivery_attempts
         self.max_deliveries = max_deliveries
@@ -72,8 +73,6 @@ class DurableQueue:
         self._work_seq = 0    # signals so far
         self._waiting = 0     # threads inside wait_for_work()
         self._wake_tokens = 0  # signals granted to a waiter, not yet taken
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
         with self._conn() as c:
             # One write transaction for create + index + migrations: DDL
             # autocommits per-statement under the implicit mode, so two
@@ -111,12 +110,6 @@ class DurableQueue:
                 # host:pid:nonce) holds the in-flight claim — the queue-side
                 # half of fleet observability: a stuck job names its holder.
                 c.execute("ALTER TABLE jobs ADD COLUMN claimed_by TEXT")
-
-    def _conn(self) -> sqlite3.Connection:
-        conn = sqlite3.connect(self.path, timeout=30.0)
-        conn.execute("PRAGMA journal_mode=WAL")
-        conn.execute("PRAGMA synchronous=NORMAL")
-        return conn
 
     # ---------------------------------------------------------------- producer
     def publish(self, body: Dict[str, Any]) -> int:

@@ -39,10 +39,11 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import sqlite3
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
 
 
 def _image_identity(path: str) -> str:
@@ -87,7 +88,7 @@ class Follower:
     attached_at: float
 
 
-class ResultCache:
+class ResultCache(SqliteStore):
     """Durable result cache + singleflight follower registry.
 
     Lives next to the jobs table (same sqlite path as
@@ -99,16 +100,16 @@ class ResultCache:
     across worker threads and processes.
     """
 
+    label = "cache"
+
     def __init__(self, path: str, *, fingerprint: str,
                  max_rows: int = 4096, ttl_s: float = 3600.0,
                  lease_s: float = 120.0):
-        self.path = path
+        super().__init__(path)
         self.fingerprint = fingerprint
         self.max_rows = max_rows
         self.ttl_s = ttl_s
         self.lease_s = lease_s
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
         with self._conn() as c:
             # One write transaction for the DDL, same rationale as the
             # queue's boot: two processes booting at once must not race
@@ -138,12 +139,6 @@ class ResultCache:
             )
             c.execute("CREATE INDEX IF NOT EXISTS cache_followers_key "
                       "ON cache_followers (cache_key, id)")
-
-    def _conn(self) -> sqlite3.Connection:
-        conn = sqlite3.connect(self.path, timeout=30.0)
-        conn.execute("PRAGMA journal_mode=WAL")
-        conn.execute("PRAGMA synchronous=NORMAL")
-        return conn
 
     # ------------------------------------------------------------- submit path
     def admit(self, key: str, *, socket_id: str,

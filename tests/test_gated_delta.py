@@ -137,3 +137,51 @@ def test_paged_attention_compiles_for_the_chip_at_the_served_size(one_chip,
     assert "tpu_custom_call" in text
     assert "%paged_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
+
+
+@pytest.mark.parametrize("B", [16, 64])
+def test_grouped_paged_attention_compiles_for_the_chip(one_chip, B):
+    """The sparse-expert decoder's full layers (ISSUE 32): 48 query heads
+    over 2 layers of 576 + 1 pages of 8 key/value heads x 256 x 128,
+    bfloat16, read in place; the smallest and the largest decode bucket."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sd(jnp.bfloat16, 2, 577, 8, 256, 128)
+    compiled = jax.jit(
+        lambda q, k, v, positions, page_slot, page_pos, pool_blocks:
+        paged_attention.paged_decode_attention(
+            q, k, v, 1, positions, page_slot, page_pos, pool_blocks, 32)
+    ).lower(sd(jnp.bfloat16, B, 48, 128), pool, pool, sd(jnp.int32, B),
+            sd(jnp.int32, 576), sd(jnp.int32, 576),
+            sd(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+@pytest.mark.parametrize("tokens,tile", [(16, 16), (64, 16), (512, 64),
+                                         (2048, 128)])
+def test_expert_layer_compiles_for_the_chip_at_the_served_size(one_chip,
+                                                               tokens, tile):
+    """The grouped expert product of ``ops/moe.py``: 128 held experts of
+    [3072, 2 x 1024] and [1024, 3072] bfloat16, 10 experts a token, for
+    decode batches and prefill chunks; the tile follows the pairs an expert
+    sees. The profile's event carries the kernel's name and the expert
+    matrices' shapes: what ``benchmark/reduce/kinds/trace_moe_roofline.py``
+    reads."""
+    from vilbert_multitask_tpu.ops import moe
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert moe.tile_rows(tokens * 10, 128) == tile
+    compiled = jax.jit(
+        lambda x, experts, weights, gate_up, down, real: moe.experts_forward(
+            x, experts, weights, (0, 128), gate_up, down, real)
+    ).lower(sd(jnp.float32, tokens, 3072), sd(jnp.int32, tokens, 10),
+            sd(jnp.float32, tokens, 10), sd(jnp.bfloat16, 128, 3072, 2048),
+            sd(jnp.bfloat16, 128, 1024, 3072), sd(jnp.bool_, tokens)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%moe_experts" in text
+    assert "bf16[128,3072,2048]" in text and "bf16[128,1024,3072]" in text

@@ -1,6 +1,6 @@
 """The paged decode attention kernel against its oracle (ISSUE 29): the
 Pallas kernel of ``ops/paged_attention.py`` in interpret mode and
-``models/olmo_hybrid.py:_decode_attention`` (the ``jax.numpy`` form the CPU
+``models/decoder.py:_decode_attention`` (the ``jax.numpy`` form the CPU
 serves with) on the same pools, for every decode bucket and four states of
 the pool.
 
@@ -123,3 +123,46 @@ def test_a_key_beyond_its_slots_position_is_not_read():
     weights /= weights.sum(-1, keepdims=True)
     assert np.abs(np.einsum("hk,hkd->hd", weights, values)
                   - seen[3]).max() < ATOL
+
+
+@pytest.mark.parametrize("group", [1, 6, 9])
+def test_grouped_queries_read_their_key_value_head(group):
+    """``group`` query heads a key/value head (ISSUE 32: 6 in laguna's full
+    layers, 9 would be its sliding ones'): the kernel against the
+    ``jax.numpy`` form, and one slot against the plain softmax in which
+    query head ``h`` reads key/value head ``h // group``."""
+    B, reach, pool_blocks = 8, 2 * BLOCK + 3, 3
+    rng = np.random.default_rng(group)
+    page_slot, page_pos, positions, with_keys = tables(B, reach, rng)
+    _, k_pool, v_pool = operands(B, group)
+    q = jax.random.normal(jax.random.PRNGKey(group),
+                          (B, HEADS * group, WIDTH))
+    args = (q, k_pool, v_pool, LAYER, jnp.asarray(positions),
+            jnp.asarray(page_slot), jnp.asarray(page_pos),
+            jnp.int32(pool_blocks), BLOCK)
+    want = np.asarray(model_lib._decode_attention(
+        OlmoHybridConfig().tiny(), *args))
+    got = np.asarray(paged_attention.paged_decode_attention(
+        *args, interpret=True))
+    assert got.shape == (B, HEADS * group, WIDTH)
+    assert np.abs(got[with_keys] - want[with_keys]).max() < ATOL
+    b = with_keys[0]
+    pages = sorted(np.nonzero(page_slot == b)[0], key=lambda p: page_pos[p])
+    keys, values = (np.concatenate([np.asarray(t[LAYER, p]) for p in pages],
+                                   axis=1)[:, :positions[b] + 1]
+                    for t in (k_pool, v_pool))           # [HEADS, n, WIDTH]
+    for h in range(HEADS * group):
+        scores = keys[h // group] @ np.asarray(q[b, h]) / np.sqrt(WIDTH)
+        weights = np.exp(scores - scores.max())
+        plain = (weights / weights.sum()) @ values[h // group]
+        assert np.abs(plain - got[b, h]).max() < ATOL
+
+
+def test_heads_that_do_not_divide_are_refused():
+    q, k_pool, v_pool = operands(8, 0)
+    with pytest.raises(ValueError, match="do not divide"):
+        paged_attention.paged_decode_attention(
+            jnp.concatenate([q, q[:, :1]], 1), k_pool, v_pool, LAYER,
+            jnp.zeros((8,), jnp.int32), jnp.full((PAGES,), -1, jnp.int32),
+            jnp.zeros((PAGES,), jnp.int32), jnp.int32(0), BLOCK,
+            interpret=True)

@@ -8,12 +8,29 @@ import numpy as np
 import pytest
 
 from vilbert_multitask_tpu import obs
-from vilbert_multitask_tpu.config import GenerateConfig, OlmoHybridConfig
-from vilbert_multitask_tpu.engine.seqstate import SequenceState
+from vilbert_multitask_tpu.config import (
+    GenerateConfig,
+    LagunaConfig,
+    OlmoHybridConfig,
+)
+from vilbert_multitask_tpu.engine import seqstate
+from vilbert_multitask_tpu.engine.generate import model_module
 
 GEN = GenerateConfig(model=OlmoHybridConfig().tiny(), param_dtype="float32",
                      slots=4, kv_pages=32, page_size=16,
                      decode_attention_pages=4)
+# The same accounting under the other model's layout (ISSUE 32): a slot of
+# rings, pages of 2 full layers of 2 key/value heads.
+LAGUNA = dataclasses.replace(GEN, model=LagunaConfig().tiny())
+BOTH = pytest.mark.parametrize("gen", [GEN, LAGUNA],
+                               ids=["olmo_hybrid", "laguna"])
+
+
+def SequenceState(gen):
+    """The manager as the engine builds it: the layout from the model's
+    module."""
+    return seqstate.SequenceState(gen, model_module(gen.model).state_layout(
+        gen.model, gen.param_dtype))
 
 
 def counter(name, **labels):
@@ -29,7 +46,9 @@ def test_sizes_follow_the_model():
     # 2 full layers, K and V, 16 tokens of 4 heads of 16
     assert st.page_bytes == 2 * 2 * 16 * 4 * 16 * 4
     assert st.capacity_bytes == 4 * st.slot_bytes + 32 * st.page_bytes
-    assert st.rec_shape == (2, 3, 4, 4, 8, 16)
+    assert st.slot_shapes == {"rec": (2, 3, 4, 4, 8, 16),
+                              "conv": (2, 3, 4, 3, 128)}
+    assert st.ring_bytes == 0
     assert st.pool_shape == (2, 33, 4, 16, 16)   # one page is nobody's
     assert st.max_pages_per_seq == m.max_position_embeddings // 16
     full = SequenceState(GenerateConfig(model=dataclasses.replace(
@@ -37,11 +56,54 @@ def test_sizes_follow_the_model():
         layer_types=OlmoHybridConfig().layer_types[:16])))
     assert full.slot_bytes == 12 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)
     assert full.page_bytes == 4 * 2 * 256 * 30 * 128 * 2
+    # to the byte what the served size held before the layout seam
+    assert (full.slot_bytes, full.page_bytes) == (27371520, 15728640)
     assert full.pages * full.page_size == 65536
 
 
-def test_admit_reserves_slot_and_pages_and_release_frees_them():
-    st = SequenceState(GEN)
+def test_laguna_sizes_follow_the_model():
+    st = SequenceState(LAGUNA)
+    # 3 sliding layers, K and V, 2 heads x window 16 x 16, float32
+    assert st.slot_bytes == st.ring_bytes == 3 * 2 * 2 * 16 * 16 * 4
+    assert st.slot_shapes == {"ring_k": (3, 4, 2, 16, 16),
+                              "ring_v": (3, 4, 2, 16, 16)}
+    assert st.pool_shape == (2, 33, 2, 16, 16)
+    assert st.page_bytes == 2 * 2 * 16 * 2 * 16 * 4
+    arrays = st.allocate()
+    assert {k: v.shape for k, v in arrays.items()} == {
+        **st.slot_shapes, "k": st.pool_shape, "v": st.pool_shape,
+        "token": (4,)}
+    served = SequenceState(GenerateConfig(
+        model=dataclasses.replace(LagunaConfig().cut(5),
+                                  experts_held=(0, 128), vocab_size=50176),
+        slots=64, kv_pages=576))
+    # a ring of 512 keys and values of 8 heads of 128, three layers; a page
+    # of 256 tokens of two full layers
+    assert served.slot_bytes == 3 * 2 * 8 * 512 * 128 * 2 == 6291456
+    assert served.page_bytes == 2 * 2 * 8 * 256 * 128 * 2 == 2097152
+
+
+def test_a_long_prompt_holds_no_more_ring_bytes_than_a_short_one():
+    """A sliding layer holds no page for tokens its window has left behind:
+    16 windows of prompt cost the same slot bytes as one, and the gauge
+    says so."""
+    st = SequenceState(LAGUNA)
+    gauge = obs.REGISTRY.gauge("vmt_seq_ring_bytes_in_use")
+    short = st.admit(16, 4)
+    assert gauge.collect()[()] == st.ring_bytes
+    long = st.admit(16 * 16, 4)
+    assert gauge.collect()[()] == 2 * st.ring_bytes
+    assert st.bytes_in_use == (2 * st.slot_bytes + (len(short.pages)
+                               + len(long.pages)) * st.page_bytes)
+    assert len(long.pages) == 17 and len(short.pages) == 2
+    st.release(short)
+    st.release(long)
+    assert gauge.collect()[()] == 0
+
+
+@BOTH
+def test_admit_reserves_slot_and_pages_and_release_frees_them(gen):
+    st = SequenceState(gen)
     seq = st.admit(prompt_len=40, max_new_tokens=8)   # 48 tokens: 3 pages
     assert (seq.slot, seq.pages) == (0, [0, 1, 2])
     assert st.bytes_in_use == st.slot_bytes + 3 * st.page_bytes
@@ -57,14 +119,19 @@ def test_admit_reserves_slot_and_pages_and_release_frees_them():
         st.release(seq)
 
 
-@pytest.mark.parametrize("reason,gen,first,second", [
-    ("no_slot", dataclasses.replace(GEN, slots=1), (8, 8), (8, 8)),
-    ("no_pages", GEN, (400, 8), (200, 8)),
-    ("no_bytes", dataclasses.replace(GEN, state_bytes_budget=200000),
-     (100, 8), (100, 8)),
+@BOTH
+@pytest.mark.parametrize("reason,change,first,second", [
+    ("no_slot", dict(slots=1), (8, 8), (8, 8)),
+    ("no_pages", {}, (400, 8), (200, 8)),
+    ("no_bytes", dict(state_bytes_budget=200000), (100, 8), (100, 8)),
 ])
-def test_refuses_by_what_runs_out(reason, gen, first, second):
-    st = SequenceState(gen)
+def test_refuses_by_what_runs_out(gen, reason, change, first, second):
+    if reason == "no_bytes" and gen is LAGUNA:
+        # its state is smaller: a budget one admission fits and two do not
+        probe = SequenceState(gen)
+        change = dict(state_bytes_budget=int(1.5 * (
+            probe.slot_bytes + 7 * probe.page_bytes)))
+    st = SequenceState(dataclasses.replace(gen, **change))
     before = counter("vmt_seq_admit_refused_total", reason=reason)
     held = st.admit(*first)
     assert held is not None
@@ -75,8 +142,9 @@ def test_refuses_by_what_runs_out(reason, gen, first, second):
     assert st.admit(*second) is not None
 
 
-def test_200_random_rounds_leak_nothing_and_share_no_page():
-    st = SequenceState(GEN)
+@BOTH
+def test_200_random_rounds_leak_nothing_and_share_no_page(gen):
+    st = SequenceState(gen)
     rng = np.random.default_rng(7)
     live = []
     for _ in range(200):
@@ -105,8 +173,9 @@ def test_200_random_rounds_leak_nothing_and_share_no_page():
     assert (st.page_slot == -1).all()
 
 
-def test_unwritten_bytes_count_what_nothing_was_written_to():
-    st = SequenceState(GEN)
+@BOTH
+def test_unwritten_bytes_count_what_nothing_was_written_to(gen):
+    st = SequenceState(gen)
     assert st.unwritten_bytes() == st.capacity_bytes
     seq = st.admit(40, 8)
     st.note_written(seq, 20)                        # 2 of its 3 pages

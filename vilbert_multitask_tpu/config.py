@@ -550,8 +550,10 @@ LINEAR_ATTENTION, FULL_ATTENTION = "linear_attention", "full_attention"
 class OlmoHybridConfig:
     """A causal decoder of ``model_type: olmo_hybrid`` (gated linear
     attention in ``k`` layers of ``k + 1``): the source ``config.json``'s
-    keys under their own names. The layers are ``models/olmo_hybrid.py``."""
+    keys under their own names. The layers are ``models/olmo_hybrid.py``
+    (``model_type`` names the module: ``engine/generate.py`` finds it so)."""
 
+    model_type: str = "olmo_hybrid"
     vocab_size: int = 100352
     hidden_size: int = 3840
     intermediate_size: int = 11008
@@ -581,6 +583,9 @@ class OlmoHybridConfig:
     def __post_init__(self):
         types = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", types)
+        if self.model_type != "olmo_hybrid":
+            raise ValueError(f"model_type {self.model_type!r} is not "
+                             "olmo_hybrid")
         if len(types) != self.num_hidden_layers:
             raise ValueError("layer_types names another depth than "
                              "num_hidden_layers")
@@ -632,6 +637,197 @@ class OlmoHybridConfig:
             linear_key_head_dim=8, linear_value_head_dim=16,
             use_pallas_scan=False)
 
+SLIDING_ATTENTION = "sliding_attention"
+_LAGUNA_ROPE = (
+    (FULL_ATTENTION, (
+        ("rope_theta", 500000.0), ("rope_type", "yarn"), ("factor", 128.0),
+        ("original_max_position_embeddings", 8192), ("beta_slow", 1.0),
+        ("beta_fast", 32.0), ("attention_factor", 1.4852030263919618),
+        ("partial_rotary_factor", 0.5))),
+    (SLIDING_ATTENTION, (
+        ("rope_type", "default"), ("rope_theta", 10000.0),
+        ("partial_rotary_factor", 1.0))),
+)
+
+
+def _frozen(value):
+    """Dicts and lists of a source ``config.json`` as nested tuples, so the
+    frozen dataclass that holds them stays hashable."""
+    if isinstance(value, dict):
+        return tuple((k, _frozen(v)) for k, v in value.items())
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaConfig:
+    """A causal decoder of ``model_type: laguna``: sparse experts behind a
+    256-wide router, and grouped attention whose layers are windowed three
+    in four, with another count of query heads than the full ones. The
+    source ``config.json``'s keys under their own names; the layers are
+    ``models/laguna.py``.
+
+    Two keys are this repo's, the *cut* a chip's share of a deployment is
+    (``benchmark/configs/laguna-s-2.1-5l-ep2.json``): ``experts_held`` =
+    (first, count) of the routed experts whose weights live here (None:
+    all; the router stays ``num_experts`` wide and a token's top
+    ``num_experts_per_tok`` is taken over all of them); and ``vocab_size``
+    itself, which counts the rows of the embedding and the head held here
+    (ids are drawn from that slice)."""
+
+    model_type: str = "laguna"
+    vocab_size: int = 100352
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    max_position_embeddings: int = 1048576
+    attention_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    hidden_act: str = "silu"
+    num_experts: int = 256
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    norm_topk_prob: bool = True
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Sequence[int] = (0,)
+    tie_word_embeddings: bool = False
+    gating: str = "per-head"
+    sliding_window: int = 512
+    rope_parameters: Sequence = _LAGUNA_ROPE
+    layer_types: Sequence[str] = (
+        (FULL_ATTENTION,) + (SLIDING_ATTENTION,) * 3) * 12
+    mlp_layer_types: Sequence[str] = ("dense",) + ("sparse",) * 47
+    gating_types: Sequence[str] = ("per_head",) * 48
+    num_attention_heads_per_layer: Sequence[int] = (48, 72, 72, 72) * 12
+    moe_apply_router_weight_on_input: bool = False
+    moe_routed_scaling_factor: float = 2.5
+    moe_router_logit_softcapping: float = 0.0
+    # The cut (see above).
+    experts_held: Sequence[int] | None = None
+    # Kernel choice (not the source's): the grouped expert product of
+    # ops/moe.py and the decode attention of ops/paged_attention.py as
+    # Pallas kernels; ``pallas_interpret`` is the CPU tests' explicit
+    # choice, never inferred from the backend.
+    use_pallas: bool = True
+    pallas_interpret: bool = False
+
+    def __post_init__(self):
+        for name in ("layer_types", "mlp_layer_types", "gating_types",
+                     "num_attention_heads_per_layer", "mlp_only_layers",
+                     "rope_parameters"):
+            object.__setattr__(self, name, _frozen(getattr(self, name)))
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held",
+                               tuple(int(v) for v in self.experts_held))
+        n = self.num_hidden_layers
+        for name in ("layer_types", "mlp_layer_types", "gating_types",
+                     "num_attention_heads_per_layer"):
+            if len(getattr(self, name)) != n:
+                raise ValueError(f"{name} names another depth than "
+                                 "num_hidden_layers")
+        if self.model_type != "laguna":
+            raise ValueError(f"model_type {self.model_type!r} is not laguna")
+        if set(self.layer_types) - {FULL_ATTENTION, SLIDING_ATTENTION}:
+            raise ValueError("layer_types: only full_attention and "
+                             "sliding_attention are implemented")
+        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError("mlp_layer_types: only dense and sparse")
+        dense = tuple(i for i, t in enumerate(self.mlp_layer_types)
+                      if t == "dense")
+        if (self.decoder_sparse_step != 1
+                or dense != tuple(i for i in self.mlp_only_layers if i < n)):
+            raise ValueError("mlp_layer_types must be dense exactly at "
+                             "mlp_only_layers (decoder_sparse_step 1)")
+        if (self.gating != "per-head"
+                or set(self.gating_types) != {"per_head"}):
+            raise ValueError("only the per-head output gate is implemented")
+        if (self.hidden_act != "silu" or self.attention_bias
+                or self.tie_word_embeddings):
+            raise ValueError("only silu, no attention bias, untied head")
+        if (not self.norm_topk_prob or self.moe_apply_router_weight_on_input
+                or self.moe_router_logit_softcapping):
+            raise ValueError("the router is implemented with norm_topk_prob, "
+                             "its weight on the expert's output and no "
+                             "logit soft-capping")
+        if any(h % self.num_key_value_heads
+               for h in self.num_attention_heads_per_layer):
+            raise ValueError("every layer's query heads must divide into "
+                             "the key/value heads")
+        rope = self.rope
+        if set(rope) != {FULL_ATTENTION, SLIDING_ATTENTION} or any(
+                p["rope_type"] not in ("default", "yarn")
+                for p in rope.values()):
+            raise ValueError("rope_parameters: one entry a layer type, of "
+                             "rope_type default or yarn")
+        if any(self.head_dim * float(p.get("partial_rotary_factor", 1.0)) % 2
+               for p in rope.values()):
+            raise ValueError("the rotated part of a head must be even")
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError("experts_held = (first, count) must lie inside "
+                             "the router's num_experts")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok exceeds num_experts")
+
+    @property
+    def rope(self) -> dict:
+        """``rope_parameters`` as the source has it: {layer type: {key:
+        value}}."""
+        return {t: dict(p) for t, p in self.rope_parameters}
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the routed experts whose weights live here."""
+        return (0, self.num_experts) if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def full_layers(self) -> tuple:
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == FULL_ATTENTION)
+
+    @property
+    def sliding_layers(self) -> tuple:
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == SLIDING_ATTENTION)
+
+    @property
+    def sparse_layers(self) -> tuple:
+        return tuple(i for i, t in enumerate(self.mlp_layer_types)
+                     if t == "sparse")
+
+    def cut(self, layers: int) -> "LagunaConfig":
+        """The first ``layers`` layers of the pattern."""
+        return dataclasses.replace(
+            self, num_hidden_layers=layers,
+            layer_types=self.layer_types[:layers],
+            mlp_layer_types=self.mlp_layer_types[:layers],
+            gating_types=self.gating_types[:layers],
+            num_attention_heads_per_layer=(
+                self.num_attention_heads_per_layer[:layers]))
+
+    def tiny(self) -> "LagunaConfig":
+        """The size of the CPU tests: 5 layers in the published pattern, 64
+        wide, 16 experts of which the first 8 are held, 4 a token; window
+        16; a context long enough that YaRN's ramp is crossed."""
+        rope = self.rope
+        rope[FULL_ATTENTION].update(original_max_position_embeddings=64,
+                                    factor=8.0, attention_factor=None)
+        return dataclasses.replace(
+            self.cut(5), vocab_size=384, hidden_size=64, intermediate_size=96,
+            num_key_value_heads=2, head_dim=16, num_attention_heads=4,
+            num_attention_heads_per_layer=(4, 6, 6, 6, 4),
+            max_position_embeddings=512, num_experts=16,
+            num_experts_per_tok=4, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, sliding_window=16,
+            rope_parameters=rope, experts_held=(0, 8), use_pallas=False)
+
 
 @dataclasses.dataclass(frozen=True)
 class GenerateConfig:
@@ -639,7 +835,7 @@ class GenerateConfig:
     app serves ViLBERT and refuses the ``generate`` task. One ``ServeApp``
     holds one model: a generate app serves no ViLBERT task."""
 
-    model: OlmoHybridConfig | None = None
+    model: OlmoHybridConfig | LagunaConfig | None = None
     param_dtype: str = "bfloat16"
     # Compiled shapes: tokens a prefill chunk (multiples of the page size
     # and of the scan's 64), sequences a decode step.

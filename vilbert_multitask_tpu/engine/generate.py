@@ -15,8 +15,13 @@ programs: :class:`~vilbert_multitask_tpu.engine.runtime._AotProgram` over
 ``vmt_engine_compiles_total`` counting real compiles, :meth:`warmup` running
 every bucket before the first request.
 
-Nothing a step needs waits for the host. The state (recurrent and
-convolution state by slot, key/value pages, and each slot's last token) is
+The model is one of ``models/`` (:func:`model_module`: the module named by
+the configuration's ``model_type``); everything here (buckets, donated
+state, the AOT cache, run-ahead, ``collect``) is shared by all of them.
+
+Nothing a step needs waits for the host. The state (what the model's
+``state_layout`` says a slot holds, key/value pages, and each slot's last
+token) is
 donated to every call and taken back updated; the token a step generates
 stays on the device as the next step's input. What the host wants of a step
 (the token, its logit, the logits asked for: a few hundred bytes) is
@@ -32,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import importlib
 import threading
 import time
 from typing import Dict, List, Optional, Sequence as Seq
@@ -50,7 +56,6 @@ from vilbert_multitask_tpu.config import (
 from vilbert_multitask_tpu.engine import aotcache
 from vilbert_multitask_tpu.engine.runtime import _AotProgram, _COMPILES
 from vilbert_multitask_tpu.engine.seqstate import Sequence, SequenceState
-from vilbert_multitask_tpu.models import olmo_hybrid as model_lib
 from vilbert_multitask_tpu.resilience import ReplicaKilled
 
 # Pages of its own sequence a prefill attention step reads at once.
@@ -80,6 +85,39 @@ _DECODE_FILL = obs.REGISTRY.histogram(
     "Running sequences of a decode step as a share of its bucket.",
     labelnames=("bucket",),
     buckets=tuple(i / 16 for i in range(1, 17)))
+
+
+_MOE_LABELS = ("program",)
+_MOE_CALLS = obs.REGISTRY.counter(
+    "vmt_moe_calls_total",
+    "Expert-layer calls dispatched: one a sparse layer a step.",
+    labelnames=_MOE_LABELS)
+_MOE_PAIRS = obs.REGISTRY.counter(
+    "vmt_moe_pairs_total",
+    "Token-expert pairs computed here (their expert's weights are held "
+    "here).", labelnames=_MOE_LABELS)
+_MOE_PAIRS_ROUTED = obs.REGISTRY.counter(
+    "vmt_moe_pairs_routed_total",
+    "Token-expert pairs routed, held here or not: experts a token times "
+    "real tokens, a sparse layer.", labelnames=_MOE_LABELS)
+_MOE_EXPERTS_TOUCHED = obs.REGISTRY.counter(
+    "vmt_moe_experts_touched_total",
+    "Held experts that got at least one pair, summed over expert-layer "
+    "calls.", labelnames=_MOE_LABELS)
+_MOE_LOAD = obs.REGISTRY.histogram(
+    "vmt_moe_expert_load_max_over_mean",
+    "An expert-layer call's fullest held expert over the mean of the held "
+    "ones (1.0: even).", labelnames=_MOE_LABELS,
+    buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 32.0, 128.0))
+
+
+def model_module(model_cfg):
+    """The module of ``models/`` that serves this configuration: the one
+    its ``model_type`` names (``param_shapes``, ``init_params``,
+    ``state_layout``, ``prefill_chunk``, ``decode_step``, ``kernels_on``,
+    ``PREFILL_GRANULE``)."""
+    return importlib.import_module(
+        f"vilbert_multitask_tpu.models.{model_cfg.model_type}")
 
 
 @dataclasses.dataclass
@@ -133,15 +171,17 @@ class GenerateEngine:
         self.cfg = cfg
         self.gen = gen
         self.model_cfg = gen.model
+        self.model_lib = model_lib = model_module(gen.model)
         self.replica_id = replica_id
         self.killed = False
         self.boot_times: Dict[str, float] = {}
         self._boot_lock = threading.Lock()
-        page, scan = gen.page_size, model_lib.gated_delta.CHUNK
+        page, granule = gen.page_size, model_lib.PREFILL_GRANULE
         for b in gen.prefill_buckets:
-            if b % page or b % scan:
+            if b % page or b % granule:
                 raise ValueError(f"prefill bucket {b} is no multiple of the "
-                                 f"page size {page} and the scan's {scan}")
+                                 f"page size {page} and the model's "
+                                 f"{granule}")
         if params is None:
             with jax.transfer_guard("allow"):
                 key = jax.random.PRNGKey(seed)
@@ -151,7 +191,8 @@ class GenerateEngine:
         self.params = jax.device_put(params)
         jax.block_until_ready(self.params)
         self.book_boot_time("upload_s", time.perf_counter() - t_up)
-        self.seqstate = SequenceState(gen)
+        self.seqstate = SequenceState(
+            gen, model_lib.state_layout(self.model_cfg, gen.param_dtype))
         self.seqstate.allocate()
         # The AOT executable cache, on accelerators only: XLA:CPU cannot load
         # the prefill program back (its triangular solve is a LAPACK call
@@ -175,7 +216,7 @@ class GenerateEngine:
         with self._program_lock:
             if key in self._programs:
                 return self._programs[key]
-            cfg, gen = self.model_cfg, self.gen
+            cfg, gen, model_lib = self.model_cfg, self.gen, self.model_lib
             if family == "prefill":
                 def run(params, state, x):
                     return model_lib.prefill_chunk(
@@ -279,7 +320,7 @@ class GenerateEngine:
 
     @property
     def pallas_enabled(self) -> bool:
-        return bool(self.model_cfg.use_pallas_scan)
+        return bool(self.model_lib.kernels_on(self.model_cfg))
 
     @property
     def input_cache_stats(self) -> Dict[str, int]:
@@ -350,7 +391,12 @@ class GenerateEngine:
         self.seqstate.note_written(seq, seq.prefilled)
         if not seq.prefilling:
             seq.generated = 1
-            self._pending.append((out, [req], None))
+            self._pending.append((out, [req], None, "prefill", n))
+        elif "moe" in out:
+            # A chunk that ends no prompt yields no token; its expert
+            # counts are still fetched, with the later steps'.
+            self._pending.append(({"moe": out["moe"]}, [], None, "prefill",
+                                  n))
 
     def decode(self, reqs: Seq[GenerateRequest]) -> None:
         """Dispatch one token of each of ``reqs`` (all prefilled, none
@@ -383,7 +429,8 @@ class GenerateEngine:
         _POOL_FILL.observe(st.stats()["kv_pages_in_use"] / st.pages)
         for req in reqs:
             req.seq.generated += 1
-        self._pending.append((out, list(reqs), [r.seq.slot for r in reqs]))
+        self._pending.append((out, list(reqs), [r.seq.slot for r in reqs],
+                              "decode", len(reqs)))
 
     def collect(self, drain: bool = False) -> List[GenerateRequest]:
         """Fetch the outputs of steps dispatched ``DECODE_RUN_AHEAD`` steps
@@ -392,9 +439,11 @@ class GenerateEngine:
         finished = []
         keep = 0 if drain else DECODE_RUN_AHEAD
         while len(self._pending) > keep:
-            out, reqs, rows = self._pending.popleft()
+            out, reqs, rows, program, tokens = self._pending.popleft()
             with obs.span("engine.result_wait", steps_behind=keep):
                 host = jax.device_get(out)
+            if "moe" in host:
+                self._count_experts(program, tokens, host["moe"])
             for k, req in enumerate(reqs):
                 pick = ((lambda a: a) if rows is None
                         else (lambda a, b=rows[k]: a[b]))
@@ -407,6 +456,23 @@ class GenerateEngine:
                     finished.append(req)
         return finished
 
+    def _count_experts(self, program: str, tokens: int, moe) -> None:
+        """One step's expert-layer integers ``moe`` [sparse layers, 3]
+        (pairs computed here, experts touched, the fullest expert's pairs)
+        into the ``vmt_moe_*`` instruments; ``tokens`` real tokens went
+        through every sparse layer."""
+        cfg = self.model_cfg
+        held = cfg.held[1]
+        _MOE_CALLS.inc(len(moe), program=program)
+        _MOE_PAIRS.inc(int(moe[:, 0].sum()), program=program)
+        _MOE_PAIRS_ROUTED.inc(
+            len(moe) * tokens * cfg.num_experts_per_tok, program=program)
+        _MOE_EXPERTS_TOUCHED.inc(int(moe[:, 1].sum()), program=program)
+        for pairs, _, fullest in moe:
+            if pairs:
+                _MOE_LOAD.observe(float(fullest) * held / float(pairs),
+                                  program=program)
+
     def release(self, req: GenerateRequest) -> None:
         """Free the request's slot and pages. Safe as soon as its last
         step is dispatched: the device runs the steps in order, and every
@@ -417,7 +483,9 @@ class GenerateEngine:
 
 def generate_fingerprint(cfg: FrameworkConfig) -> dict:
     """The AOT cache's compatibility fingerprint for the generate
-    programs: the shared one plus everything of ``GenerateConfig``."""
+    programs: the shared one plus everything of ``GenerateConfig``, the
+    model's ``model_type`` among it: one model's executables are never read
+    for another's."""
     fp = aotcache.compile_fingerprint(cfg, mesh=None, heads=False)
     fp["generate"] = dataclasses.asdict(cfg.generate)
     return fp

@@ -1,14 +1,20 @@
 """The sequence-state manager of the generate engine: one object, one byte
 budget, two kinds of state side by side.
 
-*Slots*: what a running sequence holds at a fixed size whatever its length:
-for every linear-attention layer a recurrent state ``[heads, d_k, d_v]``
-float32 (the transpose of the equations' ``S``) and the last ``taps - 1``
-rows that entered the convolution. *Pages*: what grows with the sequence:
-for every full-attention layer keys and values in pages of ``page_size``
-tokens, ``[layer, page, head, token, d]`` (a head's keys of a page are one
-tile: what ``ops/paged_attention.py`` reads), and per sequence the list of
-its pages in order.
+What the two kinds *are* is the model's to say: its module states a
+:class:`~vilbert_multitask_tpu.models.decoder.StateLayout`
+(``state_layout``), and this manager allocates and accounts it without
+knowing a layer kind. *Slot state*: what a running sequence holds at a
+fixed size whatever its length, each array ``lead + (slots,) + shape``
+(``olmo_hybrid``: every linear-attention layer's recurrent state ``[heads,
+d_k, d_v]`` float32 and the last ``taps - 1`` rows that entered its
+convolution; ``laguna``: every sliding layer's last ``sliding_window`` keys
+and values, a ring over positions, so that a window layer holds no page
+for tokens its window has left behind). *Paged state*: what grows with the
+sequence: for every full-attention layer keys and values in pages of
+``page_size`` tokens, ``[layer, page, head, token, d]`` (a head's keys of a
+page are one tile: what ``ops/paged_attention.py`` reads), and per
+sequence the list of its pages in order.
 
 The device side is a dict of arrays (:meth:`allocate`), handed to the
 compiled programs *donated* and taken back updated: a prefill chunk writes
@@ -42,6 +48,7 @@ import numpy as np
 
 from vilbert_multitask_tpu import obs
 from vilbert_multitask_tpu.config import GenerateConfig
+from vilbert_multitask_tpu.models.decoder import StateLayout
 
 _ADMITTED = obs.REGISTRY.counter(
     "vmt_seq_admitted_total", "Sequences the state manager admitted.")
@@ -58,6 +65,10 @@ _PAGES_IN_USE = obs.REGISTRY.gauge(
 _BYTES_IN_USE = obs.REGISTRY.gauge(
     "vmt_seqstate_bytes_in_use",
     "Device bytes of sequence state held by live sequences.")
+_RING_BYTES_IN_USE = obs.REGISTRY.gauge(
+    "vmt_seq_ring_bytes_in_use",
+    "Device bytes of window rings (a sliding layer's last keys and values) "
+    "held by live sequences; part of vmt_seqstate_bytes_in_use.")
 _PAGES_TOTAL = obs.REGISTRY.gauge(
     "vmt_kv_pages_total", "Key/value pages the pool has.")
 
@@ -83,42 +94,37 @@ class Sequence:
 
 
 class SequenceState:
-    def __init__(self, gen: GenerateConfig):
-        model = gen.model
+    def __init__(self, gen: GenerateConfig, layout: StateLayout):
         self.gen = gen
+        self.layout = layout
         self.slots = int(gen.slots)
         self.pages = int(gen.kv_pages)
         self.page_size = int(gen.page_size)
         if self.pages % min(gen.decode_attention_pages, self.pages):
             raise ValueError("kv_pages must be a multiple of "
                              "decode_attention_pages")
-        item = jnp.dtype(gen.param_dtype).itemsize
-        linear_layers = model.periods * (model.period - 1)
-        self.rec_shape = (model.periods, model.period - 1, self.slots,
-                          model.linear_num_value_heads,
-                          model.linear_key_head_dim,
-                          model.linear_value_head_dim)
-        self.conv_shape = (model.periods, model.period - 1, self.slots,
-                           model.linear_conv_kernel_dim - 1,
-                           model.conv_width)
+        self.slot_shapes = {
+            name: a.lead + (self.slots,) + a.shape
+            for name, a in layout.slot_arrays.items()}
         # One page more than the pool counts: the last belongs to nobody,
         # and is where a padding row's keys and values are written.
-        self.pool_shape = (model.periods, self.pages + 1,
-                           model.num_key_value_heads, self.page_size,
-                           model.head_dim)
-        self.slot_bytes = linear_layers * (
-            int(np.prod(self.rec_shape[3:])) * 4
-            + int(np.prod(self.conv_shape[3:])) * item)
+        self.pool_shape = (layout.paged_layers, self.pages + 1,
+                           layout.kv_heads, self.page_size, layout.head_dim)
+        self.slot_bytes = sum(a.slot_bytes
+                              for a in layout.slot_arrays.values())
+        self.ring_bytes = sum(a.slot_bytes
+                              for a in layout.slot_arrays.values() if a.ring)
         self.page_bytes = 2 * int(np.prod(
-            (model.periods, model.num_key_value_heads, self.page_size,
-             model.head_dim))) * item
+            (layout.paged_layers, layout.kv_heads, self.page_size,
+             layout.head_dim))) * jnp.dtype(layout.dtype).itemsize
         self.capacity_bytes = (self.slots * self.slot_bytes
                                + self.pages * self.page_bytes)
         self.budget_bytes = (self.capacity_bytes
                              if gen.state_bytes_budget is None
                              else int(gen.state_bytes_budget))
         self.max_pages_per_seq = min(
-            self.pages, -(-model.max_position_embeddings // self.page_size))
+            self.pages,
+            -(-gen.model.max_position_embeddings // self.page_size))
         self.arrays: Optional[dict] = None
         self._lock = threading.Lock()
         self._free_slots = list(range(self.slots))
@@ -142,14 +148,14 @@ class SequenceState:
     def allocate(self) -> dict:
         """The device arrays, zeroed; kept as ``self.arrays``, which the
         engine replaces with what each donated call returns."""
-        dtype = jnp.dtype(self.gen.param_dtype)
+        dtype = jnp.dtype(self.layout.dtype)
         self.arrays = {
-            "rec": jnp.zeros(self.rec_shape, jnp.float32),
-            "conv": jnp.zeros(self.conv_shape, dtype),
-            "k": jnp.zeros(self.pool_shape, dtype),
-            "v": jnp.zeros(self.pool_shape, dtype),
-            "token": jnp.zeros((self.slots,), jnp.int32),
-        }
+            name: jnp.zeros(shape, self.layout.slot_arrays[name].dtype)
+            for name, shape in self.slot_shapes.items()}
+        self.arrays.update(
+            k=jnp.zeros(self.pool_shape, dtype),
+            v=jnp.zeros(self.pool_shape, dtype),
+            token=jnp.zeros((self.slots,), jnp.int32))
         return self.arrays
 
     def drop_arrays(self) -> None:
@@ -246,3 +252,4 @@ class SequenceState:
         _SLOTS_IN_USE.set(len(self._live))
         _PAGES_IN_USE.set(self.pages - len(self._free_pages))
         _BYTES_IN_USE.set(self.bytes_in_use)
+        _RING_BYTES_IN_USE.set(len(self._live) * self.ring_bytes)

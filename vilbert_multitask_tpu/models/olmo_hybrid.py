@@ -54,12 +54,44 @@ import jax
 import jax.numpy as jnp
 
 from vilbert_multitask_tpu.config import OlmoHybridConfig
+from vilbert_multitask_tpu.models.decoder import (
+    SlotArray,
+    StateLayout,
+    _decode_attention,
+    _head,
+    _mm,
+    _prefill_attention,
+    _rms,
+    _write_rows,
+)
 from vilbert_multitask_tpu.ops import gated_delta, paged_attention
 
 __all__ = ["OlmoHybridConfig", "param_shapes", "init_params",
-           "prefill_chunk", "decode_step"]
+           "state_layout", "kernels_on", "prefill_chunk", "decode_step"]
 
-_NEG = -1e30
+# Tokens a prefill bucket must be a multiple of (beside the page size).
+PREFILL_GRANULE = gated_delta.CHUNK
+
+
+def kernels_on(cfg: OlmoHybridConfig) -> bool:
+    """Whether the step programs hold Pallas kernels (the chip's path)."""
+    return cfg.use_pallas_scan
+
+
+def state_layout(cfg: OlmoHybridConfig, param_dtype: str) -> StateLayout:
+    """What ``engine/seqstate.py`` allocates for this model: a slot holds
+    every linear layer's recurrent state (float32) and convolution tail;
+    the full layers' keys and values are paged."""
+    lead = (cfg.periods, cfg.period - 1)
+    return StateLayout(
+        slot_arrays={
+            "rec": SlotArray(lead, (cfg.linear_num_value_heads,
+                                    cfg.linear_key_head_dim,
+                                    cfg.linear_value_head_dim), "float32"),
+            "conv": SlotArray(lead, (cfg.linear_conv_kernel_dim - 1,
+                                     cfg.conv_width), param_dtype)},
+        paged_layers=cfg.periods, kv_heads=cfg.num_key_value_heads,
+        head_dim=cfg.head_dim, dtype=param_dtype)
 
 
 def param_shapes(cfg: OlmoHybridConfig) -> dict:
@@ -115,17 +147,6 @@ def init_params(cfg: OlmoHybridConfig, key, dtype=jnp.bfloat16) -> dict:
 
 
 # ----------------------------------------------------------- shared pieces
-def _rms(x, scale, eps):
-    x = x.astype(jnp.float32)
-    return (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-            * scale.astype(jnp.float32))
-
-
-def _mm(x, w):
-    """bfloat16 operands, float32 result."""
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
 def _mlp(h, lp):
     gate = jax.nn.silu(_mm(h, lp["mlp_gate"])) * _mm(h, lp["mlp_up"])
     return _mm(gate, lp["mlp_down"])
@@ -183,29 +204,6 @@ def _full_qkv(cfg, x, fp):
                  for t in (q, k, v))
 
 
-def _online_softmax(carry, scores, values):
-    """One block of a streaming softmax. ``scores`` [H, R, K] float32
-    (masked entries at ``_NEG``), ``values`` [H, K, D]."""
-    m, l, acc = carry
-    m_new = jnp.maximum(m, scores.max(-1))
-    p = jnp.exp(scores - m_new[..., None])
-    fade = jnp.exp(m - m_new)
-    acc = acc * fade[..., None] + jnp.einsum(
-        "hrk,hkd->hrd", p.astype(values.dtype), values,
-        preferred_element_type=jnp.float32)
-    return m_new, l * fade + p.sum(-1), acc
-
-
-def _head(cfg, params, h_last, logit_ids):
-    """The logits of given rows: arg-max token, its logit, the logits of
-    ``logit_ids`` [..., n] (all float32)."""
-    logits = _mm(_rms(h_last, params["final_norm"], cfg.rms_norm_eps),
-                 params["lm_head"])
-    token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return {"token": token, "token_logit": jnp.max(logits, axis=-1),
-            "logits": jnp.take_along_axis(logits, logit_ids, axis=-1)}
-
-
 def _period_layers(tree, i):
     return jax.tree_util.tree_map(lambda a: a[i], tree)
 
@@ -231,51 +229,6 @@ def _prefill_linear(cfg, x, lp, rec, tail, real, length):
         q, k, v, g, beta, rec, use_pallas=cfg.use_pallas_scan,
         interpret=cfg.pallas_interpret)
     return _linear_output(cfg, x, o, lp), rec, tail
-
-
-def _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start, block):
-    """Causal attention of a chunk's queries [T, H, D] over the sequence's
-    pages, ``block`` pages at a time up to the chunk's end. The pools are
-    [P, pages, H, page, D]; ``page_row`` names the sequence's pages."""
-    T, H, D = q.shape
-    page = k_pool.shape[3]
-    span = block * page
-    qh = jnp.swapaxes(q, 0, 1)                          # [H, T, D]
-    q_pos = start + jnp.arange(T)
-
-    def gather(pool, j):
-        parts = [jax.lax.dynamic_slice(
-            pool, (p, page_row[j * block + i], 0, 0, 0),
-            (1, 1, H, page, D)).reshape(H, page, D) for i in range(block)]
-        return jnp.concatenate(parts, axis=1)           # [H, span, D]
-
-    def body(j, carry):
-        scores = jnp.einsum("htd,hkd->htk", qh, gather(k_pool, j),
-                            preferred_element_type=jnp.float32)
-        k_pos = j * span + jnp.arange(span)
-        seen = k_pos[None, :] <= q_pos[:, None]
-        scores = jnp.where(seen[None], scores / math.sqrt(D), _NEG)
-        return _online_softmax(carry, scores, gather(v_pool, j))
-
-    blocks = (start + T + span - 1) // span
-    init = (jnp.full((H, T), _NEG, jnp.float32),
-            jnp.zeros((H, T), jnp.float32),
-            jnp.zeros((H, T, D), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-    return jnp.swapaxes(acc / l[..., None], 0, 1)       # [T, H, D]
-
-
-def _write_rows(pool, p, rows, pages, offsets):
-    """Write ``rows`` [N, R, H, D] (R tokens of every head) into the pool
-    [P, pages, H, page, D] of layer ``p``, row n at page ``pages[n]`` from
-    token ``offsets[n]``: one dynamic-update-slice a row, unrolled, each in
-    place (a scatter, or a loop that carries the pool, has the compiler
-    copy the whole pool)."""
-    rows = jnp.swapaxes(rows, 1, 2)                     # [N, H, R, D]
-    for n in range(rows.shape[0]):
-        pool = jax.lax.dynamic_update_slice(
-            pool, rows[n][None, None], (p, pages[n], 0, offsets[n], 0))
-    return pool
 
 
 def prefill_chunk(cfg: OlmoHybridConfig, params, state, tokens, slot, start,
@@ -352,43 +305,6 @@ def _decode_linear(cfg, x, lp, rec, tail):
     q, k, v = _linear_heads(cfg, conved)
     o, rec = gated_delta.recurrent_step(rec, q, k, v, g, beta)
     return _linear_output(cfg, x, o, lp), rec, window[:, 1:]
-
-
-def _decode_attention(cfg, q, k_pool, v_pool, p, positions, page_slot,
-                      page_pos, pool_blocks, block):
-    """One query a slot [B, H, D] (row b is slot b) over the whole pool,
-    ``block`` pages at a time, each key masked by who owns its page and
-    where it lies in its sequence, as far as ``pool_blocks`` says pages are
-    in use. The ``jax.numpy`` form: every block is copied out of the pool
-    and its scores go through memory. It is the CPU's path and the oracle
-    of ``ops/paged_attention.py``, which the chip runs."""
-    B, H, D = q.shape
-    page = k_pool.shape[3]
-    span = block * page
-    qh = jnp.swapaxes(q, 0, 1)                          # [H, B, D]
-    slots = jnp.arange(B)
-
-    def take(pool, j):
-        pages = jax.lax.dynamic_slice(
-            pool, (p, j * block, 0, 0, 0), (1, block, H, page, D))
-        return jnp.swapaxes(pages[0], 0, 1).reshape(H, span, D)
-
-    def body(j, carry):
-        scores = jnp.einsum("hbd,hkd->hbk", qh, take(k_pool, j),
-                            preferred_element_type=jnp.float32)
-        owner = jax.lax.dynamic_slice_in_dim(page_slot, j * block, block)
-        where = jax.lax.dynamic_slice_in_dim(page_pos, j * block, block)
-        k_pos = (where[:, None] * page + jnp.arange(page)[None]).reshape(-1)
-        mine = (jnp.repeat(owner, page)[None, :] == slots[:, None]) \
-            & (k_pos[None, :] <= positions[:, None])
-        scores = jnp.where(mine[None], scores / math.sqrt(D), _NEG)
-        return _online_softmax(carry, scores, take(v_pool, j))
-
-    init = (jnp.full((H, B), _NEG, jnp.float32),
-            jnp.zeros((H, B), jnp.float32),
-            jnp.zeros((H, B, D), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, pool_blocks, body, init)
-    return jnp.swapaxes(acc / jnp.maximum(l, 1e-30)[..., None], 0, 1)
 
 
 def decode_step(cfg: OlmoHybridConfig, params, state, active, positions,

@@ -7,10 +7,12 @@ Tolerance: all three are float32 at ``highest`` precision; they differ by
 summation order and by the triangular solve, read at 3e-6 on outputs of
 size 2: 5e-5 allowed. The TPU kernel's real-size compile is the one thing
 here that is not CPU arithmetic (section 2 of the on-chip-measurement guide:
-the chip's compiler runs without the chip). The other kernel of the generate
-path, ``ops/paged_attention.py``, is compiled for the chip here too (its
-arithmetic is ``tests/test_paged_attention.py``'s): one file describes the
-chip, because one process at a time may load its compiler."""
+the chip's compiler runs without the chip). The other kernels of the
+generate path (``ops/paged_attention.py``'s two, ``ops/moe.py``'s) are
+compiled for the chip here too (their arithmetic is
+``tests/test_paged_attention.py``'s and ``tests/test_laguna.py``'s): one
+file describes the chip, because one process at a time may load its
+compiler."""
 
 import jax
 import jax.numpy as jnp
@@ -158,6 +160,33 @@ def test_grouped_paged_attention_compiles_for_the_chip(one_chip, B):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%paged_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+@pytest.mark.parametrize("heads,pool,table,T", [
+    (48, (2, 577, 8, 256, 128), 576, 256),
+    (48, (2, 577, 8, 256, 128), 576, 2048),
+    (30, (4, 257, 30, 256, 128), 256, 256),
+    (30, (4, 257, 30, 256, 128), 256, 2048),
+])
+def test_paged_prefill_attention_compiles_for_the_chip(one_chip, heads, pool,
+                                                       table, T):
+    """The causal prefill kernel (ISSUE 33) over both served pools, the
+    smallest and the largest prefill bucket: grouped (48 query heads on 8
+    key/value heads, tiles of 128 positions) and ungrouped (30 on 30, tiles
+    of 512), the pools read in place."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, page_row, start:
+        paged_attention.paged_prefill_attention(q, k, v, 1, page_row, start)
+    ).lower(sd(jnp.bfloat16, T, heads, 128), sd(jnp.bfloat16, *pool),
+            sd(jnp.bfloat16, *pool), sd(jnp.int32, table),
+            sd(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%paged_prefill_attention" in text
+    # The queries' and the context's re-laying, never a pool or a score.
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
 
 
 @pytest.mark.parametrize("tokens,tile", [(16, 16), (64, 16), (512, 64),

@@ -41,10 +41,14 @@ def post(port, body):
         conn.close()
 
 
+def counted(name: str) -> dict:
+    """A counter's value by label tuple."""
+    return {key: v for inst in obs.REGISTRY.instruments()
+            if inst.name == name for key, v in inst.collect().items()}
+
+
 def compiles() -> float:
-    return sum(v for inst in obs.REGISTRY.instruments()
-               if inst.name == "vmt_engine_compiles_total"
-               for v in inst.collect().values())
+    return sum(counted("vmt_engine_compiles_total").values())
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +85,7 @@ def answered(app):
     prompts = {f"doc-{n}": rng.integers(0, MODEL.vocab_size, n).tolist()
                for n in (150, 70, 33, 200, 9)}
     before = compiles()
+    chunks_before = counted("vmt_prefill_attention_chunks_total")
     frames = []
     with connect(f"ws://127.0.0.1:{app.ws.bound_port}/chat/") as ws:
         ws.send("sockGen")
@@ -110,8 +115,12 @@ def answered(app):
                 frames.append(json.loads(ws.recv(timeout=0.2)))
         except TimeoutError:
             pass
+    chunks = counted("vmt_prefill_attention_chunks_total")
     return {"prompts": prompts, "frames": frames,
-            "compiled": compiles() - before}
+            "compiled": compiles() - before,
+            "attention_chunks": {key: v - chunks_before.get(key, 0)
+                                 for key, v in chunks.items()
+                                 if v != chunks_before.get(key, 0)}}
 
 
 def test_one_terminal_result_frame_a_request(answered):
@@ -184,6 +193,22 @@ def test_spans_and_instruments_are_exposed(app, answered):
                  "vmt_seq_slots_in_use", "vmt_kv_pages_in_use",
                  "vmt_seqstate_bytes_in_use", "vmt_decode_batch_fill"):
         assert name in text, name
+
+
+def test_prefill_attention_counters_move_and_are_exposed(app, answered):
+    """ISSUE 33's counters on the served path. Prompts of 150, 70, 33, 200
+    and 9 tokens in buckets of 64 and 128 are 128 + 22, 70, 33, 128 + 72
+    and 9: seven chunks, every one through the ``jax.numpy`` attention
+    (this app's model has its kernels off), which walks no kernel page."""
+    assert answered["attention_chunks"] == {("xla",): 7}
+    conn = http.client.HTTPConnection("127.0.0.1", app.http_port, timeout=30)
+    try:
+        conn.request("GET", "/metrics?format=prometheus")
+        text = conn.getresponse().read().decode()
+    finally:
+        conn.close()
+    assert 'vmt_prefill_attention_chunks_total{path="xla"' in text
+    assert "# TYPE vmt_prefill_attention_pages_total counter" in text
 
 
 def test_a_vilbert_door_refuses_the_generate_task(tmp_path):

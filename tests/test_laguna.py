@@ -135,6 +135,65 @@ def test_sequences_side_by_side_do_not_touch_each_other(engine):
         assert worst_difference(engine.params, req) < ATOL
 
 
+def test_prefill_attention_is_counted_at_dispatch(engine):
+    """A prompt of 100 tokens is two chunks in the 64 bucket. With the
+    kernels on, each is one tile of 64 positions x 2 query heads a
+    key/value head, which walks the pages up to its last position, in both
+    full layers: 2 x (4 + 8) page steps; the ``jax.numpy`` path walks none
+    of the kernel's."""
+    from vilbert_multitask_tpu import obs
+
+    def counted(name):
+        return {key: v for inst in obs.REGISTRY.instruments()
+                if inst.name == name for key, v in inst.collect().items()}
+
+    def moved():
+        chunks = counted("vmt_prefill_attention_chunks_total")
+        pages = counted("vmt_prefill_attention_pages_total")
+        return (chunks.get(("kernel",), 0), chunks.get(("xla",), 0),
+                sum(pages.values()))
+
+    before = moved()
+    run_to_end(engine, [request(engine, np.random.default_rng(1), 100)])
+    delta = tuple(b - a for a, b in zip(before, moved()))
+    assert delta == ((2, 0, 24) if engine.pallas_enabled else (0, 2, 0))
+
+
+def test_prefill_chunk_with_kernels_equals_without():
+    """``prefill_chunk`` twice over one prompt (a whole bucket, then a
+    chunk shorter than its bucket, its pages scattered) with the kernels on
+    (interpreted: the causal paged attention of ISSUE 33 and the expert
+    product) and off: the head's logits and every page written agree."""
+    from vilbert_multitask_tpu.engine.seqstate import SequenceState
+
+    gen = generate_cfg().generate
+    params = model_lib.init_params(MODEL, jax.random.PRNGKey(3), jnp.float32)
+    tokens = np.random.default_rng(3).integers(0, MODEL.vocab_size, 64 + 41)
+    row = np.full((32,), 32, np.int32)
+    row[:7] = [9, 2, 30, 4, 17, 0, 11]
+    step = jax.jit(model_lib.prefill_chunk, static_argnums=0)
+    outs = {}
+    for name, model in (("off", MODEL), ("on", dataclasses.replace(
+            MODEL, use_pallas=True, pallas_interpret=True))):
+        state = SequenceState(gen, model_lib.state_layout(
+            model, gen.param_dtype)).allocate()
+        for start, length in ((0, 64), (64, 41)):
+            chunk = np.zeros((64,), np.int32)
+            chunk[:length] = tokens[start:start + length]
+            state, out = step(model, params, state, chunk, 1, start, length,
+                              row, np.asarray(LOGIT_IDS, np.int32))
+        outs[name] = (out, state)
+    (out_on, state_on), (out_off, state_off) = outs["on"], outs["off"]
+    assert int(out_on["token"]) == int(out_off["token"])
+    assert np.abs(np.asarray(out_on["logits"])
+                  - np.asarray(out_off["logits"])).max() < ATOL
+    for pool in ("k", "v"):
+        written = np.asarray(state_on[pool])[:, row[:7]]
+        assert np.abs(written).max() > 0.1
+        assert np.abs(written - np.asarray(state_off[pool])[:, row[:7]]
+                      ).max() < ATOL
+
+
 def test_the_share_adds_up():
     """With ``held`` = each half of the experts in turn, the two partial
     outputs of one sparse layer, the shared expert counted once, sum to the

@@ -141,6 +141,41 @@ def test_chunked_prefill_and_decode_equal_reference_full_forward(engine):
     assert not engine.seqstate.live()
 
 
+def test_prefill_chunk_with_kernels_equals_without():
+    """``prefill_chunk`` twice over one prompt (a whole bucket, then a
+    chunk shorter than its bucket, its pages scattered) with the kernels on
+    (interpreted: the scan and the causal paged attention of ISSUE 33) and
+    off: the head's logits and every page written agree."""
+    from vilbert_multitask_tpu.engine.seqstate import SequenceState
+
+    gen = generate_cfg().generate
+    params = model_lib.init_params(MODEL, jax.random.PRNGKey(3), jnp.float32)
+    tokens = np.random.default_rng(3).integers(0, MODEL.vocab_size, 64 + 41)
+    row = np.full((32,), 32, np.int32)
+    row[:7] = [9, 2, 30, 4, 17, 0, 11]
+    step = jax.jit(model_lib.prefill_chunk, static_argnums=0)
+    outs = {}
+    for name, model in (("off", MODEL), ("on", dataclasses.replace(
+            MODEL, use_pallas_scan=True, pallas_interpret=True))):
+        state = SequenceState(gen, model_lib.state_layout(
+            model, gen.param_dtype)).allocate()
+        for start, length in ((0, 64), (64, 41)):
+            chunk = np.zeros((64,), np.int32)
+            chunk[:length] = tokens[start:start + length]
+            state, out = step(model, params, state, chunk, 1, start, length,
+                              row, np.asarray(LOGIT_IDS, np.int32))
+        outs[name] = (out, state)
+    (out_on, state_on), (out_off, state_off) = outs["on"], outs["off"]
+    assert int(out_on["token"]) == int(out_off["token"])
+    assert np.abs(np.asarray(out_on["logits"])
+                  - np.asarray(out_off["logits"])).max() < ATOL
+    for pool in ("k", "v"):
+        written = np.asarray(state_on[pool])[:, row[:7]]
+        assert np.abs(written).max() > 0.1
+        assert np.abs(written - np.asarray(state_off[pool])[:, row[:7]]
+                      ).max() < ATOL
+
+
 def test_a_freed_slot_and_its_pages_serve_the_next_sequence(engine):
     """Five sequences over four slots, arriving one by one: the fifth
     waits for a slot, then runs in the slot and pages another has just

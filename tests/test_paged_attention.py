@@ -10,6 +10,10 @@ that end in the middle of a page, pages handed out out of order, a page
 whose owner lies beyond the batch, and is read at the second layer index
 while the first holds other numbers.
 
+The prefill kernel (ISSUE 33) follows, against
+``models/decoder.py:_prefill_attention`` on pools whose sequence's pages
+are scattered and out of order.
+
 Tolerance: both are float32 on the CPU and differ by summation order (the
 oracle folds a block of pages at a time, the kernel a page), read at 1e-6
 on outputs of unit size: 5e-5 allowed, as ``tests/test_gated_delta.py``.
@@ -166,3 +170,115 @@ def test_heads_that_do_not_divide_are_refused():
             jnp.zeros((8,), jnp.int32), jnp.full((PAGES,), -1, jnp.int32),
             jnp.zeros((PAGES,), jnp.int32), jnp.int32(0), BLOCK,
             interpret=True)
+
+
+# ----------------------------------------------------------------- prefill
+def prefill_operands(T, group, seed, pages=PAGES):
+    """A chunk's queries, both pools (every layer filled) and the
+    sequence's table: every page of the pool, shuffled."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (LAYERS, pages + 1, HEADS, PAGE, WIDTH)
+    row = np.random.default_rng(seed).permutation(pages).astype(np.int32)
+    return (jax.random.normal(ks[0], (T, HEADS * group, WIDTH)),
+            jax.random.normal(ks[1], shape), jax.random.normal(ks[2], shape),
+            jnp.asarray(row))
+
+
+def prefill_both(q, k_pool, v_pool, row, start):
+    want = model_lib._prefill_attention(
+        OlmoHybridConfig().tiny(), q, k_pool, v_pool, LAYER, row,
+        jnp.int32(start), 2)
+    got = paged_attention.paged_prefill_attention(
+        q, k_pool, v_pool, LAYER, row, jnp.int32(start), interpret=True)
+    assert got.shape == want.shape == q.shape and got.dtype == jnp.float32
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("tile_rows", [8, 48, 768])
+@pytest.mark.parametrize("start_pages", [0, 13])
+@pytest.mark.parametrize("bucket_pages", [1, 8])
+@pytest.mark.parametrize("group", [1, 6])
+def test_prefill_kernel_equals_the_jnp_form(monkeypatch, group, bucket_pages,
+                                            start_pages, tile_rows):
+    """Group 1 and 6, a bucket of one page and of eight, at the start of a
+    prompt and thirteen pages into it; tiles shorter than a page, a page
+    long and the whole chunk (``_TILE_ROWS`` is what the served shapes
+    reach with thousands of rows; here it is turned down so that the tiny
+    chunk has several tiles too)."""
+    monkeypatch.setattr(paged_attention, "_TILE_ROWS", tile_rows)
+    T = bucket_pages * PAGE
+    args = prefill_operands(T, group, group + bucket_pages + start_pages)
+    got, want = prefill_both(*args, start_pages * PAGE)
+    assert np.abs(got - want).max() < ATOL
+    assert np.abs(got).max() > 0.1
+
+
+def test_prefill_kernel_with_a_chunk_shorter_than_its_bucket():
+    """``length < T``: the padded rows' pages are nobody's page (the
+    table's unreserved entries name it, and it holds whatever the last
+    padding wrote); the real rows agree, the padded ones are finite."""
+    T, start, length = 4 * PAGE, 3 * PAGE, PAGE + 5
+    q, k_pool, v_pool, row = prefill_operands(T, 6, 3)
+    reserved = -(-(start + length) // PAGE)
+    row = jnp.where(jnp.arange(PAGES) < reserved, row, PAGES)
+    got, want = prefill_both(q, k_pool, v_pool, row, start)
+    assert np.abs(got[:length] - want[:length]).max() < ATOL
+    assert np.isfinite(got).all()
+
+
+def test_a_key_after_the_querys_position_is_not_read(monkeypatch):
+    """Poison every key and value behind each query's position (the rest
+    of the chunk's pages and all later ones): nothing moves. Row 0 of a
+    chunk that starts a prompt is its first value alone."""
+    monkeypatch.setattr(paged_attention, "_TILE_ROWS", 8)
+    T, start = 2 * PAGE, 2 * PAGE
+    q, k_pool, v_pool, row = prefill_operands(T, 1, 5)
+    pages = np.asarray(row)
+
+    def run(k_pool, v_pool, q=q, start=start):
+        return np.asarray(paged_attention.paged_prefill_attention(
+            q, k_pool, v_pool, LAYER, row, jnp.int32(start), interpret=True))
+
+    seen = run(k_pool, v_pool)
+    # The last query (position start + T - 1) sees all of pages 0..3; a
+    # query at start + 3 sees four keys of page 2: compare it alone under
+    # poison behind it.
+    behind = (k_pool.at[LAYER, pages[2], :, 4:].set(1e4)
+              .at[LAYER, pages[3:]].set(1e4),
+              v_pool.at[LAYER, pages[2], :, 4:].set(1e4)
+              .at[LAYER, pages[3:]].set(1e4))
+    assert (run(*behind)[:4] == seen[:4]).all()
+    after_chunk = (k_pool.at[LAYER, pages[4:]].set(1e4),
+                   v_pool.at[LAYER, pages[4:]].set(1e4))
+    assert (run(*after_chunk) == seen).all()
+    first = run(k_pool, v_pool, start=0)[0]
+    assert np.abs(first - np.asarray(v_pool[LAYER, pages[0], :, 0])
+                  ).max() < ATOL
+
+
+def test_prefill_heads_that_do_not_divide_are_refused():
+    q, k_pool, v_pool, row = prefill_operands(PAGE, 1, 0)
+    with pytest.raises(ValueError, match="do not divide"):
+        paged_attention.paged_prefill_attention(
+            jnp.concatenate([q, q[:, :1]], 1), k_pool, v_pool, LAYER, row,
+            jnp.int32(0), interpret=True)
+
+
+@pytest.mark.parametrize("T,group,tq", [(2048, 6, 128), (256, 6, 128),
+                                        (2048, 1, 512), (256, 1, 256),
+                                        (96, 6, 96), (96, 9, 48)])
+def test_the_tile_follows_from_the_shapes(T, group, tq):
+    assert paged_attention.prefill_tile(T, group) == tq
+
+
+@pytest.mark.parametrize("start,T,group,max_pages,steps", [
+    (0, 2048, 6, 576, sum(range(1, 9)) * 2),      # 16 tiles of half a page
+    (30720, 2048, 6, 576, 16 * 120 + sum(range(1, 9)) * 2),
+    (0, 2048, 1, 256, 2 + 4 + 6 + 8),             # 4 tiles of two pages
+    (0, 256, 1, 256, 1),
+    (65024, 1024, 1, 256, 256 + 256),             # never past the table
+])
+def test_pages_walked_are_reckoned_from_start_and_tile(start, T, group,
+                                                       max_pages, steps):
+    assert paged_attention.prefill_pages_walked(
+        start, T, 256, group, max_pages) == steps

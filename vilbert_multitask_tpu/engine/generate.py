@@ -56,9 +56,11 @@ from vilbert_multitask_tpu.config import (
 from vilbert_multitask_tpu.engine import aotcache
 from vilbert_multitask_tpu.engine.runtime import _AotProgram, _COMPILES
 from vilbert_multitask_tpu.engine.seqstate import Sequence, SequenceState
+from vilbert_multitask_tpu.ops import paged_attention
 from vilbert_multitask_tpu.resilience import ReplicaKilled
 
-# Pages of its own sequence a prefill attention step reads at once.
+# Pages of its own sequence a step of the ``jax.numpy`` prefill attention
+# reads at once (the kernel of ``ops/paged_attention.py`` reads one).
 PREFILL_ATTENTION_PAGES = 2
 # Decode steps dispatched before the oldest one's tokens are fetched: one
 # keeps the device fed while the host fetches, two hides a slow fetch.
@@ -75,6 +77,16 @@ _PREFILL_FILL = obs.REGISTRY.histogram(
     "Prompt tokens of a prefill chunk as a share of its bucket.",
     labelnames=("bucket",),
     buckets=tuple(i / 16 for i in range(1, 17)))
+_PREFILL_ATTENTION_CHUNKS = obs.REGISTRY.counter(
+    "vmt_prefill_attention_chunks_total",
+    "Prefill chunks dispatched, by what runs their attention over the "
+    "sequence's pages: the Pallas kernel or the jax.numpy loop.",
+    labelnames=("path",))
+_PREFILL_ATTENTION_PAGES = obs.REGISTRY.counter(
+    "vmt_prefill_attention_pages_total",
+    "Page steps the prefill attention kernel was asked to walk: pages up "
+    "to a query tile's last position, summed over a chunk's tiles and the "
+    "paged layers.")
 _POOL_FILL = obs.REGISTRY.histogram(
     "vmt_kv_pool_fill",
     "Key/value pages in use as a share of the pool, read at every decode "
@@ -387,6 +399,7 @@ class GenerateEngine:
                 "logit_ids": req.logit_ids})
         _PREFILL_TOKENS.inc(n)
         _PREFILL_FILL.observe(n / bucket, bucket=str(bucket))
+        self._count_prefill_attention(seq.prefilled, bucket)
         seq.prefilled += n
         self.seqstate.note_written(seq, seq.prefilled)
         if not seq.prefilling:
@@ -455,6 +468,21 @@ class GenerateEngine:
                 if req.complete:
                     finished.append(req)
         return finished
+
+    def _count_prefill_attention(self, start: int, bucket: int) -> None:
+        """One chunk's attention over its sequence's pages into the
+        ``vmt_prefill_attention_*`` counters, reckoned here from what the
+        call was given: the kernel's tile follows from the bucket and the
+        layout, the pages a tile walks from where the chunk starts."""
+        if not self.pallas_enabled:
+            _PREFILL_ATTENTION_CHUNKS.inc(path="xla")
+            return
+        st = self.seqstate
+        _PREFILL_ATTENTION_CHUNKS.inc(path="kernel")
+        _PREFILL_ATTENTION_PAGES.inc(
+            st.layout.paged_layers * paged_attention.prefill_pages_walked(
+                start, bucket, st.page_size, st.layout.query_group,
+                st.max_pages_per_seq))
 
     def _count_experts(self, program: str, tokens: int, moe) -> None:
         """One step's expert-layer integers ``moe`` [sparse layers, 3]

@@ -1,10 +1,10 @@
 """What the served decoders share (``models/olmo_hybrid.py``,
 ``models/laguna.py``): the layout a model states for the sequence-state
 manager, and the pieces of a step that do not depend on the kind of layer:
-RMSNorm, the bfloat16 product, the streaming softmax, attention of a
-prefill chunk over a sequence's pages, the ``jax.numpy`` decode attention
-over the pool (the CPU path and the oracle of ``ops/paged_attention.py``),
-the in-place row writes and the head.
+RMSNorm, the bfloat16 product, the streaming softmax, the ``jax.numpy``
+attention of a prefill chunk over a sequence's pages and of a decode step
+over the pool (the CPU's path and the oracles of ``ops/paged_attention.py``'s
+two kernels), the in-place row writes and the head.
 
 Attention here is *grouped*: queries of ``H`` heads read pools of ``H_kv``
 heads, ``H % H_kv == 0``, query head ``h`` reading key/value head ``h //
@@ -49,13 +49,15 @@ class StateLayout:
     (``state_layout``) and ``engine/seqstate.py`` allocates and accounts
     it. *Slot state* (``slot_arrays``) has a fixed size a sequence; *paged
     state* grows with it: keys and values of ``paged_layers`` layers, pools
-    ``[paged_layers, pages + 1, kv_heads, page, head_dim]`` of ``dtype``."""
+    ``[paged_layers, pages + 1, kv_heads, page, head_dim]`` of ``dtype``,
+    each key/value head read by ``query_group`` query heads."""
 
     slot_arrays: Dict[str, SlotArray]
     paged_layers: int
     kv_heads: int
     head_dim: int
     dtype: str
+    query_group: int = 1
 
 
 def _rms(x, scale, eps):
@@ -110,7 +112,11 @@ def _from_group(ctx, N):
 def _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start, block):
     """Causal attention of a chunk's queries [T, H, D] over the sequence's
     pages, ``block`` pages at a time up to the chunk's end. The pools are
-    [P, pages, H_kv, page, D]; ``page_row`` names the sequence's pages."""
+    [P, pages, H_kv, page, D]; ``page_row`` names the sequence's pages. The
+    ``jax.numpy`` form: every block is copied out of the pool and its
+    scores go through memory. It is the CPU's path and the oracle of
+    ``ops/paged_attention.py:paged_prefill_attention``, which the chip
+    runs."""
     T, _, D = q.shape
     Hk, page = k_pool.shape[2], k_pool.shape[3]
     span = block * page

@@ -48,7 +48,8 @@ norms, rotary embedding, softmax, the router and the gates are float32.
 Two entry points, under the step contract ``models/olmo_hybrid.py`` has.
 :func:`prefill_chunk` runs a chunk of one sequence's prompt: a full layer
 writes the chunk's keys and values to the sequence's pages and attends over
-the pages so far; a sliding layer attends over the window's keys before
+the pages so far (``ops/paged_attention.py``'s causal kernel where
+``use_pallas``); a sliding layer attends over the window's keys before
 the chunk, which the slot's *ring* holds, and the chunk itself, then leaves
 the chunk's last ``sliding_window`` keys and values in the ring.
 :func:`decode_step` runs one token of every running sequence: a full layer
@@ -161,7 +162,9 @@ def state_layout(cfg: LagunaConfig, param_dtype: str) -> StateLayout:
     return StateLayout(
         slot_arrays={"ring_k": ring, "ring_v": ring},
         paged_layers=len(cfg.full_layers), kv_heads=cfg.num_key_value_heads,
-        head_dim=cfg.head_dim, dtype=param_dtype)
+        head_dim=cfg.head_dim, dtype=param_dtype,
+        query_group=(cfg.num_attention_heads_per_layer[cfg.full_layers[0]]
+                     // cfg.num_key_value_heads))
 
 
 # ------------------------------------------------------------------ rotary
@@ -364,8 +367,13 @@ def prefill_chunk(cfg: LagunaConfig, params, state, tokens, slot, start,
                                      no_offset)
                 v_pool = _write_rows(v_pool, p, by_page(v), chunk_pages,
                                      no_offset)
-                ctx = _prefill_attention(cfg, q, k_pool, v_pool, p,
-                                         page_row, start, attention_block)
+                if cfg.use_pallas:
+                    ctx = paged_attention.paged_prefill_attention(
+                        q, k_pool, v_pool, p, page_row, start,
+                        interpret=cfg.pallas_interpret)
+                else:
+                    ctx = _prefill_attention(cfg, q, k_pool, v_pool, p,
+                                             page_row, start, attention_block)
             else:
                 s = cfg.sliding_layers.index(l)
                 at = (s, slot, 0, 0, 0)

@@ -28,9 +28,10 @@ chunk of one sequence's prompt: the recurrent state through the chunked
 scan, keys and values written to the sequence's pages, attention over the
 pages written so far. :func:`decode_step` runs one token of every running
 sequence: the rank-1 state update, one key/value row written, attention
-over the whole pool under an ownership mask (the Pallas kernel of
-``ops/paged_attention.py`` where ``use_pallas_scan`` says kernels are on,
-:func:`_decode_attention` in ``jax.numpy`` otherwise). Both work on the
+over the whole pool under an ownership mask. Both attentions are the Pallas
+kernels of ``ops/paged_attention.py`` where ``use_pallas_scan`` says
+kernels are on, ``models/decoder.py``'s ``jax.numpy`` forms
+(``_prefill_attention``, ``_decode_attention``) otherwise. Both work on the
 *sequence state* of ``engine/seqstate.py`` (a dict of device arrays, donated
 and updated in place; the key/value pools are ``[P, pages + 1, H, page,
 D]``) and return the logits of the last real position only, as the arg-max
@@ -91,7 +92,8 @@ def state_layout(cfg: OlmoHybridConfig, param_dtype: str) -> StateLayout:
             "conv": SlotArray(lead, (cfg.linear_conv_kernel_dim - 1,
                                      cfg.conv_width), param_dtype)},
         paged_layers=cfg.periods, kv_heads=cfg.num_key_value_heads,
-        head_dim=cfg.head_dim, dtype=param_dtype)
+        head_dim=cfg.head_dim, dtype=param_dtype,
+        query_group=cfg.num_attention_heads // cfg.num_key_value_heads)
 
 
 def param_shapes(cfg: OlmoHybridConfig) -> dict:
@@ -275,8 +277,13 @@ def prefill_chunk(cfg: OlmoHybridConfig, params, state, tokens, slot, start,
         q, k, v = _full_qkv(cfg, x, full)
         k_pool = _write_rows(k_pool, p, by_page(k), chunk_pages, no_offset)
         v_pool = _write_rows(v_pool, p, by_page(v), chunk_pages, no_offset)
-        ctx = _prefill_attention(cfg, q, k_pool, v_pool, p, page_row, start,
-                                 attention_block)
+        if cfg.use_pallas_scan:
+            ctx = paged_attention.paged_prefill_attention(
+                q, k_pool, v_pool, p, page_row, start,
+                interpret=cfg.pallas_interpret)
+        else:
+            ctx = _prefill_attention(cfg, q, k_pool, v_pool, p, page_row,
+                                     start, attention_block)
         x = _close_block(cfg, x, _mm(ctx.reshape(T, -1), full["wo"]), full)
     rec = jnp.stack(recs).reshape(rec.shape)
     conv = jnp.stack(convs).reshape(conv.shape)

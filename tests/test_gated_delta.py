@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from vilbert_multitask_tpu.ops import gated_delta as gd
-from vilbert_multitask_tpu.ops import paged_attention
+from vilbert_multitask_tpu.ops import paged_attention, selective_scan
 
 ATOL = 5e-5
 
@@ -214,3 +214,136 @@ def test_expert_layer_compiles_for_the_chip_at_the_served_size(one_chip,
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%moe_experts" in text
     assert "bf16[128,3072,2048]" in text and "bf16[128,1024,3072]" in text
+
+
+@pytest.mark.parametrize("B,heads,ring", [
+    (32, 40, (8, 129, 10, 512, 128)),      # phi4flash: 4 rows a key pair
+    (128, 40, (8, 129, 10, 512, 128)),
+    (64, 72, (3, 65, 8, 512, 128)),        # laguna: 9 query heads a head
+])
+def test_ring_decode_attention_compiles_for_the_chip(one_chip, B, heads,
+                                                     ring):
+    """A decode step's attention over the slots' rings (ISSUE 34), read in
+    place: no copy of a ring array (2.7 GB at the larger served size), of a
+    layer of it, or of the scores."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, positions: paged_attention.ring_decode_attention(
+            q, k, v, 1, positions, 512)
+    ).lower(sd(jnp.bfloat16, B, heads, 128), sd(jnp.bfloat16, *ring),
+            sd(jnp.bfloat16, *ring), sd(jnp.int32, B)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%ring_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+def test_ring_store_writes_in_place_on_the_chip(one_chip):
+    """One slot's ring stored into the donated array: the array is aliased
+    to the result, never copied."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda ring, slot, new: paged_attention.ring_store(ring, 1, slot,
+                                                           new),
+        donate_argnums=(0,)
+    ).lower(sd(jnp.bfloat16, 8, 129, 10, 512, 128), sd(jnp.int32),
+            sd(jnp.bfloat16, 10, 512, 128)).compile()
+    assert "%ring_store" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4 * 2 ** 20
+    assert memory.alias_size_in_bytes >= 8 * 129 * 10 * 512 * 128 * 2
+
+
+@pytest.mark.parametrize("B", [32, 128])
+def test_pair_rows_over_the_one_pool_compile_for_the_chip(one_chip, B):
+    """Differential attention's 40 query rows (both maps of 20 pairs, zero-
+    padded to the pair's 128 lanes) over the one paged layer of 640 + 1
+    pages of 10 key pairs x 256 x 128: what the full layer and every cross
+    layer run in a decode step (ISSUE 34)."""
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sd(jnp.bfloat16, 1, 641, 10, 256, 128)
+    compiled = jax.jit(
+        lambda q, k, v, positions, page_slot, page_pos, pool_blocks:
+        paged_attention.paged_decode_attention(
+            q, k, v, 0, positions, page_slot, page_pos, pool_blocks, 32)
+    ).lower(sd(jnp.bfloat16, B, 40, 128), pool, pool, sd(jnp.int32, B),
+            sd(jnp.int32, 640), sd(jnp.int32, 640),
+            sd(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_selective_scan_compiles_for_the_chip(one_chip, T):
+    """5,120 channels, state 16, the smallest and the largest prefill
+    bucket: what the interpreter cannot show (tiling, SMEM, VMEM) the
+    chip's compiler refuses here."""
+    Ci, N = 5120, 16
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(selective_scan.selective_scan).lower(
+        sd(T, Ci), sd(T, Ci), sd(T, N), sd(T, N), sd(N, Ci), sd(Ci),
+        sd(N, Ci)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The profile's event carries the kernel's name:
+    # benchmark/reduce/kinds/trace_selective_scan_roofline.py finds it so.
+    assert "%selective_scan" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_phi4flash_decode_program_updates_its_state_in_place(one_chip):
+    """The whole decode program of the smallest bucket at the served size
+    (ISSUE 34; 15 s): no rematerialised update (where a later layer's read
+    of its state was fused into a reader of the whole updated array, the
+    compiler recomputed the update of the layer before and, in place,
+    applied it twice: the served logits drifted from the second decode step
+    on, on the chip only, and only with the memory near its limit), and no
+    copy of a ring array or of the scan state."""
+    from vilbert_multitask_tpu.config import GenerateConfig, Phi4FlashConfig
+    from vilbert_multitask_tpu.engine.seqstate import SequenceState
+    from vilbert_multitask_tpu.models import phi4flash
+
+    cfg, B, pages = Phi4FlashConfig(), 32, 640
+    gen = GenerateConfig(model=cfg, slots=128, kv_pages=pages,
+                         decode_buckets=(32, 64, 96, 128))
+    st = SequenceState(gen, phi4flash.state_layout(cfg, "bfloat16"))
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: sd(s, "bfloat16"), phi4flash.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))
+    state = {n: sd(s, st.layout.slot_arrays[n].dtype)
+             for n, s in st.slot_shapes.items()}
+    state.update(k=sd(st.pool_shape, "bfloat16"),
+                 v=sd(st.pool_shape, "bfloat16"), token=sd((128,), "int32"))
+    x = {"active": sd((B,), "bool"), "positions": sd((B,), "int32"),
+         "write_page": sd((B,), "int32"), "page_slot": sd((pages,), "int32"),
+         "page_pos": sd((pages,), "int32"), "pool_blocks": sd((), "int32"),
+         "logit_ids": sd((B, 16), "int32")}
+
+    def run(params, state, x):
+        return phi4flash.decode_step(
+            cfg, params, state, x["active"], x["positions"], x["write_page"],
+            x["page_slot"], x["page_pos"], x["pool_blocks"], x["logit_ids"],
+            attention_block=32)
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(params, state,
+                                                      x).compile()
+    text = compiled.as_text()
+    assert ".remat" not in text
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    assert not any("[8,129,10,512,128]" in line or "[9,128,16,5120]" in line
+                   for line in copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20

@@ -282,3 +282,71 @@ def test_pages_walked_are_reckoned_from_start_and_tile(start, T, group,
                                                        max_pages, steps):
     assert paged_attention.prefill_pages_walked(
         start, T, 256, group, max_pages) == steps
+
+
+# --------------------------------------------------------------------- ring
+# The ring kernels (ISSUE 34): a decode step's attention over the slots'
+# rings read in place, against ``models/decoder.py:_sliding_decode``, and the
+# store of one slot's ring, against a ``dynamic_update_slice``.
+RING_LAYERS, RING_SLOTS, RING_ROWS = 3, 6, 16
+
+
+def ring_operands(B, group, heads=2, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (RING_LAYERS, RING_SLOTS + 1, heads, RING_ROWS, WIDTH)
+    return (jax.random.normal(ks[0], (B, heads * group, WIDTH)),
+            jax.random.normal(ks[1], shape), jax.random.normal(ks[2], shape))
+
+
+@pytest.mark.parametrize("window", [RING_ROWS, 9])
+@pytest.mark.parametrize("group", [1, 4, 9])
+def test_ring_kernel_equals_the_jnp_form(group, window):
+    """Positions before the ring is full (rows past them hold other
+    numbers and are not read), exactly full, and many turns later; a window
+    shorter than the ring."""
+    from vilbert_multitask_tpu.models.decoder import _sliding_decode
+
+    B = 5
+    q, ring_k, ring_v = ring_operands(B, group)
+    positions = jnp.asarray([0, 3, RING_ROWS - 1, RING_ROWS, 1000], jnp.int32)
+    want = _sliding_decode(window, q, ring_k[LAYER, :B], ring_v[LAYER, :B],
+                           positions)
+    got = paged_attention.ring_decode_attention(
+        q, ring_k, ring_v, LAYER, positions, window, interpret=True)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert float(jnp.abs(got - want).max()) < ATOL
+
+
+def test_ring_heads_that_do_not_divide_are_refused():
+    q, ring_k, ring_v = ring_operands(4, 1)
+    with pytest.raises(ValueError, match="do not divide"):
+        paged_attention.ring_decode_attention(
+            q[:, :1].repeat(3, axis=1), ring_k, ring_v, 0,
+            jnp.zeros((4,), jnp.int32), RING_ROWS, interpret=True)
+
+
+@pytest.mark.parametrize("slot", [0, 4])
+def test_ring_store_replaces_one_slots_ring_and_nothing_else(slot):
+    _, ring, _ = ring_operands(4, 1)
+    new = jax.random.normal(jax.random.PRNGKey(9), ring.shape[2:])
+    got = jax.jit(lambda r, at, n: paged_attention.ring_store(
+        r, LAYER, at, n, interpret=True))(ring, jnp.int32(slot), new)
+    assert (np.asarray(got) == np.asarray(ring.at[LAYER, slot].set(new))
+            ).all()
+
+
+def test_an_inactive_rows_key_goes_to_nobodys_slot():
+    """``_write_ring`` leaves an inactive slot's ring as it was: its row
+    lands in the array's last slot, which no sequence owns."""
+    from vilbert_multitask_tpu.models.decoder import _write_ring
+
+    _, ring, _ = ring_operands(4, 1)
+    rows = jax.random.normal(jax.random.PRNGKey(3), (3, 2, WIDTH))
+    index = jnp.asarray([2, 5, 7], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    got = np.asarray(_write_ring(ring, LAYER, rows, index, active))
+    want = np.asarray(ring).copy()
+    want[LAYER, 0, :, 2] = rows[0]
+    want[LAYER, RING_SLOTS, :, 5] = rows[1]
+    want[LAYER, 2, :, 7] = rows[2]
+    assert (got == want).all()

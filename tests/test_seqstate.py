@@ -12,6 +12,7 @@ from vilbert_multitask_tpu.config import (
     GenerateConfig,
     LagunaConfig,
     OlmoHybridConfig,
+    Phi4FlashConfig,
 )
 from vilbert_multitask_tpu.engine import seqstate
 from vilbert_multitask_tpu.engine.generate import model_module
@@ -22,8 +23,11 @@ GEN = GenerateConfig(model=OlmoHybridConfig().tiny(), param_dtype="float32",
 # The same accounting under the other model's layout (ISSUE 32): a slot of
 # rings, pages of 2 full layers of 2 key/value heads.
 LAGUNA = dataclasses.replace(GEN, model=LagunaConfig().tiny())
-BOTH = pytest.mark.parametrize("gen", [GEN, LAGUNA],
-                               ids=["olmo_hybrid", "laguna"])
+# And under the third's (ISSUE 34): a slot of Mamba states, convolution
+# rows and rings, pages of one layer whatever the depth.
+PHI4FLASH = dataclasses.replace(GEN, model=Phi4FlashConfig().tiny())
+BOTH = pytest.mark.parametrize("gen", [GEN, LAGUNA, PHI4FLASH],
+                               ids=["olmo_hybrid", "laguna", "phi4flash"])
 
 
 def SequenceState(gen):
@@ -65,8 +69,9 @@ def test_laguna_sizes_follow_the_model():
     st = SequenceState(LAGUNA)
     # 3 sliding layers, K and V, 2 heads x window 16 x 16, float32
     assert st.slot_bytes == st.ring_bytes == 3 * 2 * 2 * 16 * 16 * 4
-    assert st.slot_shapes == {"ring_k": (3, 4, 2, 16, 16),
-                              "ring_v": (3, 4, 2, 16, 16)}
+    # a ring array has one slot more than the manager counts: nobody's
+    assert st.slot_shapes == {"ring_k": (3, 5, 2, 16, 16),
+                              "ring_v": (3, 5, 2, 16, 16)}
     assert st.pool_shape == (2, 33, 2, 16, 16)
     assert st.page_bytes == 2 * 2 * 16 * 2 * 16 * 4
     arrays = st.allocate()
@@ -81,6 +86,41 @@ def test_laguna_sizes_follow_the_model():
     # of 256 tokens of two full layers
     assert served.slot_bytes == 3 * 2 * 8 * 512 * 128 * 2 == 6291456
     assert served.page_bytes == 2 * 2 * 8 * 256 * 128 * 2 == 2097152
+
+
+def test_phi4flash_sizes_follow_the_model():
+    st = SequenceState(PHI4FLASH)
+    # 3 Mamba layers: state [4, 128] float32 and 3 rows of 128; 2 window
+    # layers, key pairs and values, 2 pairs x window 16 x 16, float32
+    assert st.ring_bytes == 2 * 2 * 2 * 16 * 16 * 4
+    assert st.slot_bytes == 3 * (4 * 128 * 4 + 3 * 128 * 4) + st.ring_bytes
+    assert st.slot_shapes == {"ssm": (3, 4, 4, 128), "conv": (3, 4, 3, 128),
+                              "ring_k": (2, 5, 2, 16, 16),
+                              "ring_v": (2, 5, 2, 16, 16)}
+    # one paged layer: 2 key pairs of 16, read by 4 query rows each
+    assert st.pool_shape == (1, 33, 2, 16, 16)
+    assert st.page_bytes == 2 * 16 * 2 * 16 * 4
+    assert st.layout.query_group == 4
+    served = SequenceState(GenerateConfig(
+        model=Phi4FlashConfig(), slots=128, kv_pages=640,
+        decode_buckets=(32, 64, 96, 128)))
+    # nine states [16, 5120] float32 with 3 rows of 5120, eight rings of 512
+    # key pairs and values of 10 x 128; a page of 256 tokens of one layer
+    assert served.slot_bytes == (9 * (16 * 5120 * 4 + 3 * 5120 * 2)
+                                 + 8 * 2 * 10 * 512 * 128 * 2) == 24197120
+    assert served.page_bytes == 2 * 10 * 256 * 128 * 2 == 1310720
+    assert served.pool_shape == (1, 641, 10, 256, 128)
+
+
+@pytest.mark.parametrize("gen,slot_bytes,page_bytes", [
+    (GEN, 21504, 16384), (LAGUNA, 12288, 8192), (PHI4FLASH, 18944, 4096)],
+    ids=["olmo_hybrid", "laguna", "phi4flash"])
+def test_slot_and_page_bytes_of_the_three_layouts(gen, slot_bytes,
+                                                  page_bytes):
+    """What admission is charged, by layout: held to the byte."""
+    st = SequenceState(gen)
+    assert (st.slot_bytes, st.page_bytes) == (slot_bytes, page_bytes)
+    assert st.capacity_bytes == 4 * slot_bytes + 32 * page_bytes
 
 
 def test_a_long_prompt_holds_no_more_ring_bytes_than_a_short_one():
@@ -126,7 +166,7 @@ def test_admit_reserves_slot_and_pages_and_release_frees_them(gen):
     ("no_bytes", dict(state_bytes_budget=200000), (100, 8), (100, 8)),
 ])
 def test_refuses_by_what_runs_out(gen, reason, change, first, second):
-    if reason == "no_bytes" and gen is LAGUNA:
+    if reason == "no_bytes" and gen is not GEN:
         # its state is smaller: a budget one admission fits and two do not
         probe = SequenceState(gen)
         change = dict(state_bytes_budget=int(1.5 * (

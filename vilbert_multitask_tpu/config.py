@@ -830,12 +830,134 @@ class LagunaConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Phi4FlashConfig:
+    """A decoder-hybrid-decoder of ``model_type: phi4flash`` (arXiv:
+    2507.06607): a *self-decoder* whose layers alternate a Mamba mixer
+    (selective scan) with differential attention (windowed; the last one
+    full, and paged), then a *cross-decoder* whose layers alternate a gated
+    memory unit, which reads the scan output of the self-decoder's last
+    Mamba layer, with differential cross-attention over the keys and values
+    of the self-decoder's full layer. The source ``config.json``'s keys
+    under their own names; the Mamba mixer's sizes, which it does not give,
+    are fields with the family's defaults (Mamba-1). The layers are
+    ``models/phi4flash.py``."""
+
+    model_type: str = "phi4flash"
+    vocab_size: int = 200064
+    hidden_size: int = 2560
+    intermediate_size: int = 10240
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    hidden_act: str = "silu"
+    max_position_embeddings: int = 262144
+    layer_norm_eps: float = 1e-5
+    mb_per_layer: int = 2
+    sliding_window: int = 512
+    tie_word_embeddings: bool = True
+    mlp_bias: bool = False
+    lm_head_bias: bool = False
+    embd_pdrop: float = 0.0
+    resid_pdrop: float = 0.0
+    # Assumed (the source module's defaults; its ``config.json`` has no key).
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int | str = "auto"
+    # Kernel choice (not the source's): the selective scan of
+    # ops/selective_scan.py and both attentions of ops/paged_attention.py as
+    # Pallas kernels; ``pallas_interpret`` is the CPU tests' explicit
+    # choice, never inferred from the backend.
+    use_pallas: bool = True
+    pallas_interpret: bool = False
+
+    def __post_init__(self):
+        if self.model_type != "phi4flash":
+            raise ValueError(f"model_type {self.model_type!r} is not "
+                             "phi4flash")
+        n = self.num_hidden_layers
+        if self.mb_per_layer != 2:
+            raise ValueError("only mb_per_layer 2 (every second layer a "
+                             "Mamba mixer) is implemented")
+        if n % 4 or n < 8:
+            raise ValueError("num_hidden_layers must be a multiple of 4, at "
+                             "least 8: the half-way split needs a Mamba and "
+                             "a full layer to close the self-decoder, a "
+                             "window pair before them and whole pairs after")
+        if (self.hidden_act != "silu" or self.mlp_bias or self.lm_head_bias
+                or not self.tie_word_embeddings):
+            raise ValueError("only silu, no MLP or head bias, tied head")
+        if self.embd_pdrop or self.resid_pdrop:
+            raise ValueError("dropout is not implemented (serving)")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size must divide into the heads")
+        if (self.num_attention_heads % 2 or self.num_key_value_heads % 2
+                or self.num_attention_heads % self.num_key_value_heads):
+            raise ValueError("differential attention pairs heads: even "
+                             "counts, query heads a multiple of the "
+                             "key/value heads")
+        if self.mamba_dt_rank != "auto" and int(self.mamba_dt_rank) < 1:
+            raise ValueError("mamba_dt_rank is 'auto' or a whole number")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def dt_rank(self) -> int:
+        return (-(-self.hidden_size // 16) if self.mamba_dt_rank == "auto"
+                else int(self.mamba_dt_rank))
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """What each layer's mixer is: ``mamba``, ``window`` (differential
+        attention over the last ``sliding_window`` positions), ``full``
+        (differential attention over the sequence: the one paged layer),
+        ``gmu`` (gated memory unit), ``cross`` (differential
+        cross-attention over the full layer's keys and values)."""
+        half = self.num_hidden_layers // 2
+        kinds = []
+        for l in range(self.num_hidden_layers):
+            if l < half:
+                kinds.append("mamba" if l % 2 == 0 else "window")
+            elif l == half:
+                kinds.append("mamba")
+            elif l == half + 1:
+                kinds.append("full")
+            else:
+                kinds.append("gmu" if l % 2 == 0 else "cross")
+        return tuple(kinds)
+
+    def layers_of(self, *kinds: str) -> tuple:
+        return tuple(l for l, k in enumerate(self.layer_kinds) if k in kinds)
+
+    @property
+    def self_decoder_layers(self) -> int:
+        """Layers a prompt token runs: up to the full layer, with it."""
+        return self.num_hidden_layers // 2 + 2
+
+    def tiny(self) -> "Phi4FlashConfig":
+        """The size of the CPU tests: 8 layers (Mamba and window twice, the
+        memory's Mamba, the full layer, a GMU, a cross layer), 64 wide, 8 /
+        4 heads of 8, state 4, window 16."""
+        return dataclasses.replace(
+            self, vocab_size=384, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=8, num_attention_heads=8,
+            num_key_value_heads=4, max_position_embeddings=512,
+            sliding_window=16, mamba_d_state=4, use_pallas=False)
+
+
+@dataclasses.dataclass(frozen=True)
 class GenerateConfig:
     """The text-generation engine (engine/generate.py). ``model`` None: the
     app serves ViLBERT and refuses the ``generate`` task. One ``ServeApp``
     holds one model: a generate app serves no ViLBERT task."""
 
-    model: OlmoHybridConfig | LagunaConfig | None = None
+    model: OlmoHybridConfig | LagunaConfig | Phi4FlashConfig | None = None
     param_dtype: str = "bfloat16"
     # Compiled shapes: tokens a prefill chunk (multiples of the page size
     # and of the scan's 64), sequences a decode step.

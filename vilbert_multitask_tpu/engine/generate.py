@@ -123,11 +123,34 @@ _MOE_LOAD = obs.REGISTRY.histogram(
     buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 32.0, 128.0))
 
 
+_STEP_LABELS = ("program",)
+_SELF_ROWS = obs.REGISTRY.counter(
+    "vmt_self_decoder_rows_total",
+    "Real rows a split model's self-decoder ran: prompt tokens and decoded "
+    "tokens.", labelnames=_STEP_LABELS)
+_CROSS_ROWS = obs.REGISTRY.counter(
+    "vmt_cross_decoder_rows_total",
+    "Real rows a split model's cross-decoder and head ran: one a finished "
+    "prompt, and decoded tokens.", labelnames=_STEP_LABELS)
+_SSM_TOKENS = obs.REGISTRY.counter(
+    "vmt_ssm_scan_tokens_total",
+    "Real tokens through a state-space layer's scan (prefill) or step "
+    "(decode), summed over those layers.", labelnames=_STEP_LABELS)
+_SHARED_KV_READS = obs.REGISTRY.counter(
+    "vmt_shared_kv_page_reads_total",
+    "Pages of the one pool that decode steps walked: pages in use times the "
+    "layers that read them (the paged layer itself and every cross layer).")
+
+
 def model_module(model_cfg):
     """The module of ``models/`` that serves this configuration: the one
     its ``model_type`` names (``param_shapes``, ``init_params``,
     ``state_layout``, ``prefill_chunk``, ``decode_step``, ``kernels_on``,
-    ``PREFILL_GRANULE``)."""
+    ``PREFILL_GRANULE``). A module whose prefill stops half-way down the
+    stack for every chunk but a prompt's last says ``PREFILL_SPLIT`` (its
+    ``prefill_chunk`` then takes ``final``) and ``step_work(cfg)``, from
+    which the ``vmt_self_decoder_*`` / ``vmt_ssm_*`` counters are
+    reckoned."""
     return importlib.import_module(
         f"vilbert_multitask_tpu.models.{model_cfg.model_type}")
 
@@ -184,6 +207,9 @@ class GenerateEngine:
         self.gen = gen
         self.model_cfg = gen.model
         self.model_lib = model_lib = model_module(gen.model)
+        # None: every chunk runs the whole stack.
+        self._split = (model_lib.step_work(gen.model)
+                       if getattr(model_lib, "PREFILL_SPLIT", False) else None)
         self.replica_id = replica_id
         self.killed = False
         self.boot_times: Dict[str, float] = {}
@@ -230,12 +256,15 @@ class GenerateEngine:
                 return self._programs[key]
             cfg, gen, model_lib = self.model_cfg, self.gen, self.model_lib
             if family == "prefill":
+                split = self._split is not None
+
                 def run(params, state, x):
+                    final = {"final": x["final"]} if split else {}
                     return model_lib.prefill_chunk(
                         cfg, params, state, x["tokens"], x["slot"],
                         x["start"], x["length"], x["page_row"],
                         x["logit_ids"],
-                        attention_block=PREFILL_ATTENTION_PAGES)
+                        attention_block=PREFILL_ATTENTION_PAGES, **final)
             else:
                 block = min(gen.decode_attention_pages, gen.kv_pages)
 
@@ -265,12 +294,15 @@ class GenerateEngine:
         a dispatch ships (``_abstract_forward_args``, the warm-up)."""
         st, n = self.seqstate, self.gen.max_logit_ids
         if family == "prefill":
-            return {"tokens": np.zeros((bucket,), np.int32),
-                    "slot": np.int32(0), "start": np.int32(0),
-                    "length": np.int32(0),
-                    "page_row": np.full((st.max_pages_per_seq,), st.pages,
-                                        np.int32),
-                    "logit_ids": np.zeros((n,), np.int32)}
+            x = {"tokens": np.zeros((bucket,), np.int32),
+                 "slot": np.int32(0), "start": np.int32(0),
+                 "length": np.int32(0),
+                 "page_row": np.full((st.max_pages_per_seq,), st.pages,
+                                     np.int32),
+                 "logit_ids": np.zeros((n,), np.int32)}
+            if self._split is not None:
+                x["final"] = np.bool_(False)
+            return x
         return {"active": np.zeros((bucket,), np.bool_),
                 "positions": np.zeros((bucket,), np.int32),
                 "write_page": np.full((bucket,), st.pages, np.int32),
@@ -392,12 +424,15 @@ class GenerateEngine:
         with obs.span("engine.prefill", tokens=n, bucket=bucket):
             tokens = np.zeros((bucket,), np.int32)
             tokens[:n] = req.prompt[seq.prefilled:seq.prefilled + n]
-            out = self._call("prefill", bucket, {
-                "tokens": tokens, "slot": np.int32(seq.slot),
-                "start": np.int32(seq.prefilled), "length": np.int32(n),
-                "page_row": self.seqstate.page_row(seq),
-                "logit_ids": req.logit_ids})
+            x = {"tokens": tokens, "slot": np.int32(seq.slot),
+                 "start": np.int32(seq.prefilled), "length": np.int32(n),
+                 "page_row": self.seqstate.page_row(seq),
+                 "logit_ids": req.logit_ids}
+            if self._split is not None:
+                x["final"] = np.bool_(n == left)
+            out = self._call("prefill", bucket, x)
         _PREFILL_TOKENS.inc(n)
+        self._count_split("prefill", n, int(n == left))
         _PREFILL_FILL.observe(n / bucket, bucket=str(bucket))
         self._count_prefill_attention(seq.prefilled, bucket)
         seq.prefilled += n
@@ -439,7 +474,9 @@ class GenerateEngine:
             out = self._call("decode", bucket, x)
         _DECODE_TOKENS.inc(len(reqs))
         _DECODE_FILL.observe(len(reqs) / bucket, bucket=str(bucket))
-        _POOL_FILL.observe(st.stats()["kv_pages_in_use"] / st.pages)
+        in_use = st.stats()["kv_pages_in_use"]
+        _POOL_FILL.observe(in_use / st.pages)
+        self._count_split("decode", len(reqs), len(reqs), pages=in_use)
         for req in reqs:
             req.seq.generated += 1
         self._pending.append((out, list(reqs), [r.seq.slot for r in reqs],
@@ -483,6 +520,20 @@ class GenerateEngine:
             st.layout.paged_layers * paged_attention.prefill_pages_walked(
                 start, bucket, st.page_size, st.layout.query_group,
                 st.max_pages_per_seq))
+
+    def _count_split(self, program: str, rows: int, cross_rows: int,
+                     pages: float = 0) -> None:
+        """One step of a split model into its counters: ``rows`` real rows
+        ran the self-decoder (and every state-space layer), ``cross_rows``
+        of them the cross-decoder and the head: what the call was told
+        (``final``), not what the design says; a decode step walked the
+        ``pages`` in use once a layer that reads the one pool."""
+        if self._split is None:
+            return
+        _SELF_ROWS.inc(rows, program=program)
+        _CROSS_ROWS.inc(cross_rows, program=program)
+        _SSM_TOKENS.inc(rows * self._split["ssm_layers"], program=program)
+        _SHARED_KV_READS.inc(pages * self._split["pool_readers"])
 
     def _count_experts(self, program: str, tokens: int, moe) -> None:
         """One step's expert-layer integers ``moe`` [sparse layers, 3]

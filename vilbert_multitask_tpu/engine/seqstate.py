@@ -103,8 +103,10 @@ class SequenceState:
         if self.pages % min(gen.decode_attention_pages, self.pages):
             raise ValueError("kv_pages must be a multiple of "
                              "decode_attention_pages")
+        # A ring has one slot more than the manager counts: the last
+        # belongs to nobody (``models/decoder.py:_write_ring``).
         self.slot_shapes = {
-            name: a.lead + (self.slots,) + a.shape
+            name: a.lead + (self.slots + int(a.ring),) + a.shape
             for name, a in layout.slot_arrays.items()}
         # One page more than the pool counts: the last belongs to nobody,
         # and is where a padding row's keys and values are written.

@@ -78,15 +78,14 @@ import numpy as np
 
 from vilbert_multitask_tpu.config import FULL_ATTENTION, LagunaConfig
 from vilbert_multitask_tpu.models.decoder import (
-    _NEG,
     SlotArray,
     StateLayout,
-    _by_group,
     _decode_attention,
-    _from_group,
     _head,
     _mm,
     _prefill_attention,
+    _ring_decode,
+    _ring_prefill,
     _rms,
     _write_rows,
 )
@@ -97,8 +96,6 @@ __all__ = ["LagunaConfig", "param_shapes", "init_params", "state_layout",
 
 # Tokens a prefill bucket must be a multiple of (beside the page size).
 PREFILL_GRANULE = 1
-# Query rows a sliding layer's prefill attention takes at once.
-SLIDING_QUERY_BLOCK = 256
 
 
 def param_shapes(cfg: LagunaConfig) -> dict:
@@ -277,61 +274,6 @@ def _close(out, moe_stats):
 
 
 # ----------------------------------------------------------------- prefill
-def _sliding_prefill(cfg, q, k, v, ring_k, ring_v, start):
-    """A sliding layer over a chunk: queries [T, H_l, d] at positions
-    ``start ..``, the chunk's keys and values [T, H_kv, d], and the slot's
-    rings [H_kv, R, d] holding the keys before the chunk. Query rows are
-    taken ``SLIDING_QUERY_BLOCK`` at a time against the ``window + block``
-    keys that can reach them. Returns the context [T, H_l, d] float32."""
-    T, _, d = q.shape
-    kv, R = ring_k.shape[0], ring_k.shape[1]
-    W = cfg.sliding_window
-    block = math.gcd(T, SLIDING_QUERY_BLOCK)
-    before = jnp.mod(start - W + jnp.arange(W), R)      # rows of start - W ..
-    keys = jnp.concatenate([ring_k[:, before], jnp.swapaxes(k, 0, 1)], 1)
-    values = jnp.concatenate([ring_v[:, before], jnp.swapaxes(v, 0, 1)], 1)
-    qh, G = _by_group(q, kv)                            # [kv, G * T, d]
-    qh = qh.reshape(kv, G, T, d)
-    # Column c of ``keys`` is position start - W + c.
-    col = jnp.arange(W + block)
-
-    def rows(b):
-        q_b = jax.lax.dynamic_slice_in_dim(qh, b * block, block, 2
-                                           ).reshape(kv, G * block, d)
-        k_b = jax.lax.dynamic_slice_in_dim(keys, b * block, W + block, 1)
-        v_b = jax.lax.dynamic_slice_in_dim(values, b * block, W + block, 1)
-        scores = jnp.einsum("hqd,hkd->hqk", q_b, k_b,
-                            preferred_element_type=jnp.float32)
-        q_pos = start + b * block + jnp.tile(jnp.arange(block), G)
-        k_pos = start - W + b * block + col
-        seen = ((k_pos[None, :] <= q_pos[:, None])
-                & (q_pos[:, None] - k_pos[None, :] < W)
-                & (k_pos[None, :] >= 0))
-        probs = jax.nn.softmax(
-            jnp.where(seen[None], scores / math.sqrt(d), _NEG), axis=-1)
-        return jnp.einsum("hqk,hkd->hqd", probs.astype(v_b.dtype), v_b,
-                          preferred_element_type=jnp.float32)
-
-    ctx = jax.lax.map(rows, jnp.arange(T // block))     # [nb, kv, G*blk, d]
-    ctx = ctx.reshape(T // block, kv, G, block, d)
-    ctx = jnp.moveaxis(ctx, 0, 2).reshape(kv, G * T, d)
-    return _from_group(ctx, T)
-
-
-def _ring_after(ring, rows, start, length):
-    """The slot's ring [H_kv, R, d] once the chunk's real rows ``rows`` [T,
-    H_kv, d] (positions ``start .. start + length - 1``) are in it: row
-    ``j`` holds the last position at or before the chunk's end that is
-    ``j`` modulo ``R``, from the chunk where that lies in it, else as it
-    was."""
-    R, T = ring.shape[1], rows.shape[0]
-    last = start + length - 1
-    holds = last - jnp.mod(last - jnp.arange(R), R)
-    from_chunk = holds >= start
-    taken = jnp.swapaxes(rows, 0, 1)[:, jnp.clip(holds - start, 0, T - 1)]
-    return jnp.where(from_chunk[None, :, None], taken, ring)
-
-
 def prefill_chunk(cfg: LagunaConfig, params, state, tokens, slot, start,
                   length, page_row, logit_ids, *, attention_block: int = 2):
     """One chunk of one sequence's prompt; arguments as
@@ -375,18 +317,10 @@ def prefill_chunk(cfg: LagunaConfig, params, state, tokens, slot, start,
                     ctx = _prefill_attention(cfg, q, k_pool, v_pool, p,
                                              page_row, start, attention_block)
             else:
-                s = cfg.sliding_layers.index(l)
-                at = (s, slot, 0, 0, 0)
-                size = (1, 1) + ring_k.shape[2:]
-                mine_k = jax.lax.dynamic_slice(ring_k, at, size)[0, 0]
-                mine_v = jax.lax.dynamic_slice(ring_v, at, size)[0, 0]
-                ctx = _sliding_prefill(cfg, q, k, v, mine_k, mine_v, start)
-                ring_k = jax.lax.dynamic_update_slice(
-                    ring_k, _ring_after(mine_k, k, start, length)[None, None],
-                    at)
-                ring_v = jax.lax.dynamic_update_slice(
-                    ring_v, _ring_after(mine_v, v, start, length)[None, None],
-                    at)
+                ctx, ring_k, ring_v = _ring_prefill(
+                    cfg.sliding_window, q, k, v, ring_k, ring_v,
+                    cfg.sliding_layers.index(l), slot, start, length,
+                    kernel=cfg.use_pallas, interpret=cfg.pallas_interpret)
             h = x + _attention_out(ctx, gate, lp)
         ffn, stats = _ffn(cfg, l, _rms(h, lp["mlp_norm"], cfg.rms_norm_eps),
                           lp, real)
@@ -396,47 +330,14 @@ def prefill_chunk(cfg: LagunaConfig, params, state, tokens, slot, start,
     last = jax.lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0),
                                         keepdims=False)
     with jax.named_scope("head"):
-        out = _head(cfg, params, last, logit_ids)
+        out = _head(_rms(last, params["final_norm"], cfg.rms_norm_eps),
+                    params["lm_head"], logit_ids)
     state = dict(state, k=k_pool, v=v_pool, ring_k=ring_k, ring_v=ring_v,
                  token=state["token"].at[slot].set(out["token"]))
     return state, _close(out, moe_stats)
 
 
 # ------------------------------------------------------------------ decode
-def _write_ring(ring, s, rows, index, active):
-    """Row b of ``rows`` [B, H_kv, d] into slot b's ring of sliding layer
-    ``s`` at row ``index[b]``, where ``active[b]``: one dynamic-update-slice
-    a slot, in place (an inactive slot gets back what it held)."""
-    B = rows.shape[0]
-    held = ring[s, jnp.arange(B), :, index]             # [B, H_kv, d]
-    rows = jnp.where(active[:, None, None], rows, held)
-    for b in range(B):
-        ring = jax.lax.dynamic_update_slice(
-            ring, rows[b][None, None, :, None, :], (s, b, 0, index[b], 0))
-    return ring
-
-
-def _sliding_decode(cfg, q, ring_k, ring_v, positions):
-    """One query a slot [B, H_l, d] over the slots' rings [B, H_kv, R, d],
-    which already hold the step's own key at ``positions % R``. Row ``j``
-    of a ring holds the last position at or before the slot's that is ``j``
-    modulo ``R``; it counts where that is no earlier than 0 and inside the
-    window."""
-    B, n, d = q.shape
-    kv, R = ring_k.shape[1], ring_k.shape[2]
-    qg = q.reshape(B, kv, n // kv, d)
-    scores = jnp.einsum("bhgd,bhrd->bhgr", qg, ring_k,
-                        preferred_element_type=jnp.float32)
-    pos = positions[:, None]
-    holds = pos - jnp.mod(pos - jnp.arange(R)[None, :], R)
-    seen = (holds >= 0) & (pos - holds < cfg.sliding_window)
-    probs = jax.nn.softmax(jnp.where(seen[:, None, None, :],
-                                     scores / math.sqrt(d), _NEG), axis=-1)
-    ctx = jnp.einsum("bhgr,bhrd->bhgd", probs.astype(ring_v.dtype), ring_v,
-                     preferred_element_type=jnp.float32)
-    return ctx.reshape(B, n, d)
-
-
 def decode_step(cfg: LagunaConfig, params, state, active, positions,
                 write_page, page_slot, page_pos, pool_blocks, logit_ids, *,
                 attention_block: int = 32):
@@ -445,10 +346,8 @@ def decode_step(cfg: LagunaConfig, params, state, active, positions,
     the head's output [B], with ``moe`` [sparse layers, 3]."""
     B = positions.shape[0]
     page = state["k"].shape[3]
-    R = state["ring_k"].shape[3]
     x = params["embed"][state["token"][:B]].astype(jnp.float32)
     offset = positions % page
-    ring_row = positions % R
 
     k_pool, v_pool = state["k"], state["v"]
     ring_k, ring_v = state["ring_k"], state["ring_v"]
@@ -473,11 +372,10 @@ def decode_step(cfg: LagunaConfig, params, state, active, positions,
                 else:
                     ctx = _decode_attention(cfg, *pool_args)
             else:
-                s = cfg.sliding_layers.index(l)
-                ring_k = _write_ring(ring_k, s, k, ring_row, active)
-                ring_v = _write_ring(ring_v, s, v, ring_row, active)
-                ctx = _sliding_decode(cfg, q, ring_k[s, :B], ring_v[s, :B],
-                                      positions)
+                ctx, ring_k, ring_v = _ring_decode(
+                    cfg.sliding_window, q, k, v, ring_k, ring_v,
+                    cfg.sliding_layers.index(l), positions, active,
+                    kernel=cfg.use_pallas, interpret=cfg.pallas_interpret)
             h = x + _attention_out(ctx, gate, lp)
         ffn, stats = _ffn(cfg, l, _rms(h, lp["mlp_norm"], cfg.rms_norm_eps),
                           lp, active)
@@ -485,7 +383,8 @@ def decode_step(cfg: LagunaConfig, params, state, active, positions,
         if stats is not None:
             moe_stats.append(stats)
     with jax.named_scope("head"):
-        out = _head(cfg, params, x, logit_ids)
+        out = _head(_rms(x, params["final_norm"], cfg.rms_norm_eps),
+                    params["lm_head"], logit_ids)
     token = jnp.where(active, out["token"], state["token"][:B])
     state = dict(state, k=k_pool, v=v_pool, ring_k=ring_k, ring_v=ring_v,
                  token=jax.lax.dynamic_update_slice_in_dim(

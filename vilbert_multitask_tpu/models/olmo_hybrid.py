@@ -289,7 +289,8 @@ def prefill_chunk(cfg: OlmoHybridConfig, params, state, tokens, slot, start,
     conv = jnp.stack(convs).reshape(conv.shape)
     last = jax.lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0),
                                         keepdims=False)
-    out = _head(cfg, params, last, logit_ids)
+    out = _head(_rms(last, params["final_norm"], cfg.rms_norm_eps),
+                params["lm_head"], logit_ids)
 
     def put(whole, one):
         return jax.lax.dynamic_update_index_in_dim(
@@ -360,7 +361,8 @@ def decode_step(cfg: OlmoHybridConfig, params, state, active, positions,
         else:
             ctx = _decode_attention(cfg, *pool_args)
         x = _close_block(cfg, x, _mm(ctx.reshape(B, -1), full["wo"]), full)
-    out = _head(cfg, params, x, logit_ids)
+    out = _head(_rms(x, params["final_norm"], cfg.rms_norm_eps),
+                params["lm_head"], logit_ids)
     token = jnp.where(active, out["token"], state["token"][:B])
     state = dict(state, k=k_pool, v=v_pool, rec=rec, conv=conv,
                  token=jax.lax.dynamic_update_slice_in_dim(

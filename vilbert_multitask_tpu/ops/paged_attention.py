@@ -4,7 +4,15 @@ slot against every page of the pool that is in use, and
 :func:`paged_prefill_attention`, a prefill chunk's queries, causally,
 against the pages of their own sequence. Both read a page from HBM in
 place, once for its keys and once for its values, and send no score
-through memory.
+through memory. Beside them the two kernels of a window layer's *ring*
+(the slot's last ``window`` keys and values, ``[S, slots + 1, H_kv, R,
+D]``): :func:`ring_decode_attention`, a decode step's queries each over its
+own slot's ring, read in place one slot a grid step, and :func:`ring_store`,
+one slot's ring written into the donated array by one copy. Both exist
+because the ``jax.numpy`` forms (``models/decoder.py``'s ``_sliding_decode``
+and a ``dynamic_update_slice``; the CPU's path and the oracles) let the
+compiler choose another layout for the whole array and copy it in and out
+of every step; a kernel's operand keeps the layout it came in.
 
 The pool of one kind (keys, or values) is ``[P, pages + 1, H_kv, page, D]``:
 layer, page, key/value head, token in the page, width (the last page
@@ -345,3 +353,90 @@ def paged_prefill_attention(q, k_pool, v_pool, p: int, page_row, start, *,
       v_pool)
     return jnp.transpose(out.reshape(tiles, Hk, G, tq, D), (0, 3, 1, 2, 4)
                          ).reshape(T, H, D)
+
+
+# --------------------------------------------------------------------- ring
+def _ring_kernel(pos_ref, row_ref, q_ref, k_ref, v_ref, o_ref, *,
+                 scale: float, window: int):
+    b = pl.program_id(0)
+    R = k_ref.shape[1]
+    rows = q_ref.shape[1]
+    # Row j holds the last position at or before the slot's that is j
+    # modulo R: ``back`` positions before it.
+    j = jax.lax.broadcasted_iota(jnp.int32, (rows, R), 1)
+    back = jnp.where(j <= row_ref[b], row_ref[b] - j, row_ref[b] - j + R)
+    seen = ((back <= pos_ref[b]) & (back < window))[None]
+    s = jnp.einsum("hgd,hrd->hgr", q_ref[...], k_ref[...],
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(seen, s * scale, _NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    o_ref[...] = jnp.einsum(
+        "hgr,hrd->hgd", p.astype(v_ref.dtype), v_ref[...],
+        preferred_element_type=jnp.float32) / p.sum(-1, keepdims=True)
+
+
+def ring_decode_attention(q, ring_k, ring_v, s: int, positions, window: int,
+                          *, interpret: bool = False):
+    """One query a slot ``q`` [B, H, D] (row b is slot b, at position
+    ``positions[b]``) over the slots' rings of window layer ``s``, ``ring_k``
+    / ``ring_v`` [S, slots, H_kv, R, D] read in place, one slot a grid
+    step: position ``p`` lives at row ``p % R``, and a row counts where the
+    position it holds is no earlier than 0 and inside the window (``window
+    <= R``). The mathematics is ``models/decoder.py:_sliding_decode``'s.
+    Returns the context [B, H, D] float32 (the profile's
+    ``ring_decode_attention`` custom call)."""
+    B, H, D = q.shape
+    Hk, R = ring_k.shape[2], ring_k.shape[3]
+    if H % Hk:
+        raise ValueError(f"{H} query heads do not divide into {Hk} "
+                         "key/value heads")
+    G = H // Hk
+    rows = -(-G // 16) * 16             # an operand tile's sublanes
+    qh = jnp.pad(q.reshape(B, Hk, G, D),
+                 ((0, 0), (0, 0), (0, rows - G), (0, 0)))
+    mine = pl.BlockSpec((None, Hk, rows, D), lambda b, *tables: (b, 0, 0, 0))
+    ring = pl.BlockSpec((None, None, Hk, R, D),
+                        lambda b, *tables: (s, b, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_ring_kernel, scale=1.0 / math.sqrt(D),
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[mine, ring, ring], out_specs=mine),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="ring_decode_attention",
+        interpret=interpret,
+    )(positions.astype(jnp.int32), (positions % R).astype(jnp.int32), qh,
+      ring_k, ring_v)
+    return out[:, :, :G].reshape(B, H, D)
+
+
+def _store_kernel(at_ref, new_ref, ring_hbm, out_hbm, sem, *, s: int):
+    del ring_hbm                        # the same buffer as ``out_hbm``
+    copy = pltpu.make_async_copy(new_ref, out_hbm.at[s, at_ref[0]], sem)
+    copy.start()
+    copy.wait()
+
+
+def ring_store(ring, s: int, slot, new, *, interpret: bool = False):
+    """``ring`` [S, slots, H_kv, R, D] with slot ``slot``'s ring of window
+    layer ``s`` replaced by ``new`` [H_kv, R, D], in place: one copy into
+    the donated buffer (a ``dynamic_update_slice`` here lets the compiler
+    choose another layout for the whole array and copy it in and out: 2.7
+    GB each way at the served size)."""
+    return pl.pallas_call(
+        functools.partial(_store_kernel, s=s),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(new.shape, lambda i, at: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(ring.shape, ring.dtype),
+        input_output_aliases={2: 0},
+        name="ring_store",
+        interpret=interpret,
+    )(jnp.reshape(slot, (1,)).astype(jnp.int32), new.astype(ring.dtype), ring)

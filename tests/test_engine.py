@@ -619,3 +619,342 @@ def test_input_cache_stats_counts(tiny_config):
     eng.run(req)
     s = eng.input_cache_stats
     assert s["entries"] == 1 and s["misses"] == 1 and s["hits"] >= 1
+
+
+# ---------------------------------------- residency is asked before the read
+# (ISSUE 35) prepare_from_store takes a file's identity, asks the device
+# cache for it and reads only what the device does not hold; the pack stays
+# the authority.
+from vilbert_multitask_tpu import obs  # noqa: E402
+from vilbert_multitask_tpu.features.store import (  # noqa: E402
+    FeatureStore,
+    save_reference_npy,
+)
+
+
+class CountingStore(FeatureStore):
+    """A FeatureStore that notes every ``fetch``; file loads are the
+    store's own ``vmt_feature_store_*`` counters."""
+
+    def __init__(self, root):
+        super().__init__(root)
+        self.fetched = []
+
+    def fetch(self, image_path):
+        self.fetched.append(image_path)
+        return super().fetch(image_path)
+
+
+class PlainStore:
+    """A store without ``fetch`` (and so without identities): the engine
+    reads every row of every request through ``get_batch``, caches nothing.
+    Wrapped around a real store, it is the read path the others are held
+    to, and the minimal test double ``prepare_from_store`` always took."""
+
+    def __init__(self, root):
+        self._store = FeatureStore(root)
+
+    def get_batch(self, image_paths):
+        return self._store.get_batch(image_paths)
+
+
+class NoIdentityStore(PlainStore):
+    """``fetch`` but no ``identity``: keys for the device cache as ever, and
+    nothing to ask the device with before the read."""
+
+    def fetch(self, image_path):
+        return self._store.fetch(image_path)
+
+
+def _write_image(root, name, seed, *, boxes=6, dim=32, size=None):
+    """One reference-schema file; sizes and box counts differ by seed so a
+    frame taken from the wrong row shows in a decoded box."""
+    rng = np.random.RandomState(seed)
+    xy = rng.uniform(0, 200, size=(boxes, 4)).astype(np.float32)
+    xy[:, 2:] = xy[:, :2] + 10 + xy[:, 2:] * 0.3
+    width, height = size or (320 + 16 * (seed % 7), 300 + 8 * (seed % 5))
+    region = RegionFeatures(
+        features=rng.randn(boxes, dim).astype(np.float32), boxes=xy,
+        image_width=width, image_height=height)
+    save_reference_npy(f"{root}/{name}.npy", region, name)
+
+
+def _store_reads():
+    return (obs.FEATURE_STORE_HITS.value() + obs.FEATURE_STORE_MISSES.value())
+
+
+def _intake_rows():
+    return {k: getattr(obs, f"INTAKE_ROWS_{k.upper()}").value()
+            for k in ("resident", "read", "late")}
+
+
+def _rose(before):
+    return {k: v - before[k] for k, v in _intake_rows().items()}
+
+
+@pytest.fixture(scope="module")
+def gallery(tiny_config, tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("gallery"))
+    for i in range(14):
+        _write_image(root, f"g{i:02d}", seed=100 + i, boxes=4 + i % 5,
+                     dim=tiny_config.v_feature_size)
+    return root
+
+
+def _resident_cfg(tiny_config, **kw):
+    return FrameworkConfig(
+        model=tiny_config,
+        engine=_cpu_engine_cfg(max_regions=11, image_buckets=(1, 2, 10),
+                               throughput_buckets=None, **kw))
+
+
+def _engine_pair(tiny_config, root, **kw):
+    """(engine under test, its counting store, the read-path engine): one
+    parameter tree, so equal inputs give equal bits."""
+    store = CountingStore(root)
+    eng = InferenceEngine(_resident_cfg(tiny_config, **kw), seed=0,
+                          feature_store=store)
+    ref = InferenceEngine(_resident_cfg(tiny_config), params=eng.params,
+                          feature_store=PlainStore(root))
+    return eng, store, ref
+
+
+@pytest.fixture(scope="module")
+def served_pair(tiny_config, gallery):
+    return _engine_pair(tiny_config, gallery)
+
+
+def _answer(engine, task_id, question, paths, many=False):
+    req = engine.prepare_from_store(task_id, question, paths)
+    if many:
+        return req, engine.run_many([req])[0]
+    return req, engine.run(req)[1]
+
+
+# family -> (task, images of the request, images made resident beforehand)
+RESIDENT_CASES = {
+    "labels": (1, ["g00.jpg"], []),
+    "binary_one_resident_one_carried": (12, ["g01.jpg", "g02.jpg"],
+                                        ["g01.jpg"]),
+    "trinary": (13, ["g03.jpg"], []),
+    "ranking_over_10": (7, [f"g{i:02d}.jpg" for i in range(4, 14)], []),
+    "grounding": (4, ["g03.jpg"], []),
+}
+
+
+@pytest.mark.parametrize("many", [False, True], ids=["run", "run_many"])
+@pytest.mark.parametrize("family", sorted(RESIDENT_CASES))
+def test_resident_rows_are_not_read_and_decode_bit_for_bit(
+        served_pair, family, many):
+    """With every row on the device the intake makes no ``fetch`` and no
+    store read, carries no host tensor, and the decoded result is the read
+    path's, bit for bit: the request's first serving (all read, or the
+    NLVR2 pair's one row) and its second (all resident) both equal an
+    engine that reads every row every time."""
+    eng, store, ref = served_pair
+    task_id, paths, first = RESIDENT_CASES[family]
+    question = TASK_QUESTIONS[task_id]
+    _, want = _answer(ref, task_id, question, paths, many)
+    for p in first:  # resident through a request of its own
+        eng.run(eng.prepare_from_store(1, "what is this", [p]))
+    held = eng.resident_frames([store.identity(p) for p in paths])
+    n_held = sum(f is not None for f in held)
+
+    store.fetched.clear()
+    rows = _intake_rows()
+    req, got = _answer(eng, task_id, question, paths, many)
+    assert got == want
+    assert store.fetched == [p for p, f in zip(paths, held) if f is None]
+    assert _rose(rows) == {"resident": n_held, "read": len(paths) - n_held,
+                           "late": 0}
+    if 0 < n_held < len(paths):  # the pair: one frame, one carried row
+        assert [f is not None for f in req.frames] == [True, False]
+        assert req.features.shape[0] == 1
+        assert [h is None for h in req.host_rows()] == [True, False]
+
+    store.fetched.clear()
+    rows, reads = _intake_rows(), _store_reads()
+    req, got = _answer(eng, task_id, question, paths, many)
+    assert got == want
+    assert store.fetched == [] and _store_reads() == reads
+    assert _rose(rows) == {"resident": len(paths), "read": 0, "late": 0}
+    assert req.features is None and req.spatials is None
+    assert req.host_rows() == [None] * len(paths)
+    assert [(m.width, m.height) for m in req.images] == [
+        (r.image_width, r.image_height)
+        for r in FeatureStore(store.root).get_batch(paths)]
+
+
+@pytest.mark.parametrize("what", ["mtime", "size"])
+def test_a_replaced_feature_file_is_read_again(tiny_config, tmp_path, what):
+    """The identity is taken anew for every request (path, mtime, size): a
+    file replaced between two requests is a miss, is read, and its new
+    content is what the second request is answered from."""
+    import os
+
+    root = str(tmp_path)
+    dim = tiny_config.v_feature_size
+    _write_image(root, "pic", seed=1, boxes=5, dim=dim)
+    eng, store, ref = _engine_pair(tiny_config, root)
+    _, first = _answer(eng, 4, TASK_QUESTIONS[4], ["pic.jpg"])
+    assert eng.prepare_from_store(4, "q", ["pic.jpg"]).frames is not None
+    before = os.stat(f"{root}/pic.npy")
+    if what == "mtime":  # other content, the same size, a later mtime
+        _write_image(root, "pic", seed=2, boxes=5, dim=dim)
+        os.utime(f"{root}/pic.npy", ns=(before.st_atime_ns,
+                                        before.st_mtime_ns + 1_000_000))
+        assert os.stat(f"{root}/pic.npy").st_size == before.st_size
+    else:  # another size under the very same mtime
+        _write_image(root, "pic", seed=2, boxes=7, dim=dim)
+        os.utime(f"{root}/pic.npy", ns=(before.st_atime_ns,
+                                        before.st_mtime_ns))
+        assert os.stat(f"{root}/pic.npy").st_size != before.st_size
+    store.fetched.clear()
+    rows = _intake_rows()
+    req, second = _answer(eng, 4, TASK_QUESTIONS[4], ["pic.jpg"])
+    assert store.fetched == ["pic.jpg"] and req.frames is None
+    assert _rose(rows) == {"resident": 0, "read": 1, "late": 0}
+    _, want = _answer(ref, 4, TASK_QUESTIONS[4], ["pic.jpg"])
+    assert second == want and second != first
+
+
+@pytest.mark.parametrize("case", ["run_labels", "run_many_grounding",
+                                  "evicted_and_replaced"])
+def test_a_row_evicted_between_intake_and_pack_is_read_late(
+        tiny_config, tmp_path, case):
+    """The pack is the authority: a row the intake called resident and the
+    LRU has dropped since is read and encoded at the pack, the request is
+    answered as the read path answers it (from the file as it is THEN), and
+    the row is counted ``late``."""
+    root = str(tmp_path)
+    dim = tiny_config.v_feature_size
+    for i, name in enumerate(("pic", "other_a", "other_b")):
+        _write_image(root, name, seed=10 + i, boxes=5 + i, dim=dim)
+    eng, store, ref = _engine_pair(tiny_config, root,
+                                   device_input_cache_entries=2)
+    task_id = 1 if case == "run_labels" else 4
+    question = TASK_QUESTIONS[task_id]
+    eng.predict(task_id, question, ["pic.jpg"])
+    req = eng.prepare_from_store(task_id, question, ["pic.jpg"])
+    assert req.frames is not None and req.features is None
+    for other in ("other_a.jpg", "other_b.jpg"):  # two slots: pic goes
+        eng.predict(1, "what is this", [other])
+    assert eng.resident_frames(req.cache_keys) == [None]
+    if case == "evicted_and_replaced":
+        _write_image(root, "pic", seed=99, boxes=8, dim=dim)
+    store.fetched.clear()
+    rows = _intake_rows()
+    if case == "run_many_grounding":
+        got = eng.run_many([req])[0]
+    else:
+        got = eng.run(req)[1]
+    assert store.fetched == ["pic.jpg"]
+    assert _rose(rows) == {"resident": 0, "read": 0, "late": 1}
+    _, want = _answer(ref, task_id, question, ["pic.jpg"])
+    assert got == want
+    # The request learned what it was packed from; the row is resident again.
+    assert req.cache_keys == [store.identity("pic.jpg")]
+    assert eng.resident_frames(req.cache_keys)[0] is not None
+    rows = _intake_rows()
+    assert eng.run(req)[1] == want and _rose(rows)["late"] == 0
+
+
+def test_a_cache_smaller_than_a_pack_never_hands_a_slot_out_twice(
+        tiny_config, gallery):
+    """Two cache slots, a pack of ten: rows the cache holds are pinned
+    before anything is inserted and the overflow rides scratch slots, so
+    every row of the pack has a slot of its own and the answer is the read
+    path's."""
+    eng, _, ref = _engine_pair(tiny_config, gallery,
+                               device_input_cache_entries=2)
+    paths = [f"g{i:02d}.jpg" for i in range(10)]
+    eng.predict(1, "what is this", [paths[9]])  # resident, last in the pack
+    req = eng.prepare_from_store(7, TASK_QUESTIONS[7], paths)
+    assert [f is not None for f in req.frames] == [False] * 9 + [True]
+    _, slots = eng._pack_rows(eng._request_rows(req), req.bucket)
+    assert len(set(slots.tolist())) == 10
+    _, want = _answer(ref, 7, TASK_QUESTIONS[7], paths)
+    assert eng.run(req)[1] == want
+
+
+@pytest.mark.parametrize("case", ["mesh", "no_fetch", "no_identity",
+                                  "cache_off"])
+def test_who_cannot_ask_the_device_reads_every_row_as_before(
+        tiny_config, gallery, case):
+    """Mesh serving, a store without ``fetch`` (or without ``identity``)
+    and an engine without a device cache run the code they ran: every row
+    read and encoded on every request, the bucket-padded arrays carried."""
+    kw, mesh, store = {}, None, CountingStore(gallery)
+    if case == "mesh":
+        mesh = build_mesh(MeshConfig(dp=4, tp=2))
+    elif case == "no_fetch":
+        store = PlainStore(gallery)
+    elif case == "no_identity":
+        store = NoIdentityStore(gallery)
+    else:
+        kw = dict(device_input_cache_entries=0)
+    cfg = dataclasses.replace(_resident_cfg(tiny_config, **kw),
+                              mesh=MeshConfig(dp=4, tp=2))
+    eng = InferenceEngine(cfg, seed=0, feature_store=store, mesh=mesh)
+    paths = ["g00.jpg", "g01.jpg", "g02.jpg"]
+    for serving in range(2):
+        rows = _intake_rows()
+        req = eng.prepare_from_store(7, TASK_QUESTIONS[7], paths)
+        assert _rose(rows) == {"resident": 0, "read": 3, "late": 0}
+        assert req.frames is None and req.features.shape[0] == req.bucket
+        assert len(req.host_rows()) == 3 and None not in req.host_rows()
+        assert (req.cache_keys is None) == (case in ("no_fetch",
+                                                     "cache_off"))
+        if case in ("no_identity", "cache_off"):
+            assert len(eng.run(req)[1].ranking) == 3
+
+
+def test_intakes_and_packs_race_an_evicting_cache_and_every_answer_stands(
+        tiny_config, gallery):
+    """Stress: more threads than cores prepare and run against a device
+    cache of three slots over fourteen images, so rows the intake was
+    promised are evicted before their pack all the time. Every answer is
+    the read path's, the frames stay the cache's key for key, and the three
+    ways a row can come in add up to the rows asked for."""
+    import os
+    import sys
+    import threading
+
+    eng, _, ref = _engine_pair(tiny_config, gallery,
+                               device_input_cache_entries=3)
+    images = [f"g{i:02d}.jpg" for i in range(14)]
+    want = {p: _answer(ref, 4, TASK_QUESTIONS[4], [p])[1] for p in images}
+    eng.predict(4, TASK_QUESTIONS[4], images[:1])  # compiled before the race
+    threads, rounds = 2 * (os.cpu_count() or 4), 12
+    rows = _intake_rows()
+    wrong, errors = [], []
+
+    def worker(k):
+        try:
+            for j in range(rounds):
+                path = images[(5 * k + 3 * j) % len(images)]
+                got = _answer(eng, 4, TASK_QUESTIONS[4], [path])[1]
+                if got != want[path]:
+                    wrong.append(path)
+        except Exception as e:  # noqa: BLE001 - reported by the assert below
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pool = [threading.Thread(target=worker, args=(k,), daemon=True)
+                for k in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in pool)
+    assert errors == [] and wrong == []
+    rose = _rose(rows)
+    assert rose["resident"] + rose["read"] == threads * rounds
+    assert rose["late"] <= rose["resident"]
+    with eng._input_cache_lock:
+        assert set(eng._input_frames) == set(eng._input_cache)
+        assert len(eng._input_cache) <= 3

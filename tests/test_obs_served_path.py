@@ -145,6 +145,8 @@ def test_store_read_and_host_encode_are_two_spans_apart(served):
     # ``engine.features`` apart went with the second use.
     assert "source" not in read.attrs and "source" not in encode.attrs
     assert read.attrs["n_images"] == encode.attrs["n_images"] == 1
+    # Nothing was on the device yet: the one row was read, none resident.
+    assert (read.attrs["resident"], read.attrs["read"]) == (0, 1)
     assert read.start_s + read.dur_s <= encode.start_s
     dispatch = by_name["engine.dispatch"]
     assert (dispatch.attrs["rows"], dispatch.attrs["bucket"]) == (1, 1)
@@ -224,6 +226,93 @@ def test_input_cache_counters_equal_input_cache_stats(fresh_engine):
     assert (hits, misses) == (s1["hits"] - s0["hits"],
                               s1["misses"] - s0["misses"]) == (3, 1)
     assert inserts == misses + 2
+
+
+# ------------------------------------------- the intake's rows, by path
+def test_the_features_span_and_the_counters_say_resident_or_read(
+        fresh_engine, features_dir):
+    """``engine.features`` carries how many of a request's rows the intake
+    found on the device and how many it read, the three
+    ``vmt_intake_rows_*`` counters count the same rows, and a request whose
+    rows are all resident opens no ``engine.encode``. A resident row is
+    neither a hit nor a miss of the store's host LRU."""
+    eng = fresh_engine
+    for name in ("img_a.jpg", "img_b.jpg"):  # on the device, whoever ran
+        eng.predict(1, "what is this", [name])
+    tracer = obs.default_tracer()
+    names = ("INTAKE_ROWS_RESIDENT", "INTAKE_ROWS_READ", "INTAKE_ROWS_LATE")
+
+    def prepared(paths):
+        tracer.clear()
+        before = [getattr(obs, n).value() for n in names]
+        store = _store_counters()
+        eng.prepare_from_store(7, "a caption", paths)
+        rose = tuple(getattr(obs, n).value() - b
+                     for n, b in zip(names, before))
+        spans = {s.name: s for s in tracer.spans()}
+        return rose, spans, {k: v - store[k]
+                             for k, v in _store_counters().items()}
+
+    rose, spans, store = prepared(["img_a.jpg", "img_b.jpg"])
+    assert rose == (2, 0, 0)
+    attrs = spans["engine.features"].attrs
+    assert (attrs["n_images"], attrs["resident"], attrs["read"]) == (2, 2, 0)
+    assert "engine.encode" not in spans and "engine.tokenize" in spans
+    assert not any(store.values())
+
+    copy = os.path.join(features_dir, "img_a_again.npy")
+    shutil.copy(os.path.join(features_dir, "img_a.npy"), copy)
+    try:  # a file the device has not seen, beside one it holds
+        rose, spans, store = prepared(["img_a_again.jpg", "img_b.jpg"])
+    finally:
+        os.remove(copy)
+    assert rose == (1, 1, 0)
+    attrs = spans["engine.features"].attrs
+    assert (attrs["resident"], attrs["read"]) == (1, 1)
+    assert spans["engine.encode"].attrs["n_images"] == 1
+    assert (store["FEATURE_STORE_HITS"]
+            + store["FEATURE_STORE_MISSES"]) == 1
+
+
+@pytest.mark.parametrize("name,cell,moves", [
+    ("intake_resident_row_share.saturated", "base.saturated", "rows_per_s"),
+    ("intake_resident_row_share.interactive", "base.interactive",
+     "latency_p50_ms"),
+])
+def test_the_benchmark_reads_the_intake_counters(name, cell, moves):
+    """Both ``per_layer`` entries resolve to the one reader file, and every
+    counter it names is in the registry once the engine is imported: the
+    harness leaves a metric out whose counter it cannot find."""
+    from benchmark.harness.spec import ROOT, reader_file
+    from benchmark.reduce import readers
+    from vilbert_multitask_tpu.engine import runtime  # noqa: F401
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == name]
+    assert (entry["workloads"], entry["moves"], entry["layer"],
+            entry["source"], entry["better"], entry["unit"]) == (
+        [cell], moves, "engine", "program_counter", "higher", "%")
+    path = reader_file(name)
+    assert os.path.basename(path) == "intake_resident_row_share.json"
+    with open(path) as f:
+        reader = json.load(f)
+    assert readers.find_kind(reader["kind"]) is readers.counter_ratio
+    counters = {i.name for i in obs.REGISTRY.instruments()
+                if i.kind == "counter"}
+    params = reader["params"]
+    assert set(params["numerator"]) == {"vmt_intake_rows_resident_total"}
+    assert set(params["denominator"]) == {
+        "vmt_intake_rows_resident_total", "vmt_intake_rows_read_total",
+        "vmt_intake_rows_late_total"} <= counters
+    # 3 resident, 1 read, 0 late: 75%; nothing counted: left out.
+    ctx = {"counters": {"before": dict.fromkeys(counters, 0.0),
+                        "after": {**dict.fromkeys(counters, 0.0),
+                                  "vmt_intake_rows_resident_total": 3.0,
+                                  "vmt_intake_rows_read_total": 1.0}}}
+    assert readers.counter_ratio(ctx, **params) == 75.0
+    ctx["counters"]["after"] = ctx["counters"]["before"]
+    assert readers.counter_ratio(ctx, **params) is None
 
 
 # ------------------------------------------------------------ intake polls

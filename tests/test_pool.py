@@ -553,3 +553,74 @@ def test_add_then_retire_roundtrip_keeps_pool_consistent():
     info = pool.retire_replica()
     assert pool.ready_count() == 1
     assert info["name"] not in {r.name for r in pool.replicas}
+
+
+# ------------------------------- residency is every replica's (ISSUE 35)
+@pytest.fixture(scope="module")
+def two_engines(tiny_framework_cfg, features_dir, tmp_path_factory):
+    """Two real tiny engines over one parameter tree and one store (a
+    directory of this module's own: other modules rewrite img_a)."""
+    import shutil
+
+    from vilbert_multitask_tpu.engine.runtime import InferenceEngine
+    from vilbert_multitask_tpu.features.store import FeatureStore
+
+    root = tmp_path_factory.mktemp("pool_features")
+    for name in ("img_a", "img_b"):
+        shutil.copy(f"{features_dir}/{name}.npy", root)
+    store = FeatureStore(str(root))
+    r0 = InferenceEngine(tiny_framework_cfg, feature_store=store,
+                         replica_id="r0")
+    r1 = InferenceEngine(tiny_framework_cfg, params=r0.params,
+                         feature_store=store, replica_id="r1")
+    return r0, r1
+
+
+def _evict(engine, key):
+    """What an LRU eviction leaves behind, for one key (held or not)."""
+    with engine._input_cache_lock:
+        if key in engine._input_cache:
+            engine._slab_free.append(engine._input_cache.pop(key))
+            del engine._input_frames[key]
+    assert engine.resident_frames([key]) == [None]
+
+
+@pytest.mark.parametrize("case", ["one_holds", "one_holds_other_dead",
+                                  "both_hold", "both_hold_then_one_evicts"])
+def test_pool_calls_a_row_resident_only_if_every_live_replica_holds_it(
+        two_engines, case):
+    """``ReplicaPool.prepare_from_store`` prepares on replica 0 and the
+    batch may land on either: a row only replica 0 holds is read (a late
+    read must not become the rule), unless the other replica is dead; a
+    row both hold is not; and should the replica a batch lands on have
+    lost the row since, the pack reads it late and the answer stands."""
+    from vilbert_multitask_tpu import obs
+
+    r0, r1 = two_engines
+    pool = ReplicaPool([r0, r1], serving=ServingConfig())
+    pool.mark_ready()
+    image = {"one_holds": "img_a.jpg", "one_holds_other_dead": "img_a.jpg",
+             "both_hold": "img_b.jpg",
+             "both_hold_then_one_evicts": "img_b.jpg"}[case]
+    want = r0.predict(1, "what is this", [image])  # r0 holds the row now
+    if case.startswith("both_hold"):
+        assert r1.predict(1, "what is this", [image]) == want
+    else:
+        _evict(r1, r0.feature_store.identity(image))
+    if case == "one_holds_other_dead":
+        pool.replicas[1].state = STATE_DEAD
+    req = pool.prepare_from_store(1, "what is this", [image])
+    if case == "one_holds":
+        assert req.frames is None and req.features is not None
+    else:
+        assert req.frames is not None and req.features is None
+    late = obs.INTAKE_ROWS_LATE.value()
+    if case == "both_hold_then_one_evicts":
+        _evict(r1, req.cache_keys[0])
+        assert r1.run(req)[1] == want
+        assert obs.INTAKE_ROWS_LATE.value() == late + 1
+    else:
+        for rep in pool.replicas:
+            if rep.state != STATE_DEAD:
+                assert rep.engine.run(req)[1] == want
+        assert obs.INTAKE_ROWS_LATE.value() == late

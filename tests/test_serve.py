@@ -754,6 +754,39 @@ def test_device_cache_misses_when_feature_file_changes(stack, features_dir):
     assert new_keys, "changed file content must mint a NEW cache key"
 
 
+@pytest.mark.parametrize("task_id,images", [
+    (1, ["img_a.jpg"]), (11, ["img_b.jpg"]), (7, ["img_a.jpg", "img_b.jpg"]),
+], ids=["labels", "grounding", "ranking"])
+def test_worker_intake_reads_no_file_for_a_resident_image(stack, task_id,
+                                                          images):
+    """The same question about the same unchanged files, twice through the
+    worker: the second intake finds every row on the device (``resident`` on
+    its ``engine.features`` span, no store read, no ``engine.encode``) and
+    the answer persisted is the first one's."""
+    from vilbert_multitask_tpu import obs
+
+    s, hub, q, store, worker = stack
+    tracer = obs.default_tracer()
+    answers, spans = [], []
+    for _ in range(2):
+        tracer.clear()
+        reads = (obs.FEATURE_STORE_HITS.value()
+                 + obs.FEATURE_STORE_MISSES.value())
+        q.publish(make_job_message(images, "the left thing", task_id,
+                                   "sockR"))
+        assert worker.step() == "acked"
+        answers.append(store.recent()[0]["answer_text"])
+        spans.append({sp.name: sp for sp in tracer.spans()})
+        reads = (obs.FEATURE_STORE_HITS.value()
+                 + obs.FEATURE_STORE_MISSES.value()) - reads
+    assert answers[0] == answers[1]
+    features = spans[1]["engine.features"].attrs
+    assert (features["resident"], features["read"]) == (len(images), 0)
+    assert reads == 0 and "engine.encode" not in spans[1]
+    first = spans[0]["engine.features"].attrs
+    assert first["resident"] + first["read"] == len(images)
+
+
 # ----------------------------------------------------------- observability
 def test_end_to_end_single_trace(stack):
     """The ISSUE-2 acceptance path: one HTTP-submitted request yields ONE

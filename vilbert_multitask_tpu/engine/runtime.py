@@ -40,7 +40,7 @@ import threading
 import time
 from collections import OrderedDict
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +185,19 @@ class _AotProgram:
         return out
 
 
+@dataclasses.dataclass(frozen=True)
+class RowFrame:
+    """What :meth:`InferenceEngine.decode` needs of one image row on the
+    host, 2 KB: the picture's size (``ImageMeta``: ranking, grounding) and
+    the row's normalized boxes (grounding). The engine keeps one beside a
+    row's slab slot from the moment the row is inserted, so a request about
+    a resident row decodes from the bytes the read path would have made."""
+
+    width: int
+    height: int
+    spatials: np.ndarray  # (Nv, 5) f32
+
+
 @dataclasses.dataclass
 class PreparedRequest:
     """Host-side buffers for one request, already bucketed.
@@ -194,21 +207,68 @@ class PreparedRequest:
     the compute dtype anyway — see models/embeddings.py ImageEmbeddings — so
     pre-casting on the host is bit-identical and halves the dominant
     host→device payload), f32 otherwise (test/golden-fixture engines).
+
+    Where the intake found rows on the device (``frames``), the three image
+    arrays hold the OTHER rows only, in request order and unpadded, and are
+    None when there is no other row: read them through :meth:`host_rows`.
     """
 
     spec: TaskSpec
     n_images: int
     bucket: int
     text: EncodedText  # (bucket, Nt)
-    features: np.ndarray  # (bucket, Nv, D) transfer dtype
-    spatials: np.ndarray  # (bucket, Nv, 5) f32 (decode reads these host-side)
-    image_mask: np.ndarray  # (bucket, Nv)
+    features: Optional[np.ndarray]  # (bucket, Nv, D) transfer dtype
+    spatials: Optional[np.ndarray]  # (bucket, Nv, 5) f32
+    image_mask: Optional[np.ndarray]  # (bucket, Nv)
     task_ids: np.ndarray  # (bucket, 1)
     images: List[dec.ImageMeta]
     # Stable per-image identities for the device input cache (one string
     # per REAL image row, length n_images), or None for novel uploads /
     # synthetic defaults. Row-level so any bucket size shares entries.
     cache_keys: Optional[List[str]] = None
+    # One entry per REAL image row: the frame of a row prepare_from_store
+    # found on the device (no host tensors were made for it), None for a
+    # row whose tensors this request carries. None: every row is carried.
+    frames: Optional[List[Optional[RowFrame]]] = None
+
+    def host_rows(self) -> List[Optional[dict]]:
+        """Per real image row its host tensors, or None where the intake
+        found the row on the device."""
+        frames = self.frames or [None] * self.n_images
+        rows: List[Optional[dict]] = []
+        at = 0  # the carried rows lie in request order
+        for frame in frames:
+            if frame is not None:
+                rows.append(None)
+                continue
+            rows.append(dict(features=self.features[at],
+                             spatials=self.spatials[at],
+                             image_mask=self.image_mask[at]))
+            at += 1
+        return rows
+
+    @property
+    def first_spatials(self) -> np.ndarray:
+        """Row 0's normalized boxes (grounding decodes against them)."""
+        if self.frames is not None and self.frames[0] is not None:
+            return self.frames[0].spatials
+        return self.spatials[0]
+
+
+class _Row(NamedTuple):
+    """One image row on its way into a pack."""
+
+    host: Optional[dict]  # features/spatials/image_mask; None: resident
+    key: Optional[str]  # device-cache identity; None: scratch slot
+    image: Optional[dec.ImageMeta] = None  # size; the path of a late read
+    req: Optional[PreparedRequest] = None  # whose row ``index`` this is
+    index: int = 0
+
+    def frame(self) -> RowFrame:
+        """The frame of a row that carries its tensors (a copy: 2 KB must
+        not keep a request's stacked arrays alive)."""
+        return RowFrame(self.image.width, self.image.height,
+                        np.array(self.host["spatials"], np.float32))
 
 
 class InferenceEngine:
@@ -354,6 +414,10 @@ class InferenceEngine:
         # row slab (see _row_slab); the cache maps key → slab slot, LRU
         # over EngineConfig.device_input_cache_entries.
         self._input_cache: "OrderedDict[str, int]" = OrderedDict()
+        # key → RowFrame, the same key set as _input_cache at every release
+        # of the lock: what decode needs of a row whose tensors stayed on
+        # the device (prepare_from_store asks here BEFORE it reads a file).
+        self._input_frames: Dict[str, RowFrame] = {}
         self._input_cache_lock = threading.Lock()
         self._input_cache_hits = 0
         self._input_cache_misses = 0
@@ -901,9 +965,9 @@ class InferenceEngine:
                 host = self._dummy_host(b)
                 text = {k: host[k] for k in
                         ("input_ids", "segment_ids", "input_mask", "task_ids")}
-                rows = [(dict(features=host["features"][i],
-                              spatials=host["spatials"][i],
-                              image_mask=host["image_mask"][i]), None)
+                rows = [_Row(dict(features=host["features"][i],
+                                  spatials=host["spatials"][i],
+                                  image_mask=host["image_mask"][i]), None)
                         for i in range(b)]
                 _, bundle = self._run_rows(b, False, text, rows)
             jax.block_until_ready(bundle["vil_logit"])
@@ -919,29 +983,118 @@ class InferenceEngine:
                 _warm_one(b)
 
     # -------------------------------------------------------------- prepare
-    def prepare_from_store(self, task_id: int, question: str,
-                           image_paths: Sequence[str]) -> PreparedRequest:
-        """prepare() with regions AND device-cache identities from the
-        attached feature store in one read (store.fetch) — the identity is
-        captured at read time, so the cache can never bind a fresh key to
-        stale tensors. The single place the store→cache-key contract lives;
-        serving (_intake) and predict() both come through here. Stores
-        without fetch() (minimal test doubles) just skip device caching."""
+    def prepare_from_store(
+        self, task_id: int, question: str, image_paths: Sequence[str], *,
+        resident: Optional[Callable[[Sequence[str]],
+                                    List[Optional[RowFrame]]]] = None,
+    ) -> PreparedRequest:
+        """prepare() from the attached feature store, asking the device
+        before the disk. The single place the store→cache-key contract
+        lives; serving (_intake) and predict() both come through here.
+
+        Per image, in this order: (1) ``store.identity``: the file is
+        resolved and stat'd, nothing is read; (2) residency: does the
+        device cache hold a row under that identity (``resident``, by
+        default this engine's :meth:`resident_frames`; a pool passes one
+        that asks every replica a batch may land on); (3) only a row the
+        device does NOT hold is read (``store.fetch``, whose own identity,
+        captured before its read, is the key the row is inserted under: the
+        cache can never bind a fresh key to stale tensors), clipped,
+        encoded and carried to the pack. A resident row costs a ``stat`` and
+        a dictionary look-up: no read, no encode, no host tensor; decode
+        gets its size and boxes from the frame kept beside the slot. The
+        identity is taken anew for every request, so a replaced or edited
+        file is a miss and is read. The pack stays the authority
+        (:meth:`_pack_rows`): a row that left the device since is read
+        there, late.
+
+        Step 2 needs the slab path (no mesh, ``device_input_cache_entries``
+        above 0) and a store with ``identity`` and ``fetch``; otherwise every
+        row is read as before. Stores without fetch() (minimal test
+        doubles) also skip device caching."""
         if self.feature_store is None:
             raise RuntimeError("prepare_from_store() needs a FeatureStore; "
                                "use prepare() with in-memory regions instead")
-        fetch = getattr(self.feature_store, "fetch", None)
-        with obs.span("engine.features", n_images=len(image_paths),
-                      task_id=task_id):
-            if fetch is not None:
-                pairs = [fetch(p) for p in image_paths]
-                regions = [r for r, _ in pairs]
-                cache_keys: Optional[List[str]] = [k for _, k in pairs]
+        store = self.feature_store
+        fetch = getattr(store, "fetch", None)
+        identity = getattr(store, "identity", None)
+        n = len(image_paths)
+        frames: List[Optional[RowFrame]] = [None] * n
+        cache_keys: Optional[List[Optional[str]]] = None
+        with obs.span("engine.features", n_images=n, task_id=task_id) as sp:
+            if fetch is None:
+                regions = store.get_batch(image_paths)
             else:
-                regions = self.feature_store.get_batch(image_paths)
-                cache_keys = None
+                cache_keys = [None] * n
+                if (identity is not None and self.mesh is None
+                        and self.cfg.engine.device_input_cache_entries > 0):
+                    cache_keys = [identity(p) for p in image_paths]
+                    frames = (resident or self.resident_frames)(cache_keys)
+                regions = [None] * n
+                for i, path in enumerate(image_paths):
+                    if frames[i] is None:
+                        regions[i], cache_keys[i] = fetch(path)
+            n_resident = sum(f is not None for f in frames)
+            sp.set(resident=n_resident, read=n - n_resident)
+        obs.INTAKE_ROWS_RESIDENT.inc(n_resident)
+        obs.INTAKE_ROWS_READ.inc(n - n_resident)
         return self.prepare(task_id, question, regions, image_paths,
-                            cache_keys=cache_keys)
+                            cache_keys=cache_keys,
+                            frames=frames if n_resident else None)
+
+    def resident_frames(self, keys: Sequence[str]
+                        ) -> List[Optional[RowFrame]]:
+        """Per identity, the frame of the row the device cache holds under
+        it, else None; one lock hold for a request's keys. A row found is
+        moved to the young end of the LRU (what an intake was just promised
+        should be the last to go before its pack), but neither hit nor miss
+        is counted: the pack counts, once a row."""
+        with self._input_cache_lock:
+            frames = [self._input_frames.get(k) for k in keys]
+            for key, frame in zip(keys, frames):
+                if frame is not None:
+                    self._input_cache.move_to_end(key)
+        return frames
+
+    def _read_late(self, gone: Sequence[_Row]) -> List[_Row]:
+        """The pack's late path: rows the intake called resident, read and
+        encoded from the store after all, through the steps of
+        prepare_from_store's read path. Each comes back carrying its
+        tensors under ``fetch``'s own identity, and its request learns the
+        key, size and frame of what it is now packed from (the file may
+        have been replaced since the intake's ``stat``)."""
+        pairs = [self.feature_store.fetch(r.image.path) for r in gone]
+        regions, feats, spatials, masks = self._encode_rows(
+            [region for region, _ in pairs])
+        rows = []
+        for i, (old, region, (_, key)) in enumerate(
+                zip(gone, regions, pairs)):
+            row = old._replace(
+                host=dict(features=feats[i], spatials=spatials[i],
+                          image_mask=masks[i]),
+                key=key, image=dec.ImageMeta(
+                    old.image.path, region.image_width, region.image_height))
+            req, at = row.req, row.index
+            req.images[at], req.cache_keys[at] = row.image, key
+            req.frames[at] = row.frame()
+            rows.append(row)
+        return rows
+
+    def _encode_rows(self, regions: Sequence[RegionFeatures],
+                     pad_to: Optional[int] = None):
+        """Clip, ``encode_image``, stack (padded to ``pad_to`` rows) and
+        cast to the transfer dtype: (clipped regions, features, spatials,
+        image mask). Feature files are confidence-ordered (extractor top-K
+        order, same as the reference's .npy dumps), so an over-provisioned
+        store clips to this engine's region budget instead of erroring."""
+        ecfg = self.cfg.engine
+        regions = clip_regions(regions, ecfg.max_regions,
+                               num_features=ecfg.num_features)
+        feats, spatials, image_mask = batch_images(
+            [encode_image(r, ecfg.max_regions) for r in regions],
+            pad_to=pad_to)
+        return (regions, feats.astype(self.transfer_dtype, copy=False),
+                spatials, image_mask)
 
     @property
     def transfer_dtype(self) -> np.dtype:
@@ -962,6 +1115,7 @@ class InferenceEngine:
         image_paths: Optional[Sequence[str]] = None,
         *,
         cache_keys: Optional[Sequence[str]] = None,
+        frames: Optional[Sequence[Optional[RowFrame]]] = None,
     ) -> PreparedRequest:
         """Host-side preprocessing: validate, tokenize, encode, bucket.
 
@@ -972,6 +1126,10 @@ class InferenceEngine:
         store path) opts this request's region tensors into the device
         input cache — pass them ONLY for content-stable images; never
         derived from the synthetic ``image_paths`` defaults.
+
+        ``frames`` is :meth:`prepare_from_store`'s: where ``frames[i]`` is
+        given, row i is on the device under ``cache_keys[i]``,
+        ``regions[i]`` is None and nothing is encoded for it.
         """
         if task_id not in TASK_REGISTRY:
             raise ValueError(f"unknown task_id {task_id}")
@@ -985,15 +1143,15 @@ class InferenceEngine:
             text = encode_question(
                 self.tokenizer, question, ecfg.max_text_len, task_id=task_id,
             ).stack(bucket)
-        with obs.span("engine.encode", n_images=n, task_id=task_id):
-            # Feature files are confidence-ordered (extractor top-K order,
-            # same as the reference's .npy dumps), so an over-provisioned
-            # store clips to this engine's region budget instead of erroring.
-            regions = clip_regions(regions, ecfg.max_regions,
-                                   num_features=ecfg.num_features)
-            encoded = [encode_image(r, ecfg.max_regions) for r in regions]
-            feats, spatials, image_mask = batch_images(encoded, pad_to=bucket)
-            feats = feats.astype(self.transfer_dtype, copy=False)
+        carried = [r for r in regions if r is not None]
+        feats = spatials = image_mask = None
+        if carried:
+            with obs.span("engine.encode", n_images=len(carried),
+                          task_id=task_id):
+                # Bucket padding is the batched (mesh) program's; the slab
+                # path pads with slot 0, and only it has resident rows.
+                carried, feats, spatials, image_mask = self._encode_rows(
+                    carried, pad_to=bucket if frames is None else None)
         task_ids = np.full((bucket, 1), task_id, np.int32)
         if cache_keys is not None:
             if len(cache_keys) != n:
@@ -1006,13 +1164,19 @@ class InferenceEngine:
             raise ValueError(
                 f"got {len(paths)} image paths for {n} feature sets"
             )
-        images = [
-            dec.ImageMeta(p, r.image_width, r.image_height)
-            for p, r in zip(paths, regions)
-        ]
+        sized = iter(carried)  # the clipped regions, in request order
+        images = []
+        for path, frame in zip(paths, frames or [None] * n):
+            if frame is None:
+                region = next(sized)
+                width, height = region.image_width, region.image_height
+            else:
+                width, height = frame.width, frame.height
+            images.append(dec.ImageMeta(path, width, height))
         return PreparedRequest(spec, n, bucket, text, feats, spatials,
                                image_mask, task_ids, images,
-                               cache_keys=cache_keys)
+                               cache_keys=cache_keys,
+                               frames=list(frames) if frames else None)
 
     # ---------------------------------------------------------------- decode
     def decode(self, req: PreparedRequest, bundle, row: int = 0
@@ -1042,7 +1206,7 @@ class InferenceEngine:
         if spec.decode == "grounding":
             return dec.decode_grounding(
                 spec, np.asarray(bundle["vision_logit"])[row],
-                req.spatials[0], req.images[0])
+                req.first_spatials, req.images[0])
         raise ValueError(f"unknown decode family {spec.decode}")
 
     # ---------------------------------------------------------------- serve
@@ -1113,16 +1277,24 @@ class InferenceEngine:
             self._slab = self._slab_insert_fn(self._slab, placed)
         obs.INPUT_CACHE_INSERTS.inc()
 
-    def _row_slot_locked(self, host_row: dict, key: Optional[str]) -> int:
+    def _row_slot_locked(self, row: _Row, pinned: set) -> int:
         """Slab slot for one image row (caller holds _input_cache_lock):
-        cache hit → existing slot; keyed miss → LRU cache slot + insert;
-        keyless → next scratch slot + insert."""
+        cache hit → existing slot; keyed miss → LRU cache slot + insert,
+        and the row's frame kept beside it; keyless → next scratch slot +
+        insert. ``pinned`` are the slots this pack already points at (the
+        slot returned joins them): a cache smaller than a pack must not
+        hand one of them out again, so a miss that could only evict a
+        pinned row rides a scratch slot, uncached. A resident row
+        (``row.host`` None) must be a hit here: :meth:`_pack_rows` reads
+        what is gone before it calls."""
+        key = row.key
         if key is not None:
             slot = self._input_cache.get(key)
             if slot is not None:
                 self._input_cache.move_to_end(key)
                 self._input_cache_hits += 1
                 obs.INPUT_CACHE_HITS.inc()
+                pinned.add(slot)
                 return slot
             self._input_cache_misses += 1
             obs.INPUT_CACHE_MISSES.inc()
@@ -1132,8 +1304,14 @@ class InferenceEngine:
                 # Cache full: reuse the LRU entry's slot. In-flight
                 # forwards captured the pre-insert slab value, so the
                 # overwrite cannot corrupt a dispatched batch.
-                _, slot = self._input_cache.popitem(last=False)
+                lru, slot = next(iter(self._input_cache.items()))
+                if slot in pinned:
+                    key = None
+                else:
+                    del self._input_cache[lru], self._input_frames[lru]
+        if key is not None:
             self._input_cache[key] = slot
+            self._input_frames[key] = row.frame()
         else:
             # No stable identity → scratch rotor. One pack needs at most
             # max_batch_rows slots (= the scratch region size), and the
@@ -1142,7 +1320,8 @@ class InferenceEngine:
             slot = self._slab_scratch0 + (
                 self._scratch_next % self._slab_scratch_n)
             self._scratch_next += 1
-        self._slab_insert(slot, host_row)
+        self._slab_insert(slot, row.host)
+        pinned.add(slot)
         return slot
 
     @property
@@ -1187,22 +1366,47 @@ class InferenceEngine:
             self._breaker.state != "closed")
         return stats
 
-    def _pack_rows(self, rows: Sequence[Tuple[dict, Optional[str]]],
-                   bucket: int) -> Tuple[dict, np.ndarray]:
-        """Resolve each (host_row, cache_key) to a slab slot and return
-        (slab value, (bucket,) int32 slot vector); pad slots are 0. The
-        whole pack runs under one lock hold and captures the slab value
-        before releasing it, so concurrent packs can never recycle this
-        pack's scratch slots out from under its forward."""
+    def _pack_rows(self, rows: Sequence[_Row], bucket: int
+                   ) -> Tuple[dict, np.ndarray]:
+        """Resolve each row to a slab slot and return (slab value,
+        (bucket,) int32 slot vector); pad slots are 0. The whole pack runs
+        under one lock hold and captures the slab value before releasing
+        it, so concurrent packs can never recycle this pack's scratch slots
+        out from under its forward.
+
+        The pack is the authority on residency, the intake's answer a
+        promise that may have lapsed (LRU eviction since; a replica that
+        never held the row). A resident row the cache no longer holds is
+        read and encoded here, late (:meth:`_read_late`: the same files
+        through the same steps), with the lock released, then packed as
+        the miss it now is, and its request learns the frame it is decoded
+        with. Rows the cache holds are resolved before anything is
+        inserted, so no insert of this pack evicts a row of this pack."""
         self._row_slab()  # built outside the (non-reentrant) lock hold
         with self._input_cache_lock:
-            slots = [self._row_slot_locked(row, key) for row, key in rows]
-            slab = self._slab
-        slots.extend([0] * (bucket - len(slots)))
-        return slab, np.asarray(slots, np.int32)
+            late = [i for i, r in enumerate(rows)
+                    if r.host is None and r.key not in self._input_cache]
+            if not late:
+                pinned: set = set()
+                slots = [self._row_slot_locked(r, pinned)
+                         if r.key in self._input_cache else None
+                         for r in rows]
+                slots = [s if s is not None
+                         else self._row_slot_locked(r, pinned)
+                         for r, s in zip(rows, slots)]
+                slots.extend([0] * (bucket - len(slots)))
+                return self._slab, np.asarray(slots, np.int32)
+        rows = list(rows)
+        with obs.span("engine.late_read", rows=len(late)):
+            for i, row in zip(late, self._read_late([rows[i] for i in late])):
+                rows[i] = row
+        obs.INTAKE_ROWS_LATE.inc(len(late))
+        # Again from the top: every row read now carries its tensors, so
+        # this ends; another row may have left the device meanwhile.
+        return self._pack_rows(rows, bucket)
 
     def _run_rows(self, bucket: int, collect_attention: bool,
-                  text_host: dict, rows: Sequence[Tuple[dict, Optional[str]]]):
+                  text_host: dict, rows: Sequence[_Row]):
         """Dispatch the O(1)-leaf rows program: pack the image rows into
         the slab, then ship text + slot indices as ONE fused explicit
         device_put (the donated ``pack`` argument)."""
@@ -1211,13 +1415,11 @@ class InferenceEngine:
         return self._call_forward(bucket, collect_attention, slab, pack,
                                   rows=True)
 
-    def _request_rows(self, req: PreparedRequest
-                      ) -> List[Tuple[dict, Optional[str]]]:
-        """A request's real image rows as (host_row, cache_key) pairs."""
-        return [(dict(features=req.features[i], spatials=req.spatials[i],
-                      image_mask=req.image_mask[i]),
-                 req.cache_keys[i] if req.cache_keys is not None else None)
-                for i in range(req.n_images)]
+    def _request_rows(self, req: PreparedRequest) -> List[_Row]:
+        """A request's real image rows, in order, for :meth:`_pack_rows`."""
+        keys = req.cache_keys or [None] * req.n_images
+        return [_Row(host, keys[i], req.images[i], req, i)
+                for i, host in enumerate(req.host_rows())]
 
     def run(self, req: PreparedRequest, *, collect_attention: bool = False,
             deadline=None):
@@ -1453,10 +1655,7 @@ class InferenceEngine:
             # (discarded at decode). Packed text + the slot-index vector
             # move in one deliberate device_put inside _run_rows — the
             # compiled signature stays O(1) in chunk rows.
-            rows = [(dict(features=r.features[i], spatials=r.spatials[i],
-                          image_mask=r.image_mask[i]),
-                     r.cache_keys[i] if r.cache_keys is not None else None)
-                    for r, i in spans]
+            rows = [row for r in reqs for row in self._request_rows(r)]
             _, bundle = self._run_rows(bucket, False, text, rows)
         return bundle
 
@@ -1476,7 +1675,8 @@ class InferenceEngine:
         if self.feature_store is None:
             raise RuntimeError("predict() needs a FeatureStore; use "
                                "prepare()+run() with in-memory regions instead")
-        # One store read yields regions + device-cache identities together.
+        # Identity, residency, and a store read only for what the device
+        # does not hold.
         req = self.prepare_from_store(task_id, question, image_paths)
         _, result = self.run(req, collect_attention=collect_attention)
         return result

@@ -106,10 +106,14 @@ def image_key(image_path: str) -> str:
     return os.path.basename(image_path).split(".")[0]
 
 
+def stat_identity(path: str, st: os.stat_result) -> str:
+    """The identity string of a file from a ``stat`` already taken."""
+    return f"{path}:{st.st_mtime_ns}:{st.st_size}"
+
+
 def file_identity(path: str) -> str:
     """Content-stable cache identity for a file: path + mtime + size."""
-    st = os.stat(path)
-    return f"{path}:{st.st_mtime_ns}:{st.st_size}"
+    return stat_identity(path, os.stat(path))
 
 
 class FeatureStore:
@@ -140,11 +144,19 @@ class FeatureStore:
         except OSError:
             return False
 
-    def path_for(self, key: str) -> str:
-        for ext, loader in ((".npy", load_reference_npy), (".vlfr", load_vlfr)):
-            p = os.path.join(self.root, key + ext)
-            if os.path.exists(p):
-                return p
+    def _locate(self, image_path: str) -> tuple[str, str]:
+        """(feature file, its content identity) for an image: ONE ``stat``
+        where the reference ``.npy`` is there (a second for a ``.vlfr``
+        store), which both finds the file and dates it. Every system call
+        is a hand-over of the interpreter lock in a busy server, and the
+        intake makes this call for every image of every request."""
+        key = image_key(image_path)
+        for ext in (".npy", ".vlfr"):
+            path = os.path.join(self.root, key + ext)
+            try:
+                return path, stat_identity(path, os.stat(path))
+            except (OSError, ValueError):  # what os.path.exists calls absent
+                continue
         raise FileNotFoundError(
             f"no feature file for key '{key}' under {self.root} (.npy/.vlfr)"
         )
@@ -153,16 +165,22 @@ class FeatureStore:
         """Content-stable identity for this image's features: resolved file
         path + mtime + size. Cache layers (the host LRU here, the engine's
         device input cache) key on this so a replaced/edited feature file
-        is a cache MISS, never silently served stale."""
-        return file_identity(self.path_for(image_key(image_path)))
+        is a cache MISS, never silently served stale. One ``stat`` and no
+        read: the engine's intake asks it of every image FIRST, asks its
+        device cache for that identity, and calls :meth:`fetch` only for
+        what the device does not hold (engine.prepare_from_store)."""
+        return self._locate(image_path)[1]
 
     def fetch(self, image_path: str) -> tuple[RegionFeatures, str]:
         """(features, content identity) — the identity is captured BEFORE
         the read, so a file replaced mid-request can at worst bind an OLD
         key to NEW content (which the next request's fresh stat misses and
-        re-reads), never a new key to stale content."""
-        path = self.path_for(image_key(image_path))
-        key = file_identity(path)
+        re-reads), never a new key to stale content.
+
+        The engine binds a device row to THIS key, whatever identity its
+        intake probed with a moment earlier; the same holds for the late
+        read of a row that left the device between intake and pack."""
+        path, key = self._locate(image_path)
         if key in self._cache:
             self._cache.move_to_end(key)
             obs.FEATURE_STORE_HITS.inc()

@@ -120,7 +120,8 @@ __all__ = [
     "INTAKE_CLAIMS_SIGNALLED", "INTAKE_CLAIMS_UNSIGNALLED",
     "FEATURE_STORE_HITS", "FEATURE_STORE_MISSES", "FEATURE_STORE_READ_BYTES",
     "FEATURE_STORE_LOAD_SECONDS", "INPUT_CACHE_HITS", "INPUT_CACHE_MISSES",
-    "INPUT_CACHE_INSERTS",
+    "INPUT_CACHE_INSERTS", "INTAKE_ROWS_RESIDENT", "INTAKE_ROWS_READ",
+    "INTAKE_ROWS_LATE",
     "SAMPLER_THREAD_NAME", "Sampler", "TimeSeriesStore",
     "RECORDER_THREAD_NAME", "FlightRecorder", "active_recorder",
     "clear_recorder", "install_recorder", "record_event", "record_spike",
@@ -245,6 +246,23 @@ INPUT_CACHE_INSERTS = REGISTRY.counter(
     "vmt_input_cache_inserts_total",
     "Rows written into the device slab: keyed misses plus keyless "
     "scratch rows (each a functional update of the whole slab).",
+)
+# An image row of prepare_from_store, by what the intake did for it. Apart
+# from the two pairs above: a resident row never reaches the store (neither
+# a hit nor a miss of its host LRU) and is a hit of the slab at the pack.
+INTAKE_ROWS_RESIDENT = REGISTRY.counter(
+    "vmt_intake_rows_resident_total",
+    "Image rows the intake found on the device by their file identity: "
+    "not read, not encoded, no host tensor carried to the pack.",
+)
+INTAKE_ROWS_READ = REGISTRY.counter(
+    "vmt_intake_rows_read_total",
+    "Image rows the intake read from the feature store and encoded.",
+)
+INTAKE_ROWS_LATE = REGISTRY.counter(
+    "vmt_intake_rows_late_total",
+    "Rows the intake called resident that the pack no longer found in "
+    "the slab: read and encoded late, on the dispatch thread.",
 )
 
 # Replica-pool instruments (serve/pool.py).

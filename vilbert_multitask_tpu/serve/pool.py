@@ -199,7 +199,27 @@ class ReplicaPool:
         return self._host.prepare_generate(*args, **kwargs)
 
     def prepare_from_store(self, *args, **kwargs):
+        # The host engine asks its device cache before it reads a file; a
+        # batch may land on any replica, so with more than one the answer
+        # has to be every replica's (doubles without a cache read as ever).
+        if len(self.replicas) > 1 and hasattr(self._host, "resident_frames"):
+            kwargs["resident"] = self._resident_frames
         return self._host.prepare_from_store(*args, **kwargs)
+
+    def _resident_frames(self, keys):
+        """Per identity a row's frame if EVERY replica that may take a
+        batch holds the row, else None: a pool must not turn the intake's
+        skipped reads into late reads at the pack as a rule (the pack still
+        answers when a replica joins or evicts in between)."""
+        frames = None
+        for rep in list(self.replicas):
+            if rep.killed or rep.state == STATE_DEAD:
+                continue
+            ask = getattr(rep.engine, "resident_frames", None)
+            held = ask(keys) if ask is not None else [None] * len(keys)
+            frames = held if frames is None else [
+                f if h is not None else None for f, h in zip(frames, held)]
+        return frames if frames is not None else [None] * len(keys)
 
     def chunk_plan(self, *args, **kwargs):
         return self._host.chunk_plan(*args, **kwargs)

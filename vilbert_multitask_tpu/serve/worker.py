@@ -159,10 +159,10 @@ class ServeWorker:
         # job id so redelivered attempts reuse one row.
         qa_id = self.store.create_question(task_id, question, image_paths,
                                            socket_id, queue_job_id=job.id)
-        # One store read yields regions + content-stable device-cache
-        # identities (file + mtime + size, captured at read time): repeat
-        # queries about unchanged images skip the feature upload; an
-        # edited/replaced file is a cache miss.
+        # Each image's content identity first (file + mtime + size, one
+        # stat), then whether the device holds a row under it: a repeat
+        # query about an unchanged image reads no file, encodes nothing and
+        # uploads nothing; an edited/replaced file is a miss and is read.
         prepared = self.engine.prepare_from_store(task_id, question,
                                                   image_paths)
         obs.job_charge(body.get("trace_id", ""), "intake",

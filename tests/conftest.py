@@ -179,7 +179,7 @@ def _no_leaked_project_threads():
                 and obs.active_recorder() is recorder_before
                 and getattr(recorder_before, "_thread", None) is t):
             continue
-        if t.name in (obs.SAMPLER_THREAD_NAME,
+        if t.name in (obs.SAMPLER_THREAD_NAME, obs.GIL_PROBE_THREAD_NAME,
                       obs.RECORDER_THREAD_NAME):
             leaked.append(f"{t.name} (stop()/close() must join it)")
         elif not t.daemon:

@@ -18,6 +18,7 @@ import pytest
 from vilbert_multitask_tpu import obs
 from vilbert_multitask_tpu.features.store import FeatureStore
 from vilbert_multitask_tpu.obs import Tracer
+from vilbert_multitask_tpu.obs.timeseries import GIL_WAIT
 from vilbert_multitask_tpu.serve.scheduler import (
     ContinuousScheduler,
     ReadyItem,
@@ -73,6 +74,9 @@ def served(tiny_framework_cfg, fresh_engine, features_dir, tmp_path_factory):
             media_root=str(root / "media"), http_port=0, ws_port=0))
     app = ServeApp(cfg, engine=[fresh_engine], feature_root=features_dir)
     tracer = obs.default_tracer()
+    rows_counted = (obs.INPUT_CACHE_HITS.value()
+                    + obs.INPUT_CACHE_MISSES.value())
+    probed = GIL_WAIT.count()
     app.start()
     try:
         with connect(f"ws://127.0.0.1:{app.ws.bound_port}/chat/") as ws:
@@ -103,7 +107,12 @@ def served(tiny_framework_cfg, fresh_engine, features_dir, tmp_path_factory):
         app.stop()
     return {"trace_id": body["trace_id"], "job_id": body["job_id"],
             "spans": [s for s in tracer.spans()
-                      if s.trace_id == body["trace_id"]]}
+                      if s.trace_id == body["trace_id"]],
+            "rows_counted": (obs.INPUT_CACHE_HITS.value()
+                             + obs.INPUT_CACHE_MISSES.value()
+                             - rows_counted),
+            "probed": GIL_WAIT.count() - probed,
+            "threads_after_stop": {t.name for t in threading.enumerate()}}
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_PARENTS))
@@ -122,6 +131,23 @@ def test_served_request_span_appears_once_under_its_parent(served, name):
     assert parent.start_s <= span.start_s
     assert (span.start_s + span.dur_s
             <= parent.start_s + parent.dur_s + 1e-6)
+
+
+def test_the_app_starts_and_stops_the_probe_with_its_sampler(served):
+    assert served["probed"] >= 1
+    assert not {obs.GIL_PROBE_THREAD_NAME, obs.SAMPLER_THREAD_NAME} \
+        & served["threads_after_stop"]
+
+
+def test_a_served_span_reads_its_cpu_and_the_rows_counted_are_dispatched(
+        served):
+    """``host_cpu_ms_per_row`` divides by hits + misses of the device
+    cache: one a keyed row packed into a dispatch."""
+    spans = {s.name: s for s in served["spans"]}
+    for name in ("http.submit", "worker.intake", "engine.dispatch"):
+        assert 0.0 <= spans[name].cpu_s <= spans[name].dur_s + 1e-3
+    assert spans["worker.claim"].cpu_s is None   # recorded after the fact
+    assert served["rows_counted"] == spans["engine.dispatch"].attrs["rows"]
 
 
 def test_the_trace_id_is_minted_once_and_rides_in_the_job_body(served):

@@ -14,10 +14,12 @@ Three pillars (see ARCHITECTURE.md "Observability"):
 Importing the package wires the default tracer's observer to feed every
 completed span into the ``vmt_span_ms{name,task}`` histogram, which is
 what ``GET /metrics?format=prometheus`` serves as per-task stage
-latencies.
+latencies, and its CPU seconds into ``vmt_span_cpu_ms{name}``.
 """
 
 from __future__ import annotations
+
+import time
 
 from vilbert_multitask_tpu.obs.trace import (
     Span,
@@ -60,9 +62,12 @@ from vilbert_multitask_tpu.obs.attrib import (
 )
 from vilbert_multitask_tpu.obs.tracestore import TraceStore
 from vilbert_multitask_tpu.obs.timeseries import (
+    GIL_PROBE_THREAD_NAME,
+    GilProbe,
     SAMPLER_THREAD_NAME,
     Sampler,
     TimeSeriesStore,
+    WINDOW_RESERVOIR,
 )
 from vilbert_multitask_tpu.obs.recorder import (
     RECORDER_THREAD_NAME,
@@ -122,6 +127,9 @@ __all__ = [
     "FEATURE_STORE_LOAD_SECONDS", "INPUT_CACHE_HITS", "INPUT_CACHE_MISSES",
     "INPUT_CACHE_INSERTS", "INTAKE_ROWS_RESIDENT", "INTAKE_ROWS_READ",
     "INTAKE_ROWS_LATE",
+    "SPAN_CPU_HISTOGRAM", "READY_WAIT", "COMPLETION_WAIT",
+    "DISPATCH_STARVED", "DISPATCH_BLOCKED", "PROCESS_CPU_SECONDS",
+    "GIL_PROBE_THREAD_NAME", "GilProbe", "WINDOW_RESERVOIR",
     "SAMPLER_THREAD_NAME", "Sampler", "TimeSeriesStore",
     "RECORDER_THREAD_NAME", "FlightRecorder", "active_recorder",
     "clear_recorder", "install_recorder", "record_event", "record_spike",
@@ -133,10 +141,28 @@ __all__ = [
     "FleetSpine", "default_spine_path",
 ]
 
+# Both span histograms hold a benchmark window's spans (a reader divides
+# one's sum by the other's over the same spans).
 SPAN_HISTOGRAM = REGISTRY.histogram(
     "vmt_span_ms",
     "Span durations by span name and task (ms).",
     labelnames=("name", "task"),
+    reservoir=WINDOW_RESERVOIR,
+)
+SPAN_CPU_HISTOGRAM = REGISTRY.histogram(
+    "vmt_span_cpu_ms",
+    "The thread's CPU time inside a span, by span name (ms); the span's "
+    "wall time less this is time off the core. Spans recorded after the "
+    "fact have none.",
+    labelnames=("name",),
+    reservoir=WINDOW_RESERVOIR,
+)
+# The host process as a whole: its CPU, read when collected.
+PROCESS_CPU_SECONDS = REGISTRY.read_counter(
+    "vmt_process_cpu_seconds_total",
+    "CPU seconds of this process, all threads (time.process_time, read "
+    "when collected).",
+    time.process_time,
 )
 
 # Resilience instruments (resilience/ policy plane). Defined here so the
@@ -173,8 +199,31 @@ BATCH_FILL = REGISTRY.histogram(
 )
 SCHED_WAIT = REGISTRY.histogram(
     "vmt_sched_wait_ms",
-    "Time a ready (claimed + prepped) job waited in the scheduler's "
-    "ready-queue before its batch fired (ms).",
+    "Claim to dispatch (ms): the claimed job's preparation on an intake "
+    "thread plus its wait in the ready-queue before its batch fired.",
+)
+# The scheduler's hand-overs, and its dispatch stage's time between spans.
+READY_WAIT = REGISTRY.histogram(
+    "vmt_ready_wait_ms",
+    "Time a prepared job waited in the ready-queue, from its parking to "
+    "its dispatch or admission (ms).",
+    reservoir=WINDOW_RESERVOIR,
+)
+COMPLETION_WAIT = REGISTRY.histogram(
+    "vmt_completion_wait_ms",
+    "Time a result waited in the completion queue, from its put to the "
+    "completion thread taking it (ms).",
+    reservoir=WINDOW_RESERVOIR,
+)
+DISPATCH_STARVED = REGISTRY.counter(
+    "vmt_dispatch_starved_seconds_total",
+    "Seconds the scheduler's batch-selecting loop waited with no ready "
+    "job (in a replica pool, batches then run on an executor thread).",
+)
+DISPATCH_BLOCKED = REGISTRY.counter(
+    "vmt_dispatch_blocked_seconds_total",
+    "Seconds the thread that runs a batch blocked handing a result to "
+    "a full completion queue.",
 )
 QUEUE_WAIT = REGISTRY.histogram(
     "vmt_queue_wait_ms",
@@ -316,6 +365,8 @@ TENANT_DEFICIT = REGISTRY.gauge(
 def _observe_span(s: Span) -> None:
     SPAN_HISTOGRAM.observe(
         s.dur_s * 1e3, name=s.name, task=str(s.attrs.get("task_id", "")))
+    if s.cpu_s is not None:
+        SPAN_CPU_HISTOGRAM.observe(s.cpu_s * 1e3, name=s.name)
 
 
 default_tracer().set_observer(_observe_span)

@@ -21,7 +21,7 @@ import math
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 
 def percentile(values: Sequence[float], p: float) -> Optional[float]:
@@ -99,6 +99,26 @@ class Counter(_Instrument):
     def collect(self) -> Dict[Tuple[str, ...], float]:
         with self._lock:
             return dict(self._values)
+
+
+class ReadCounter(_Instrument):
+    """A counter whose one value is read when it is collected (the
+    process's CPU seconds): no thread and no tick, so two collections
+    bracket an interval exactly. It has no ``inc``."""
+
+    kind = "counter"
+
+    def __init__(self, name: str, help: str = "",
+                 labelnames: Sequence[str] = (),
+                 read: Optional[Callable[[], float]] = None):
+        super().__init__(name, help, ())
+        self._read = read
+
+    def value(self, **labels) -> float:
+        return float(self._read())
+
+    def collect(self) -> Dict[Tuple[str, ...], float]:
+        return {(): float(self._read())}
 
 
 class Gauge(_Instrument):
@@ -350,10 +370,16 @@ class Registry:
               labelnames: Sequence[str] = ()) -> Gauge:
         return self._get(Gauge, name, help, labelnames)
 
+    def read_counter(self, name: str, help: str,
+                     read: Callable[[], float]) -> ReadCounter:
+        return self._get(ReadCounter, name, help, (), read=read)
+
     def histogram(self, name: str, help: str = "",
                   labelnames: Sequence[str] = (),
-                  buckets: Optional[Sequence[float]] = None) -> Histogram:
-        return self._get(Histogram, name, help, labelnames, buckets=buckets)
+                  buckets: Optional[Sequence[float]] = None,
+                  reservoir: int = 2048) -> Histogram:
+        return self._get(Histogram, name, help, labelnames, buckets=buckets,
+                         reservoir=reservoir)
 
     def instruments(self) -> List[_Instrument]:
         with self._lock:

@@ -30,7 +30,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from vilbert_multitask_tpu.obs import trace as _trace
 from vilbert_multitask_tpu.obs.instruments import (
-    Counter, Gauge, Histogram, REGISTRY, percentile)
+    Counter, Gauge, Histogram, REGISTRY, ReadCounter, percentile)
 
 RECORDER_THREAD_NAME = "flight-recorder"
 _EVENT_SAFE = re.compile(r"[^a-z0-9_-]+")
@@ -49,7 +49,7 @@ def _instrument_snapshot() -> List[dict]:
     out: List[dict] = []
     for inst in REGISTRY.instruments():
         row: dict = {"name": inst.name, "kind": inst.kind}
-        if isinstance(inst, (Counter, Gauge)):
+        if isinstance(inst, (Counter, ReadCounter, Gauge)):
             row["values"] = {"|".join(k) or "_": v
                              for k, v in inst.collect().items()}
         elif isinstance(inst, Histogram):

@@ -23,13 +23,28 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
-from vilbert_multitask_tpu.obs.instruments import REGISTRY
+from vilbert_multitask_tpu.obs.instruments import REGISTRY, log_buckets
 
 SAMPLER_THREAD_NAME = "obs-sampler"
+GIL_PROBE_THREAD_NAME = "obs-gil-probe"
+# A reservoir that holds a 51 s benchmark window of an instrument observed
+# up to 80 times a second (a job's spans and hand-overs in base.saturated
+# come about 47 times a second; the default 2048 holds 43 s of them).
+WINDOW_RESERVOIR = 4096
+# The probe's sleep: 20 wake-ups a second, about 1,000 in a 51 s window.
+GIL_PROBE_PERIOD_S = 0.05
 
 _SAMPLER_ERRORS = REGISTRY.counter(
     "vmt_sampler_errors_total",
     "Probe failures swallowed by the background sampler")
+GIL_WAIT = REGISTRY.histogram(
+    "vmt_gil_wait_ms",
+    "How late the interpreter-lock probe woke from a 50 ms sleep (ms): "
+    "the wait of any thread that returns from a system call for the "
+    "interpreter lock, the kernel's wake-up latency its floor.",
+    buckets=log_buckets(0.01, 1000.0),
+    reservoir=WINDOW_RESERVOIR,
+)
 
 
 class TimeSeriesStore:
@@ -96,6 +111,42 @@ class TimeSeriesStore:
         return {name: self.points(name, window_s) for name in self.names()}
 
 
+class GilProbe:
+    """Daemon thread that sleeps :data:`GIL_PROBE_PERIOD_S` and observes
+    into ``vmt_gil_wait_ms`` how late it woke. Waking, it needs the
+    interpreter lock back like any thread returning from a system call,
+    so the lateness is that hand-over's cost in this process, read
+    rather than inferred, on top of the kernel's wake-up latency (what an
+    idle process reads). Started and stopped by :class:`Sampler`."""
+
+    def __init__(self):
+        self._stop = threading.Event()
+        self._thread: Optional[threading.Thread] = None
+
+    def _run(self) -> None:
+        while True:
+            t0 = time.perf_counter()
+            if self._stop.wait(GIL_PROBE_PERIOD_S):
+                return
+            late = time.perf_counter() - t0 - GIL_PROBE_PERIOD_S
+            GIL_WAIT.observe(max(late, 0.0) * 1e3)
+
+    def start(self) -> None:
+        if self._thread is not None and self._thread.is_alive():
+            return
+        self._stop.clear()
+        self._thread = threading.Thread(
+            target=self._run, name=GIL_PROBE_THREAD_NAME, daemon=True)
+        self._thread.start()
+
+    def stop(self, timeout: float = 2.0) -> None:
+        self._stop.set()
+        t = self._thread
+        if t is not None:
+            t.join(timeout)
+        self._thread = None
+
+
 class Sampler:
     """Daemon thread snapshotting one probe callable into a store.
 
@@ -103,7 +154,8 @@ class Sampler:
     (it knows the queue/worker/engine wiring); the sampler owns only the
     cadence, the rate derivation for ``*_total`` keys, and the thread
     lifecycle. ``tick()`` is public so tests and the soak can sample
-    synchronously without a thread.
+    synchronously without a thread. Its thread starts and stops the
+    :class:`GilProbe` with it.
     """
 
     def __init__(self, store: TimeSeriesStore,
@@ -114,6 +166,7 @@ class Sampler:
         self.cadence_s = max(0.01, float(cadence_s))
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self.gil_probe = GilProbe()
         # Previous (perf_counter, value) per counter key, for rates.
         self._prev: Dict[str, Tuple[float, float]] = {}
 
@@ -154,6 +207,7 @@ class Sampler:
         self._thread = threading.Thread(
             target=self._run, name=SAMPLER_THREAD_NAME, daemon=True)
         self._thread.start()
+        self.gil_probe.start()
 
     def stop(self, timeout: float = 2.0) -> None:
         self._stop.set()
@@ -161,3 +215,4 @@ class Sampler:
         if t is not None:
             t.join(timeout)
         self._thread = None
+        self.gil_probe.stop(timeout)

@@ -16,7 +16,15 @@ mechanisms:
   across the queue boundary.
 
 Timing is ``time.perf_counter`` throughout (monotonic — wall-clock
-``time.time()`` in a duration is the VMT109 lint hazard). The disabled
+``time.time()`` in a duration is the VMT109 lint hazard). On Linux that
+is ``CLOCK_MONOTONIC``, the clock of ``time.monotonic`` too, on which the
+benchmark takes its two profiler marks: so spans share the device
+trace's time axis through that one clock (``benchmark/reduce/trace.py:
+clock_offset``; a tier-1 test holds the two clocks to one
+implementation). A span measured with ``with`` also reads the thread's
+CPU clock at entry and exit (``Span.cpu_s``): its wall time less its CPU
+time is the time it spent off the core, waiting for the interpreter
+lock, another lock, the kernel or the device. The disabled
 fast path returns a shared no-op context manager after a single attribute
 check, so instrumentation can stay on hot serving paths permanently
 (tier-1 guards < 5 µs per disabled call).
@@ -51,6 +59,9 @@ class Span:
     thread_id: int
     thread_name: str
     attrs: Dict[str, Any] = field(default_factory=dict)
+    # The thread's CPU seconds inside the span (time.thread_time); None
+    # for a span recorded after the fact (Tracer.record_span).
+    cpu_s: Optional[float] = None
 
 
 class _NoopSpan:
@@ -83,7 +94,7 @@ class _ActiveSpan:
     """A span being measured; becomes a :class:`Span` on ``__exit__``."""
 
     __slots__ = ("_tracer", "name", "attrs", "trace_id", "span_id",
-                 "parent_id", "_t0")
+                 "parent_id", "_t0", "_c0")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -108,10 +119,14 @@ class _ActiveSpan:
             self.parent_id = None
         self.span_id = new_trace_id()
         state.stack.append(self)
+        # The CPU reads sit inside the wall reads: a span's CPU time never
+        # exceeds its wall time by the cost of reading the clocks.
         self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        cpu = time.thread_time() - self._c0
         dur = time.perf_counter() - self._t0
         state = self._tracer._state()
         if state.stack and state.stack[-1] is self:
@@ -126,7 +141,7 @@ class _ActiveSpan:
         th = threading.current_thread()
         self._tracer._record(Span(
             self.name, self.trace_id, self.span_id, self.parent_id,
-            self._t0, dur, th.ident or 0, th.name, self.attrs))
+            self._t0, dur, th.ident or 0, th.name, self.attrs, cpu))
         return False
 
 
@@ -243,6 +258,12 @@ class Tracer:
         with self._lock:
             out = list(self._ring)
         return out[-limit:] if limit else out
+
+    def spans_of(self, trace_id: str) -> List[Span]:
+        """The ring's spans of one trace (a kept trace's copy, not the
+        whole ring's)."""
+        with self._lock:
+            return [s for s in self._ring if s.trace_id == trace_id]
 
     def clear(self) -> None:
         with self._lock:

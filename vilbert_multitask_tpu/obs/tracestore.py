@@ -29,7 +29,7 @@ import json
 import random
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 from vilbert_multitask_tpu.obs.attrib import JobCost
 from vilbert_multitask_tpu.obs.sqlitestore import SqliteStore
@@ -117,24 +117,28 @@ class TraceStore(SqliteStore):
         return None
 
     def offer(self, cost: JobCost,
-              spans: Sequence[Any] = ()) -> Optional[str]:
+              spans: Union[Sequence[Any], Callable[[], Sequence[Any]]] = ()
+              ) -> Optional[str]:
         """Tail-sampling decision for one completed job. Returns the
-        keep reason, or None when the trace is dropped."""
+        keep reason, or None when the trace is dropped. ``spans`` may be
+        a callable, called only for a kept trace (the serving app's copy
+        of the tracer ring is then made for the traces it keeps)."""
         with self._lock:
             self.offered += 1
             reason = self._keep_reason(cost)
             if reason is None:
                 return None
             self.kept += 1
-            self._pending.append((
-                cost.trace_id, self.ident, cost.task or "unknown",
-                cost.tenant or "anon", cost.verdict or "ok", reason,
-                cost.total_ms(),
-                cost.finished_unix or time.time(),
-                json.dumps([_span_dict(s) for s in spans
-                            if s.trace_id == cost.trace_id],
-                           default=str),
-                json.dumps(cost.as_dict(), default=str)))
+        if callable(spans):
+            spans = spans()
+        row = (cost.trace_id, self.ident, cost.task or "unknown",
+               cost.tenant or "anon", cost.verdict or "ok", reason,
+               cost.total_ms(), cost.finished_unix or time.time(),
+               json.dumps([_span_dict(s) for s in spans
+                           if s.trace_id == cost.trace_id], default=str),
+               json.dumps(cost.as_dict(), default=str))
+        with self._lock:
+            self._pending.append(row)
         return reason
 
     def pin(self, trace_ids: Sequence[str]) -> None:

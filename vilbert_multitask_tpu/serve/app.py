@@ -379,8 +379,10 @@ class ServeApp:
     def _offer_trace(self, cost: "obs.JobCost") -> None:
         """Attributor → store handoff (runs on the finishing worker
         thread, outside the attributor lock): the completed cost record
-        plus its spans still in the local tracer ring."""
-        self.tracestore.offer(cost, obs.default_tracer().spans())
+        plus its spans still in the local tracer ring, copied only if the
+        store keeps the trace."""
+        self.tracestore.offer(
+            cost, lambda: obs.default_tracer().spans_of(cost.trace_id))
 
     def _sample(self) -> dict:
         """One sampler tick's worth of live signals. ``*_total`` keys get

@@ -75,10 +75,15 @@ class ReadyItem:
     passed this item over for tenant-budget reasons (not row pressure
     alone), so an expiry while deferred sheds as ``tenant_budget``
     instead of ``deadline``.
+
+    ``enq_t`` is taken before the intake prepares the job (the window
+    policy's age, ``vmt_sched_wait_ms``), ``ready_t`` when the prepared
+    item is parked (``vmt_ready_wait_ms``, the attributor's
+    ``ready_wait``).
     """
 
     __slots__ = ("job", "qa_id", "prepared", "t0", "deadline", "enq_t",
-                 "solo", "tenant", "deferred")
+                 "solo", "tenant", "deferred", "ready_t")
 
     def __init__(self, job: Job, qa_id, prepared, t0, deadline, enq_t,
                  solo: bool = False, tenant: str = "anon"):
@@ -91,6 +96,7 @@ class ReadyItem:
         self.solo = solo
         self.tenant = tenant
         self.deferred = False
+        self.ready_t = enq_t
 
     def rows(self) -> int:
         return self.prepared.n_images if self.prepared is not None else 1
@@ -390,6 +396,7 @@ class ContinuousScheduler:
                 return
             item = ReadyItem(job, qa_id, prepared, t0, deadline, enq_t,
                              tenant=tenant)
+        item.ready_t = self.clock()
         with self._cond:
             self._ready.append(item)
             self._cond.notify()
@@ -406,7 +413,10 @@ class ContinuousScheduler:
         with self._cond:
             while not self.stop.is_set():
                 if not self._ready:
+                    t = time.perf_counter()
                     self._cond.wait(self.poll_interval_s)
+                    # In-memory inc, like the observe below (VMT116).
+                    obs.DISPATCH_STARVED.inc(time.perf_counter() - t)
                     continue
                 now = self.clock()
                 fire, wait_s = fire_decision(
@@ -467,9 +477,7 @@ class ContinuousScheduler:
         batch onto another replica while this one computes."""
         now = self.clock()
         for item in batch:
-            obs.SCHED_WAIT.observe(max(now - item.enq_t, 0.0) * 1e3)
-            obs.job_charge(item.job.body.get("trace_id", ""),
-                           "ready_wait", max(now - item.enq_t, 0.0))
+            self._observe_dispatched(item, now)
         with self._cond:
             # Per-tenant queue-wait EWMA for the sampler: the fairness
             # tier's observable effect is exactly this number staying
@@ -525,10 +533,7 @@ class ContinuousScheduler:
 
         def _on_result(pos: int, result) -> None:
             streamed.add(pos)
-            # Blocking put IS the completion backpressure: a stalled
-            # persist/push stage eventually stalls dispatch instead of
-            # piling unpersisted results without bound.
-            self._completions.put((packed[pos], result))
+            self._complete(packed[pos], result)
 
         rep_name = rep.name if rep is not None else ""
         t_fwd = time.perf_counter()
@@ -592,6 +597,31 @@ class ContinuousScheduler:
                     else:
                         self.worker._fail_job(item.job)
 
+    def _observe_dispatched(self, item: ReadyItem, now: float) -> None:
+        """A ready item leaves the ready-queue for the device: claim to
+        dispatch (``vmt_sched_wait_ms``) and its wait as a prepared job
+        (``vmt_ready_wait_ms``, charged as ``ready_wait``: the intake is
+        charged by the worker, so it is not counted twice)."""
+        obs.SCHED_WAIT.observe(max(now - item.enq_t, 0.0) * 1e3)
+        ready_wait = max(now - item.ready_t, 0.0)
+        obs.READY_WAIT.observe(ready_wait * 1e3)
+        obs.job_charge(item.job.body.get("trace_id", ""), "ready_wait",
+                       ready_wait)
+
+    def _complete(self, item: ReadyItem, result) -> None:
+        """Hand a result to the completion stage, stamped with its put
+        time. The blocking put IS the completion backpressure: a stalled
+        persist/push stage stalls the dispatch thread, whose seconds
+        blocked are counted, instead of piling unpersisted results
+        without bound."""
+        msg = (item, result, time.perf_counter())
+        try:
+            self._completions.put_nowait(msg)
+        except stdlib_queue.Full:
+            t = time.perf_counter()
+            self._completions.put(msg)
+            obs.DISPATCH_BLOCKED.inc(time.perf_counter() - t)
+
     # ---------------------------------------------------- completion stage
     def _completion_loop(self) -> None:
         """Persist + push off the dispatch thread, so the next batch's
@@ -609,7 +639,9 @@ class ContinuousScheduler:
             msg = self._completions.get()
             if msg is None:
                 return
-            item, result = msg
+            item, result, put_t = msg
+            obs.COMPLETION_WAIT.observe(
+                max(time.perf_counter() - put_t, 0.0) * 1e3)
             try:
                 with obs.trace_scope(item.job.body.get("trace_id")):
                     self.worker._finish_job(item.job, item.qa_id,
@@ -684,9 +716,7 @@ class ContinuousScheduler:
                 passed_over.pop(item.job.id, None)
                 self.worker._expire_job(item.job)
             for item in admitted:
-                obs.SCHED_WAIT.observe(max(now - item.enq_t, 0.0) * 1e3)
-                obs.job_charge(item.job.body.get("trace_id", ""),
-                               "ready_wait", max(now - item.enq_t, 0.0))
+                self._observe_dispatched(item, now)
             running.extend(admitted)
             try:
                 prefilling = [i for i in running
@@ -721,9 +751,7 @@ class ContinuousScheduler:
             sp.set(admitted=len(admitted), decoding=len(decoding))
         for req in finished:
             item = self._awaiting_tokens.pop(id(req))
-            # The completion queue's blocking put is the backpressure
-            # point, as in _dispatch_packed.
-            self._completions.put((item, req.result()))
+            self._complete(item, req.result())
 
     # -------------------------------------------------------------- driver
     def run(self) -> None:

@@ -4,12 +4,16 @@ recurrence one token at a time, with beta in (1, 2) present (negative
 eigenvalues) and alpha near 0 and near 1.
 
 Tolerance: all three are float32 at ``highest`` precision; they differ by
-summation order and by the triangular solve, read at 3e-6 on outputs of
-size 2: 5e-5 allowed. The TPU kernel's real-size compile is the one thing
-here that is not CPU arithmetic (section 2 of the on-chip-measurement guide:
-the chip's compiler runs without the chip). The other kernels of the
-generate path (``ops/paged_attention.py``'s two, ``ops/moe.py``'s) are
-compiled for the chip here too (their arithmetic is
+summation order and by the chunk's system, which the chunked forms solve by
+an inverse built from batched matmuls (``gd.unit_lower_inverse``), read at
+3e-6 on outputs of size 2: 5e-5 allowed. That inverse is held on its own to
+a float64 solve of the same system, at 1e-5 relative, on inputs that make
+the system hard (ISSUE 37: write strengths near 2, keys pulled toward one
+direction) and at the served size. The TPU kernel's real-size compile is
+the one thing here that is not CPU arithmetic (section 2 of the
+on-chip-measurement guide: the chip's compiler runs without the chip). The
+other kernels of the generate path (``ops/paged_attention.py``'s two,
+``ops/moe.py``'s) are compiled for the chip here too (their arithmetic is
 ``tests/test_paged_attention.py``'s and ``tests/test_laguna.py``'s): one
 file describes the chip, because one process at a time may load its
 compiler."""
@@ -58,6 +62,58 @@ def test_chunked_scan_equals_the_recurrence(alpha, form):
         interpret=form != "jnp")
     assert float(jnp.abs(want_o - got_o).max()) < ATOL
     assert float(jnp.abs(want_s - got_s).max()) < ATOL
+
+
+def hard_inputs(alpha, T, H, dk, dv, seed=0):
+    """Write strengths in (1.5, 2) and unit keys pulled toward a shared
+    direction: the system's off-diagonal entries near 2 in size, where a
+    Neumann series over the whole chunk diverges."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shared = jax.random.normal(ks[0], (dk,))
+    k = 0.3 * jax.random.normal(ks[1], (T, H, dk)) \
+        + shared / jnp.linalg.norm(shared)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = jax.random.normal(ks[2], (T, H, dk)) / np.sqrt(dk)
+    v = jax.random.normal(ks[3], (T, H, dv))
+    beta = jax.random.uniform(ks[4], (T, H), minval=1.5, maxval=2.0)
+    return q, k, v, jnp.log(alpha(ks[5], (T, H))), beta
+
+
+def solve_float64(q, k, v, g, beta, chunk=gd.CHUNK):
+    """``[u | w]`` of every chunk by ``numpy.linalg.solve`` in float64, the
+    system and right-hand side built as the module docstring writes them."""
+    T, H, _ = q.shape
+
+    def heads_first(x):
+        x = np.moveaxis(np.asarray(x, np.float64), 1, 0)
+        return x.reshape(H, T // chunk, chunk, *x.shape[2:])
+
+    q, k, v, g, beta = map(heads_first, (q, k, v, g, beta))
+    logg = np.cumsum(g, axis=-1)
+    t, i = np.tril_indices(chunk, -1)
+    a = np.zeros(logg.shape + (chunk,))
+    a[..., t, i] = np.exp(logg[..., t] - logg[..., i]) \
+        * np.einsum("...tk,...tk->...t", k[..., t, :], k[..., i, :])
+    system = np.eye(chunk) + beta[..., None] * a
+    rhs = beta[..., None] * np.concatenate(
+        [v, np.exp(logg)[..., None] * k], axis=-1)
+    return np.linalg.solve(system, rhs)
+
+
+@pytest.mark.parametrize("size", [(192, 3, 8, 16), (2048, 30, 96, 192)],
+                         ids=["small", "served"])
+@pytest.mark.parametrize("alpha", sorted(ALPHAS))
+def test_preparation_solves_the_chunk_system(alpha, size):
+    """``u`` and ``w`` of :func:`gd.chunk_prepare` against a float64 solve:
+    the inverse of blocks merged by doubling is exact to float32's
+    rounding, however near 2 the write strengths."""
+    T, H, dk, dv = size
+    args = hard_inputs(ALPHAS[alpha], T, H, dk, dv)
+    prep = jax.jit(gd.chunk_prepare)(*args)
+    want = solve_float64(*args)
+    for got, ref in ((prep["u"], want[..., :dv]), (prep["w"], want[..., dv:])):
+        err = np.abs(np.asarray(got, np.float64) - ref).max()
+        assert err < 1e-5 * np.abs(ref).max()
 
 
 def test_padding_tokens_leave_the_state_alone():
@@ -116,6 +172,25 @@ def test_kernel_compiles_for_the_chip_at_the_served_size(one_chip):
     # reads.
     assert "%gated_delta_scan" in text
     assert "(f32[30,32,64,192]" in text and "f32[30,96,192]" in text
+
+
+def test_preparation_compiles_without_a_triangular_solve(one_chip):
+    """The preparation of a 2048-token chunk of 30 heads (d_k 96, d_v 192):
+    no ``triangular-solve`` and no ``InvertDiagBlocksLowerTriangular``
+    custom call of ``f32[30,32,1,64,64]``, which walked each 64 x 64 block
+    row by row and took a tenth of ``olmo.longdocs``'s chip (ISSUE 37)."""
+    T, H, dk, dv = 2048, 30, 96, 192
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(gd.chunk_prepare).lower(
+        sd(T, H, dk), sd(T, H, dk), sd(T, H, dv), sd(T, H),
+        sd(T, H)).compile().as_text()
+    assert "triangular-solve" not in text
+    results = [line.split(" custom-call(")[0] for line in text.splitlines()
+               if " custom-call(" in line]
+    assert not any("f32[30,32,1,64,64]" in r for r in results)
 
 
 @pytest.mark.parametrize("B", [8, 16, 24, 32])

@@ -29,13 +29,17 @@ A)^-1 diag(b) [V | diag(G) K]``. Then ``O = diag(G) Q St_0 + P U`` with
 (diag(G_C/G) K)^T U``.
 
 So the work is two stages. :func:`chunk_prepare` computes ``u, w, diag(G)Q,
-P, (diag(G_C/G)K)^T`` and ``G_C`` for every chunk at once (batched matmuls
-and one triangular solve, plain XLA). The *scan* carries the state through
-the chunks, three matmuls and one rank-C update each:
-:func:`chunk_scan_jnp` is a ``lax.scan`` (CPU, tests), and
-:func:`gated_delta_scan` the TPU kernel: grid ``(heads, chunks)``, the
-``[dk, dv]`` float32 state of one head in VMEM scratch across the chunk
-axis, read from HBM once and written once a call.
+P, (diag(G_C/G)K)^T`` and ``G_C`` for every chunk at once, in plain XLA
+and batched matmuls alone: :func:`unit_lower_inverse` builds the system's
+inverse (blocks of 8 inverted exactly, then merged by doubling), and ``u``
+and ``w`` are one product with it each (``lax.linalg.triangular_solve``
+walked each 64 x 64 block row by row on the TPU, a tenth of a long-prompt
+cell's chip). The *scan* carries the state through the chunks, three
+matmuls and one rank-C update each: :func:`chunk_scan_jnp` is a
+``lax.scan`` (CPU, tests), and :func:`gated_delta_scan` the TPU kernel:
+grid ``(heads, chunks)``, the ``[dk, dv]`` float32 state of one head in
+VMEM scratch across the chunk axis, read from HBM once and written once a
+call.
 
 Tokens beyond a sequence's real length are given ``g = 0, b = 0`` by the
 caller: they write nothing and decay nothing, so the state that leaves is
@@ -81,6 +85,43 @@ def gated_delta_recurrent(q, k, v, g, beta, state):
     return o, state
 
 
+def unit_lower_inverse(system):
+    """The inverse of a batch of unit lower triangular ``[..., C, C]``
+    float32 matrices, ``C = 8 * 2**j``, in batched matmuls alone.
+
+    The diagonal blocks of 8 first: with ``N`` a block's strictly lower part
+    (``N**8 = 0``), ``(I + N)^-1 = (I - N)(I + N**2)(I + N**4)``. Then blocks
+    of 2b from blocks of b, ``[[A, 0], [E, D]]^-1 = [[A^-1, 0], [-D^-1 E
+    A^-1, D^-1]]``, which over the whole matrix is ``X - X E_b X`` with ``X``
+    the block-diagonal inverse so far and ``E_b`` the system's lower-left
+    blocks of b inside the diagonal blocks of 2b. Every product is of whole
+    ``C x C`` matrices, kept block-diagonal by zeros, at ``HIGHEST``. (A
+    Neumann product over the whole matrix is no substitute: with write
+    strengths near 2 the powers of ``N`` grow without bound.)"""
+    C = system.shape[-1]
+    blocks = C // 8
+    assert C % 8 == 0 and blocks & (blocks - 1) == 0, C
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = jnp.eye(C, dtype=jnp.float32)
+
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=_HI)
+
+    def within(b):
+        return row // b == col // b
+
+    n = jnp.where(within(8) & (row > col), system, 0.0)
+    n2 = mm(n, n)
+    inv = mm(eye - n + n2 - mm(n, n2), eye + mm(n2, n2))
+    b = 8
+    while b < C:
+        lower_left = jnp.where(within(2 * b) & ~within(b), system, 0.0)
+        inv = inv - mm(mm(inv, lower_left), inv)
+        b *= 2
+    return inv
+
+
 def chunk_prepare(q, k, v, g, beta, chunk: int = CHUNK) -> dict:
     """Stage one, every chunk at once. Inputs as
     :func:`gated_delta_recurrent` with T a multiple of ``chunk``. Returns
@@ -105,14 +146,12 @@ def chunk_prepare(q, k, v, g, beta, chunk: int = CHUNK) -> dict:
     kk = jnp.einsum("hntk,hnik->hnti", k, k, precision=_HI)
     qk = jnp.einsum("hntk,hnik->hnti", q, k, precision=_HI)
     decay = jnp.exp(gsum)[..., None]
-    system = jnp.eye(chunk, dtype=jnp.float32) \
-        + beta[..., None] * (kk * before)
-    rhs = beta[..., None] * jnp.concatenate([v, decay * k], axis=-1)
-    solved = jax.lax.linalg.triangular_solve(
-        system, rhs, left_side=True, lower=True, unit_diagonal=True)
-    dv = v.shape[-1]
+    b = beta[..., None]
+    inverse = unit_lower_inverse(jnp.eye(chunk, dtype=jnp.float32)
+                                 + b * (kk * before))
     tail = jnp.exp(gsum[..., -1:] - gsum)[..., None]    # G_C/G_t
-    return {"u": solved[..., :dv], "w": solved[..., dv:],
+    return {"u": jnp.matmul(inverse, b * v, precision=_HI),
+            "w": jnp.matmul(inverse, b * decay * k, precision=_HI),
             "qg": decay * q, "p": qk * upto,
             "kdt": jnp.swapaxes(tail * k, -1, -2),
             "gc": jnp.exp(gsum[..., -1])[..., None, None]}

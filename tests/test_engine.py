@@ -410,6 +410,37 @@ def test_rows_dispatch_leaf_count_is_constant(engine, monkeypatch):
     assert counts[1] == counts[4] == 8, counts
 
 
+@pytest.mark.parametrize("bucket", EngineConfig().all_row_buckets())
+def test_rows_program_reads_the_rows_a_gather_reads(bucket):
+    """The rows program's row-by-row read of the slab
+    (``runtime._gather_rows``) returns exactly what ``slab[k][rows]``
+    returns, for all three leaves in their served dtypes: repeated slots,
+    the pad slot 0 and the slab's last scratch slot among the rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from vilbert_multitask_tpu.engine.runtime import _gather_rows
+
+    n_rows, regions = 1 + 40 + 32, 11
+    rng = np.random.RandomState(bucket)
+    slab = jax.device_put(dict(
+        features=rng.randn(n_rows, regions, 16).astype(jnp.bfloat16),
+        spatials=rng.rand(n_rows, regions, 5).astype(np.float32),
+        image_mask=rng.randint(0, 2, (n_rows, regions)).astype(np.int32)))
+    rows = rng.randint(0, n_rows, size=bucket).astype(np.int32)
+    rows[0] = n_rows - 1
+    rows[-1] = 0
+    if bucket > 2:
+        rows[1] = rows[2]
+    rows = jax.device_put(rows)
+    served, gather = jax.jit(_gather_rows), jax.jit(lambda x, r: x[r])
+    for name, leaf in slab.items():
+        got, want = served(leaf, rows), gather(leaf, rows)
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
 def test_bf16_param_storage_decode_parity(tiny_config):
     """EngineConfig.param_dtype="bfloat16" halves served-weight HBM; decodes
     must stay within bf16 rounding of the f32 engine for EVERY decode

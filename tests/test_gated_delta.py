@@ -14,9 +14,10 @@ the one thing here that is not CPU arithmetic (section 2 of the
 on-chip-measurement guide: the chip's compiler runs without the chip). The
 other kernels of the generate path (``ops/paged_attention.py``'s two,
 ``ops/moe.py``'s) are compiled for the chip here too (their arithmetic is
-``tests/test_paged_attention.py``'s and ``tests/test_laguna.py``'s): one
-file describes the chip, because one process at a time may load its
-compiler."""
+``tests/test_paged_attention.py``'s and ``tests/test_laguna.py``'s), and
+so is the ViLBERT rows program's read of its device-resident slab (its
+values are ``tests/test_engine.py``'s): one file describes the chip,
+because one process at a time may load its compiler."""
 
 import jax
 import jax.numpy as jnp
@@ -422,3 +423,63 @@ def test_phi4flash_decode_program_updates_its_state_in_place(one_chip):
     assert not any("[8,129,10,512,128]" in line or "[9,128,16,5120]" in line
                    for line in copies)
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
+SLAB_ROWS = 1 + 7680 + 32  # the served slab: pad slot, cache, scratch
+
+
+def slab_program(gather):
+    """A rows program's read of the served slab and a consumer like the
+    model's visual embedding: a 2048 -> 1024 projection of the features,
+    the boxes' own, a LayerNorm and the mask."""
+    def run(features, spatials, image_mask, rows, w, w_box):
+        f, s, m = (gather(x, rows) for x in (features, spatials, image_mask))
+        h = jnp.einsum("brf,fh->brh", f, w,
+                       preferred_element_type=jnp.float32)
+        h = h + jnp.einsum("brs,sh->brh", s, w_box)
+        h = (h - h.mean(-1, keepdims=True)) * jax.lax.rsqrt(
+            h.var(-1, keepdims=True) + 1e-12)
+        return h * m[..., None]
+    return run
+
+
+def whole_slab_results(gather, bucket, one_chip):
+    """The compiled program's instructions, other than its parameters,
+    whose result holds the whole ``features`` leaf."""
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    text = jax.jit(slab_program(gather)).lower(
+        sd((SLAB_ROWS, 101, 2048), "bfloat16"),
+        sd((SLAB_ROWS, 101, 5), "float32"),
+        sd((SLAB_ROWS, 101), "int32"), sd((bucket,), "int32"),
+        sd((2048, 1024), "bfloat16"), sd((5, 1024), "float32"),
+    ).compile().as_text()
+    # An instruction reads "%name = <result> opcode(%operand, ...)": the
+    # result is what comes before the first operand.
+    results = [line.split(" = ", 1)[1].split("%", 1)[0]
+               for line in text.splitlines() if " = " in line]
+    return [r for r in results if f"bf16[{SLAB_ROWS},101,2048]" in r
+            and "parameter(" not in r]
+
+
+@pytest.mark.parametrize("bucket", [2, 10, 32])
+def test_rows_program_reads_the_slab_in_place(one_chip, bucket):
+    """The rows program's row-by-row read (``engine/runtime.py:
+    _gather_rows``) of the served slab (7713 rows of 101 x 2048 bfloat16,
+    3.19 GB) compiles with no instruction that makes a whole copy of it:
+    such a copy took three quarters of the device's busy time in the
+    saturated ViLBERT cell."""
+    from vilbert_multitask_tpu.engine.runtime import _gather_rows
+
+    assert whole_slab_results(_gather_rows, bucket, one_chip) == []
+
+
+def test_a_gather_of_ten_rows_re_lays_the_whole_slab(one_chip):
+    """What the row-by-row read replaced: ``slab[k][rows]`` of ten rows
+    wants the features in another tiling and copies all of them first. If
+    a compiler stops doing so, this case says it, and the other form may
+    go back to the plain gather."""
+    copies = whole_slab_results(lambda x, rows: x[rows], 10, one_chip)
+    assert any(" copy(" in r or "copy-start(" in r for r in copies), copies

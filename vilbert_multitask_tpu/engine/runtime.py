@@ -91,6 +91,19 @@ from vilbert_multitask_tpu.text.pipeline import EncodedText, encode_question
 from vilbert_multitask_tpu.text.wordpiece import FullTokenizer
 
 
+def _gather_rows(leaf, rows):
+    """``leaf[rows]`` for the rows program's static bucket, as one dynamic
+    slice a row, stacked. XLA's gather of two rows or more wants the slab's
+    ``features`` in another tiling than the one it is stored in, so every
+    such program first re-laid the whole slab (3.19 GB at the served size);
+    a dynamic slice reads each row where it lies, which is what XLA already
+    emits for a one-row gather. ``tests/test_gated_delta.py`` compiles both
+    forms for the chip and looks for the copy."""
+    return jnp.stack([
+        jax.lax.dynamic_index_in_dim(leaf, rows[i], keepdims=False)
+        for i in range(rows.shape[0])])
+
+
 class _AotProgram:
     """One compiled program behind a manifest record key, resolved lazily.
 
@@ -747,8 +760,9 @@ class InferenceEngine:
         live in the device-resident slab (:meth:`_row_slab`) and the
         per-call ``pack`` carries the text tensors plus one (bucket,)
         int32 slot-index vector; the (bucket, ...) batch is GATHERED from
-        the slab inside the compiled program. Rows that are already slab-
-        resident (the input cache, the permanent pad slot 0) upload
+        the slab inside the compiled program, a row at a time and in
+        place (:func:`_gather_rows`). Rows that are already slab-resident
+        (the input cache, the permanent pad slot 0) upload
         nothing. The flattened argument list is params + 3 slab leaves +
         5 pack leaves — constant in bucket size, so per-dispatch argument
         marshalling no longer scales with batch rows. The pack is freshly uploaded every
@@ -771,7 +785,7 @@ class InferenceEngine:
                 # Scopes are profile metadata (op_name prefixes): the
                 # executable stays ``jit_fwd``.
                 with jax.named_scope("slab_gather"):
-                    images = {k: slab[k][rows] for k in
+                    images = {k: _gather_rows(slab[k], rows) for k in
                               ("features", "spatials", "image_mask")}
                 batch = dict(
                     input_ids=pack["input_ids"],

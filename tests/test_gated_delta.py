@@ -315,6 +315,38 @@ def test_ring_decode_attention_compiles_for_the_chip(one_chip, B, heads,
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
 
+@pytest.mark.parametrize("rows,prefill", [(2048, True), (256, True),
+                                           (24, False), (8, False)])
+def test_cca_prep_compiles_for_the_chip(one_chip, rows, prefill):
+    """CCA's preparation of queries and keys at the served width
+    (8 query and 2 key/value heads of 128, half of each rotated): a prefill
+    chunk's window in blocks that overlap by the halo, and a decode step's
+    rows. The profile's event carries the kernel's name, the second
+    convolution's matrices and the output's width: what
+    ``benchmark/reduce/kinds/trace_cca_prep_roofline.py`` reads."""
+    from vilbert_multitask_tpu.ops import cca
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dims = dict(heads=8, kv_heads=2, rotary_dim=64)
+    maps = (sd(jnp.bfloat16, 2, 1280), sd(jnp.bfloat16, 2, 10, 128, 128),
+            sd(jnp.bfloat16, 2), sd(jnp.float32, rows, 128),
+            sd(jnp.float32, rows, 128))
+    if prefill:
+        compiled = jax.jit(lambda tail, c, *m: cca.cca_prep_prefill(
+            tail, c, *m, **dims)).lower(
+                sd(jnp.float32, 2, 1280), sd(jnp.float32, rows, 1280),
+                *maps).compile()
+    else:
+        compiled = jax.jit(lambda a, b, c, *m: cca.cca_prep_rows(
+            a, b, c, *m, **dims)).lower(
+                *[sd(jnp.float32, rows, 1280)] * 3, *maps).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%cca_prep" in text
+    assert "bf16[2,10,128,128]" in text and f"bf16[{rows},1024]" in text
+
+
 def test_ring_store_writes_in_place_on_the_chip(one_chip):
     """One slot's ring stored into the donated array: the array is aliased
     to the result, never copied."""

@@ -13,6 +13,7 @@ from vilbert_multitask_tpu.config import (
     LagunaConfig,
     OlmoHybridConfig,
     Phi4FlashConfig,
+    ZayaConfig,
 )
 from vilbert_multitask_tpu.engine import seqstate
 from vilbert_multitask_tpu.engine.generate import model_module
@@ -26,8 +27,12 @@ LAGUNA = dataclasses.replace(GEN, model=LagunaConfig().tiny())
 # And under the third's (ISSUE 34): a slot of Mamba states, convolution
 # rows and rings, pages of one layer whatever the depth.
 PHI4FLASH = dataclasses.replace(GEN, model=Phi4FlashConfig().tiny())
-BOTH = pytest.mark.parametrize("gen", [GEN, LAGUNA, PHI4FLASH],
-                               ids=["olmo_hybrid", "laguna", "phi4flash"])
+# And the fourth's: a slot of two carried latent rows and the
+# last input a layer, every layer paged.
+ZAYA = dataclasses.replace(GEN, model=ZayaConfig().tiny())
+BOTH = pytest.mark.parametrize("gen", [GEN, LAGUNA, PHI4FLASH, ZAYA],
+                               ids=["olmo_hybrid", "laguna", "phi4flash",
+                                    "zaya"])
 
 
 def SequenceState(gen):
@@ -112,9 +117,31 @@ def test_phi4flash_sizes_follow_the_model():
     assert served.pool_shape == (1, 641, 10, 256, 128)
 
 
+def test_zaya_sizes_follow_the_model():
+    st = SequenceState(ZAYA)
+    # 2 layers: two rows of 6 heads x 16 latent channels side by side and
+    # the last input of 64, float32
+    assert st.slot_bytes == 2 * (2 * 96 + 64) * 4
+    assert st.slot_shapes == {"cca_conv": (2, 4, 192), "cca_prev": (2, 4, 64)}
+    assert st.ring_bytes == 0
+    # every layer paged: 2 key/value heads of 16, read by 2 query heads each
+    assert st.pool_shape == (2, 33, 2, 16, 16)
+    assert st.layout.query_group == 2
+    served = SequenceState(GenerateConfig(
+        model=dataclasses.replace(ZayaConfig(), num_hidden_layers=10,
+                                  layer_types=("hybrid",) * 10),
+        slots=24, kv_pages=2432, decode_buckets=(8, 16, 24)))
+    # ten layers of 2 x 1280 + 2048 float32; a page of 256 tokens of ten
+    # layers of 2 heads of 128 in bfloat16: 10 KB a token
+    assert served.slot_bytes == 10 * (2 * 1280 + 2048) * 4 == 184320
+    assert served.page_bytes == 2 * 10 * 2 * 256 * 128 * 2 == 2621440
+    assert served.pages * served.page_bytes == 6375342080
+
+
 @pytest.mark.parametrize("gen,slot_bytes,page_bytes", [
-    (GEN, 21504, 16384), (LAGUNA, 12288, 8192), (PHI4FLASH, 18944, 4096)],
-    ids=["olmo_hybrid", "laguna", "phi4flash"])
+    (GEN, 21504, 16384), (LAGUNA, 12288, 8192), (PHI4FLASH, 18944, 4096),
+    (ZAYA, 2048, 8192)],
+    ids=["olmo_hybrid", "laguna", "phi4flash", "zaya"])
 def test_slot_and_page_bytes_of_the_three_layouts(gen, slot_bytes,
                                                   page_bytes):
     """What admission is charged, by layout: held to the byte."""

@@ -951,13 +951,134 @@ class Phi4FlashConfig:
             sliding_window=16, mamba_d_state=4, use_pallas=False)
 
 
+_ZAYA_ROPE = (
+    ("hybrid", (("partial_rotary_factor", 0.5), ("rope_theta", 5000000.0),
+                ("rope_type", "default"))),
+    ("hybrid_sliding", (("partial_rotary_factor", 0.5),
+                        ("rope_theta", 10000.0), ("rope_type", "default"))),
+    ("rope_type", "default"),
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class ZayaConfig:
+    """A decoder of ``model_type: zaya`` (ZAYA1, arXiv:2511.17127): every
+    layer an attention sublayer, compressed convolutional attention (CCA,
+    arXiv:2510.04476) over few key/value heads, then an expert sublayer
+    whose router is a small MLP that carries a state from layer to layer
+    (exponential depth averaging) and may choose no expert (mixture of
+    depths). The source ``config.json``'s keys under their own names; the
+    three flags that name the router's parts and the residual merge are
+    the sibling ``ZAYA1-base`` configuration's (this one has no key for
+    them). The layers are ``models/zaya.py``."""
+
+    model_type: str = "zaya"
+    vocab_size: int = 262272
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    num_attention_heads: int = 8
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 0.5
+    rope_parameters: Sequence = _ZAYA_ROPE
+    rms_norm_eps: float = 1e-5
+    hidden_act: str = "silu"
+    attention_bias: bool = False
+    lm_head_bias: bool = False
+    tie_word_embeddings: bool = True
+    max_position_embeddings: int = 131072
+    sliding_window: int | None = None
+    layer_types: Sequence[str] = ("hybrid",) * 40
+    num_experts: int = 16
+    num_experts_per_tok: int = 1
+    moe_intermediate_size: int = 2048
+    router_hidden_size: int = 256
+    # The sibling configuration's flags (assumed for this one).
+    zaya_use_eda: bool = True
+    zaya_use_mod: bool = True
+    scale_residual_merge: bool = True
+    # Kernel choice (not the source's): the CCA preparation of ops/cca.py,
+    # the grouped expert product of ops/moe.py and both attentions of
+    # ops/paged_attention.py as Pallas kernels; ``pallas_interpret`` is the
+    # CPU tests' explicit choice, never inferred from the backend.
+    use_pallas: bool = True
+    pallas_interpret: bool = False
+
+    def __post_init__(self):
+        for name in ("layer_types", "rope_parameters"):
+            object.__setattr__(self, name, _frozen(getattr(self, name)))
+        if self.model_type != "zaya":
+            raise ValueError(f"model_type {self.model_type!r} is not zaya")
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError("layer_types names another depth than "
+                             "num_hidden_layers")
+        if set(self.layer_types) != {"hybrid"} or self.sliding_window:
+            raise ValueError("only hybrid layers (CCA, then experts) over "
+                             "the whole sequence are implemented")
+        if (self.cca_time0, self.cca_time1) != (2, 2):
+            raise ValueError("only CCA's two-tap convolutions are "
+                             "implemented (cca_time0 = cca_time1 = 2)")
+        if (self.num_attention_heads % self.num_key_value_heads
+                or self.rotary_dim % 2 or self.rotary_dim > self.head_dim):
+            raise ValueError("query heads must divide into the key/value "
+                             "heads; the rotated part of a head is even")
+        if (self.hidden_act != "silu" or self.attention_bias
+                or self.lm_head_bias or not self.tie_word_embeddings):
+            raise ValueError("only silu experts, no biases, a tied head")
+        if self.num_experts_per_tok != 1 or not (
+                self.zaya_use_eda and self.zaya_use_mod
+                and self.scale_residual_merge):
+            raise ValueError("only the top-1 router with depth averaging, "
+                             "the no-expert choice and the scaled residual "
+                             "merge is implemented")
+
+    @property
+    def rope(self) -> dict:
+        return {k: (dict(v) if isinstance(v, tuple) else v)
+                for k, v in self.rope_parameters}
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * float(self.rope["hybrid"].get(
+            "partial_rotary_factor", self.partial_rotary_factor)))
+
+    @property
+    def rope_theta(self) -> float:
+        return float(self.rope["hybrid"]["rope_theta"])
+
+    @property
+    def channels(self) -> int:
+        """The latent channels CCA convolves: query and key heads."""
+        return (self.num_attention_heads + self.num_key_value_heads) \
+            * self.head_dim
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the routed experts held here: all of them. The
+        router's last choice, ``num_experts``, is "no expert"."""
+        return (0, self.num_experts)
+
+    def tiny(self) -> "ZayaConfig":
+        """The size of the CPU tests: 2 layers, 64 wide, 4 / 2 heads of 16
+        (half of each rotated), 4 experts of 32, a router 16 wide."""
+        return dataclasses.replace(
+            self, vocab_size=512, hidden_size=64, num_hidden_layers=2,
+            layer_types=("hybrid",) * 2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, max_position_embeddings=512,
+            num_experts=4, moe_intermediate_size=32, router_hidden_size=16,
+            use_pallas=False)
+
+
 @dataclasses.dataclass(frozen=True)
 class GenerateConfig:
     """The text-generation engine (engine/generate.py). ``model`` None: the
     app serves ViLBERT and refuses the ``generate`` task. One ``ServeApp``
     holds one model: a generate app serves no ViLBERT task."""
 
-    model: OlmoHybridConfig | LagunaConfig | Phi4FlashConfig | None = None
+    model: (OlmoHybridConfig | LagunaConfig | Phi4FlashConfig | ZayaConfig
+           | None) = None
     param_dtype: str = "bfloat16"
     # Compiled shapes: tokens a prefill chunk (multiples of the page size
     # and of the scan's 64), sequences a decode step.

@@ -121,6 +121,11 @@ _MOE_LOAD = obs.REGISTRY.histogram(
     "An expert-layer call's fullest held expert over the mean of the held "
     "ones (1.0: even).", labelnames=_MOE_LABELS,
     buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 32.0, 128.0))
+_MOE_SKIPPED = obs.REGISTRY.counter(
+    "vmt_moe_skipped_total",
+    "Token-layers a router sent to no expert (a mixture-of-depths choice): "
+    "real tokens whose expert sublayer added nothing.",
+    labelnames=_MOE_LABELS)
 
 
 _STEP_LABELS = ("program",)
@@ -140,6 +145,15 @@ _SHARED_KV_READS = obs.REGISTRY.counter(
     "vmt_shared_kv_page_reads_total",
     "Pages of the one pool that decode steps walked: pages in use times the "
     "layers that read them (the paged layer itself and every cross layer).")
+_CCA_ROWS = obs.REGISTRY.counter(
+    "vmt_cca_rows_total",
+    "Real rows whose queries and keys CCA's preparation made (ops/cca.py), "
+    "summed over the layers: prompt tokens and decoded tokens.",
+    labelnames=_STEP_LABELS)
+_CCA_CALLS = obs.REGISTRY.counter(
+    "vmt_cca_calls_total",
+    "Calls of CCA's preparation dispatched: one a layer a step.",
+    labelnames=_STEP_LABELS)
 
 
 def model_module(model_cfg):
@@ -150,7 +164,8 @@ def model_module(model_cfg):
     stack for every chunk but a prompt's last says ``PREFILL_SPLIT`` (its
     ``prefill_chunk`` then takes ``final``) and ``step_work(cfg)``, from
     which the ``vmt_self_decoder_*`` / ``vmt_ssm_*`` counters are
-    reckoned."""
+    reckoned; one with CCA layers says ``cca_layers(cfg)``, from which the
+    ``vmt_cca_*`` counters are."""
     return importlib.import_module(
         f"vilbert_multitask_tpu.models.{model_cfg.model_type}")
 
@@ -210,6 +225,9 @@ class GenerateEngine:
         # None: every chunk runs the whole stack.
         self._split = (model_lib.step_work(gen.model)
                        if getattr(model_lib, "PREFILL_SPLIT", False) else None)
+        # Layers a token runs CCA's preparation in (0: the model has none).
+        self._cca_layers = (model_lib.cca_layers(gen.model)
+                            if hasattr(model_lib, "cca_layers") else 0)
         self.replica_id = replica_id
         self.killed = False
         self.boot_times: Dict[str, float] = {}
@@ -433,6 +451,7 @@ class GenerateEngine:
             out = self._call("prefill", bucket, x)
         _PREFILL_TOKENS.inc(n)
         self._count_split("prefill", n, int(n == left))
+        self._count_cca("prefill", n)
         _PREFILL_FILL.observe(n / bucket, bucket=str(bucket))
         self._count_prefill_attention(seq.prefilled, bucket)
         seq.prefilled += n
@@ -443,8 +462,9 @@ class GenerateEngine:
         elif "moe" in out:
             # A chunk that ends no prompt yields no token; its expert
             # counts are still fetched, with the later steps'.
-            self._pending.append(({"moe": out["moe"]}, [], None, "prefill",
-                                  n))
+            self._pending.append((
+                {k: out[k] for k in ("moe", "moe_skipped") if k in out}, [],
+                None, "prefill", n))
 
     def decode(self, reqs: Seq[GenerateRequest]) -> None:
         """Dispatch one token of each of ``reqs`` (all prefilled, none
@@ -477,6 +497,7 @@ class GenerateEngine:
         in_use = st.stats()["kv_pages_in_use"]
         _POOL_FILL.observe(in_use / st.pages)
         self._count_split("decode", len(reqs), len(reqs), pages=in_use)
+        self._count_cca("decode", len(reqs))
         for req in reqs:
             req.seq.generated += 1
         self._pending.append((out, list(reqs), [r.seq.slot for r in reqs],
@@ -494,6 +515,9 @@ class GenerateEngine:
                 host = jax.device_get(out)
             if "moe" in host:
                 self._count_experts(program, tokens, host["moe"])
+            if "moe_skipped" in host:
+                _MOE_SKIPPED.inc(int(np.sum(host["moe_skipped"])),
+                                 program=program)
             for k, req in enumerate(reqs):
                 pick = ((lambda a: a) if rows is None
                         else (lambda a, b=rows[k]: a[b]))
@@ -534,6 +558,14 @@ class GenerateEngine:
         _CROSS_ROWS.inc(cross_rows, program=program)
         _SSM_TOKENS.inc(rows * self._split["ssm_layers"], program=program)
         _SHARED_KV_READS.inc(pages * self._split["pool_readers"])
+
+    def _count_cca(self, program: str, rows: int) -> None:
+        """One step's CCA preparation into the ``vmt_cca_*`` counters:
+        ``rows`` real rows through every layer that has it, one call a
+        layer; what the call was told, reckoned here."""
+        if self._cca_layers:
+            _CCA_ROWS.inc(rows * self._cca_layers, program=program)
+            _CCA_CALLS.inc(self._cca_layers, program=program)
 
     def _count_experts(self, program: str, tokens: int, moe) -> None:
         """One step's expert-layer integers ``moe`` [sparse layers, 3]

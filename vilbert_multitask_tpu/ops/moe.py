@@ -1,8 +1,16 @@
 """Sparse experts: the router, and the expert layer *that is told which
 experts it holds*.
 
-A token's router scores run over all ``E`` experts of the model; its top
-``k`` and their weights are taken over all of them (:func:`route`). The
+A token's router scores run over all ``E`` choices of the model; its top
+``k`` and their weights are taken over all of them (:func:`route`): by
+default the softmax's ``k`` largest, renormalised to sum to 1 and scaled.
+Two further forms of the same function: a *selection bias*, added to the
+logits to pick the ``k`` and never to weight them (the weights stay the
+softmax's of the logits alone), and weights left unrenormalised (a top-1
+weight renormalised is 1, which would cut the router out of the forward).
+A choice the router may make that no chip holds (an id at or past the
+experts held: a "no expert" choice) is dropped by the expert layer like an
+expert some other chip holds: it adds nothing. The
 expert layer (:func:`experts_forward`) holds the weights of the experts
 ``first .. first + held - 1`` only, and computes the part of the result
 those give: ``sum over e in top-k(t) and held of w[t, e] * E_e(x[t])``,
@@ -45,13 +53,24 @@ from jax.experimental.pallas import tpu as pltpu
 _VMEM_LIMIT = 96 * 1024 * 1024
 
 
-def route(logits, k: int, scale: float):
+def route(logits, k: int, scale: float = 1.0, *, bias=None,
+          renormalize: bool = True):
     """``logits`` [T, E] float32 -> (experts [T, k] int32, weights [T, k]
     float32): softmax over all ``E``, the ``k`` largest, their scores
-    renormalised to sum to 1 and scaled by ``scale``."""
+    renormalised to sum to 1 and scaled by ``scale``. With ``bias`` [E] the
+    ``k`` are the largest of ``logits + bias`` and their weights the
+    softmax's scores of them; without ``renormalize`` the scores are only
+    scaled."""
     scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top, experts = jax.lax.top_k(scores, k)
-    weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(logits.astype(jnp.float32) + bias, k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalize:
+        weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)
+    else:
+        weights = scale * top
     return experts.astype(jnp.int32), weights
 
 
